@@ -57,4 +57,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from trlx_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

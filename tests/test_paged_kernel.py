@@ -100,7 +100,7 @@ def test_kernel_matches_jnp_reference_with_sentinel_pages():
     q, k, v, pt, bias = _kernel_case()
     ref = _jnp_paged_reference(q, k, v, pt, bias)
     out = jax.jit(
-        lambda *a: paged_decode_attention(*a, interpret=True)
+        lambda *a: paged_decode_attention(*a)
     )(q, k, v, pt, bias)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=1e-5
@@ -121,7 +121,7 @@ def test_kernel_int8_matches_dequantized_reference():
         pt, bias,
     )
     out = jax.jit(
-        lambda *a: paged_decode_attention(*a, interpret=True)
+        lambda *a: paged_decode_attention(*a)
     )(q, (k_codes, k_scales), (v_codes, v_scales), pt, bias)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=1e-5
@@ -130,7 +130,7 @@ def test_kernel_int8_matches_dequantized_reference():
 
 def test_make_paged_decode_fn_single_device_is_direct_call():
     q, k, v, pt, bias = _kernel_case(seed=2)
-    fn = make_paged_decode_fn(mesh=None, interpret=True)
+    fn = make_paged_decode_fn(mesh=None)
     out = fn(q, k, v, pt, bias)
     np.testing.assert_allclose(
         np.asarray(out),

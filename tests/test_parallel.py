@@ -22,38 +22,6 @@ from trlx_tpu.parallel.mesh import resolve_axis_sizes
 from trlx_tpu.utils.loading import get_model, get_orchestrator, get_pipeline
 from trlx_tpu.utils.tokenizer import ByteTokenizer
 
-# -- environment capability gates (NOT expected failures) ------------- #
-# Each gate detects the concrete mechanism the test needs so tier-1 is
-# green where the capability is absent and the test RUNS (and can
-# regress loudly) where it is present.
-
-#: the GPipe schedule marks its scan carries per-stage-varying via
-#: jax.lax.pcast (pipeline_parallel.py); older jax (< 0.5) has no pcast
-#: and the pp>1 path cannot trace at all
-HAS_PCAST = hasattr(jax.lax, "pcast")
-pcast_skip = pytest.mark.skipif(
-    not HAS_PCAST,
-    reason=f"jax.lax.pcast is missing in jax {jax.__version__} — the "
-           f"pp>1 GPipe schedule needs its scan carries cast "
-           f"per-stage-varying (jax >= 0.5)",
-)
-
-#: two-process CPU collectives need jax to plumb a CPU collectives
-#: implementation (gloo) into the client — the config knob that does so
-#: landed after 0.4.x; without it every cross-process computation dies
-#: with "Multiprocess computations aren't implemented on the CPU
-#: backend" no matter what jaxlib ships
-HAS_CPU_MULTIPROCESS = hasattr(
-    jax.config, "jax_cpu_collectives_implementation"
-)
-multiprocess_skip = pytest.mark.skipif(
-    not HAS_CPU_MULTIPROCESS,
-    reason=f"jax {jax.__version__} cannot run multiprocess computations "
-           f"on the CPU backend (no jax_cpu_collectives_implementation "
-           f"config to select gloo)",
-)
-
-
 # --------------------------------------------------------------------- #
 # mesh construction
 # --------------------------------------------------------------------- #
@@ -230,7 +198,7 @@ def test_sharded_ppo_e2e_smoke(devices):
 
 @pytest.mark.parametrize("arch", ["gptj", "gptneox", "llama"])
 def test_tp_sharded_forward_matches_dense_other_arches(devices, arch):
-    """VERDICT item 6: tensor-parallel forward parity for the gpt-j /
+    """Tensor-parallel forward parity for the gpt-j /
     gpt-neox / llama families (rotary, parallel blocks, untied heads,
     GQA + swiglu for llama — the structures larger workloads shard
     over tp)."""
@@ -408,7 +376,6 @@ def test_broadcast_host_floats_uses_process0_when_multihost(monkeypatch):
     np.testing.assert_array_equal(out, [1.0, 2.0])
     assert out.dtype == np.float32
 
-@multiprocess_skip
 @pytest.mark.parametrize("mesh_spec", [
     None,  # pure dp over both processes
     # every parameter sharded over all 8 devices: forwards/backwards
@@ -485,7 +452,6 @@ def test_two_process_distributed_cpu(tmp_path, mesh_spec):
 # --------------------------------------------------------------------- #
 
 
-@pcast_skip
 def test_pp_forward_matches_dense(devices):
     """GPipe forward over pp=4 (composed with dp=2) must equal the dense
     stacked-layer scan — values AND gradients; the schedule is an
@@ -607,9 +573,8 @@ def _pp_trainer(mesh_cfg, n_layer=3):
     return config, trainer
 
 
-@pcast_skip
 def test_pp_trainer_train_step_matches_single_device(devices):
-    """train.mesh pp > 1 now drives the trainers' forward (VERDICT r04 #6):
+    """train.mesh pp > 1 now drives the trainers' forward:
     the GPipe'd frozen trunk produces the same loss and updated params as
     the dense single-device step."""
     config_s, single = _pp_trainer(None)
@@ -645,7 +610,6 @@ def test_pp_trainer_train_step_matches_single_device(devices):
     )
 
 
-@pcast_skip
 def test_pp_trainer_full_loop_runs(devices):
     """make_experience + learn() under a pp mesh: rollout scoring and the
     update both route the frozen trunk through the GPipe op."""

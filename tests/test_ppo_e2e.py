@@ -247,7 +247,7 @@ def test_make_experience_crosses_host_boundary_twice_per_chunk(monkeypatch):
     """Architecture guard: one device_get (sequences + seq_kl) and one
     host->device scores transfer per rollout chunk — per-token
     logprobs/values/rewards must never round-trip through the host
-    (each sync on tunneled/remote TPUs costs ~100 ms regardless of size)."""
+    (each sync stalls the host until the device queue drains)."""
     import jax
 
     import trlx_tpu.orchestrator.ppo_orchestrator as orch_mod

@@ -417,3 +417,65 @@ def test_wandb_tracker_reuses_last_step_when_iter_absent():
     t({"eval_only": 1.0})
     assert [s for _, s in logged] == [5, 5, 7, 7]
     assert logged[1][0]["mean_score"] == 0.5
+
+
+# --------------------------------------------------------------------- #
+# the device the numbers are divided by
+# --------------------------------------------------------------------- #
+
+
+def _fake_device(platform, kind, stats=None):
+    return types.SimpleNamespace(
+        platform=platform, device_kind=kind, memory_stats=lambda: stats
+    )
+
+
+def test_peak_flops_answers_from_device_kind(monkeypatch):
+    """v5e (device_kind "TPU v5 lite", read off the chip) is 197e12; a
+    TPU kind the table does not know is an error, never a default; the
+    CPU backend has no peak and MFU is simply omitted there."""
+    import jax
+
+    from trlx_tpu.telemetry import flops
+
+    assert flops.peak_flops() is None  # this CPU run
+    assert flops.mfu_estimate(1e3, 1e9) is None
+    monkeypatch.setattr(
+        jax, "devices", lambda: [_fake_device("tpu", "TPU v5 lite")]
+    )
+    assert flops.peak_flops() == 197e12
+    assert flops.mfu_estimate(1e3, 197e9) == pytest.approx(1.0)
+    monkeypatch.setattr(
+        jax, "devices", lambda: [_fake_device("tpu", "TPU v9 mega")]
+    )
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        flops.peak_flops()
+
+
+def test_device_monitor_is_silent_only_where_the_backend_has_no_stats(
+    monkeypatch,
+):
+    import jax
+
+    from trlx_tpu.telemetry import device
+
+    monkeypatch.setattr(device, "_available", True)
+    reg = MetricsRegistry()
+    device.sample_device_stats(reg)  # CPU: no stats, latches silent
+    assert device._available is False and not reg.gauges
+
+    monkeypatch.setattr(device, "_available", True)
+    monkeypatch.setattr(
+        jax, "local_devices", lambda: [_fake_device("tpu", "TPU v5 lite")]
+    )
+    with pytest.raises(RuntimeError, match="memory_stats"):
+        device.sample_device_stats(reg)
+    monkeypatch.setattr(
+        jax, "local_devices", lambda: [_fake_device(
+            "tpu", "TPU v5 lite",
+            {"bytes_in_use": 2**30, "bytes_limit": 16 * 2**30},
+        )],
+    )
+    device.sample_device_stats(reg)
+    assert reg.gauges["device/hbm_in_use_gb"] == 1.0
+    assert reg.gauges["device/hbm_utilization"] == 1 / 16

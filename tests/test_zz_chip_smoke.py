@@ -1,0 +1,197 @@
+"""chip_smoke.py's phases at toy widths on the CPU, its refusal to pass
+without a TPU, and the compile-cache helper it (and every other entry
+point) calls.
+
+The real run — gpt2-124M widths, Mosaic-compiled kernels — needs the
+chip and goes through the builder's chip tool; what tier-1 can hold is
+that the phases still wire up through the registries and the serve CLI,
+that the script cannot exit 0 on a CPU backend or away from the repo,
+and that the cache lands where the contract says.
+"""
+
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+TOY = {
+    "model_spec": {"vocab_size": 257, "n_layer": 2, "n_head": 4,
+                   "d_model": 64, "n_positions": 64},
+    "compute_dtype": "float32",
+    "batch": 8,
+    "prompt_tokens": 4,
+    "gen_tokens": 8,
+}
+
+
+def test_trainer_and_server_phases_at_toy_width(tmp_path):
+    """trainer_phase (two checked PPO cycles + save) feeds server_phase
+    (serve CLI child: exact token counts, /metrics invariants, SIGTERM
+    drain) — the same functions main() runs on the chip."""
+    trained = chip_smoke.trainer_phase(str(tmp_path), width=TOY)
+    assert os.path.isdir(trained["checkpoint"])
+    requests = [(tokens, min(max_new, 8))
+                for tokens, max_new in trained["requests"]]
+    assert len(requests) == len(chip_smoke.SERVE_MAX_NEW)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    # one device is enough for the child; 8 virtual ones only slow its boot
+    env.pop("XLA_FLAGS", None)
+    served = chip_smoke.server_phase(
+        trained["checkpoint"], str(tmp_path), "toy", requests,
+        ("--config", trained["greedy_config"]),
+        buckets="2x16x8,4x16x8", boot_timeout=120.0, env=env,
+    )
+    assert [len(t) for t in served["tokens"]] == [2, 5, 8, 8]
+
+
+def test_failed_check_names_its_phase():
+    with pytest.raises(chip_smoke.SmokeFailure, match="phase=server"):
+        chip_smoke.check(False, "server", "made to fail")
+
+
+def test_main_exits_nonzero_without_a_tpu_and_prints_no_result():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+    assert "FAILED phase=device" in proc.stderr
+
+
+def test_main_exits_nonzero_away_from_the_repo(tmp_path):
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+    proc = subprocess.run(
+        [sys.executable, "chip_smoke.py"], cwd=tmp_path,
+        capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+
+
+# -- the compile-cache helper ------------------------------------------- #
+
+
+def _cache_dir_from(cwd, env_value=None):
+    """What enable_compile_cache() returns and what it left in
+    jax.config, from a fresh interpreter started in ``cwd``."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_value is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_value
+    code = (
+        "import jax\n"
+        "from trlx_tpu.utils.compile_cache import enable_compile_cache\n"
+        "before = jax.config.jax_compilation_cache_dir\n"
+        "print(enable_compile_cache())\n"
+        "print(before == jax.config.jax_compilation_cache_dir)\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], cwd=cwd, env=env,
+        capture_output=True, text=True, timeout=120, check=True,
+    ).stdout.split("\n")
+    return out[0], out[1] == "True", out[2]
+
+
+def test_cache_dir_is_fixed_under_the_checkout_from_any_cwd(tmp_path):
+    want = os.path.join(REPO, ".jax_cache")
+    for cwd in (REPO, str(tmp_path)):
+        returned, untouched, configured = _cache_dir_from(cwd)
+        assert returned == configured == want
+        assert not untouched
+
+
+def test_cache_env_var_wins_and_code_sets_nothing(tmp_path):
+    placed = str(tmp_path / "placed")
+    returned, untouched, configured = _cache_dir_from(REPO, placed)
+    assert returned == placed
+    # JAX read the variable itself; the helper changed no config
+    assert untouched and configured == placed
+
+
+def test_importing_the_package_does_not_enable_the_cache():
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax, trlx_tpu, trlx_tpu.utils, trlx_tpu.serve\n"
+         "print(jax.config.jax_compilation_cache_dir)"],
+        env=env, capture_output=True, text=True, timeout=120, check=True,
+    ).stdout.strip()
+    assert out == "None"
+
+
+# -- Mosaic itself, without a chip --------------------------------------- #
+
+
+def test_kernels_compile_under_mosaic_for_a_v5e_from_this_host(monkeypatch):
+    """libtpu ships the compiler, and jax can hand it a compile-only v5e
+    topology with no TPU attached: the same Mosaic passes the chip run
+    goes through, minus the numbers. tests/test_kernel_lowering.py stops
+    at Pallas' block rules; this goes on to Mosaic's own objections (int8
+    tiles are (32, 128), so a 16-row int8 page; hd = 64 under 128 lanes;
+    the strided per-head load) — each a chip-minute saved when it
+    breaks."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from trlx_tpu.ops import pallas_mode
+    from trlx_tpu.ops.paged_attention import paged_decode_attention
+    from trlx_tpu.ops.pallas_attention import flash_attention
+
+    try:
+        topology = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu on this host: nothing to compile with
+        pytest.skip(f"no compile-only TPU topology here: {e!r}")
+    on_chip = SingleDeviceSharding(topology.devices[0])
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    def compile_for_v5e(fn, *args):
+        # conftest's "highest" matmul precision is for f32 CPU parity; on
+        # bf16 MXU operands Mosaic refuses it ("Bad lhs type"), and no
+        # TPU entry point sets it
+        with jax.default_matmul_precision("default"):
+            text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text
+
+    S, max_pages, num_pages = 16, 4, 80
+    for H, Hkv, hd in ((12, 12, 64), (16, 16, 256), (32, 8, 128)):
+        for page_size in (16, 64):
+            pool = (num_pages, page_size, Hkv, hd)
+            for pages in (
+                sds(pool, jnp.bfloat16),
+                (sds(pool, jnp.int8), sds(pool[:3], jnp.float32)),
+            ):
+                compile_for_v5e(
+                    paged_decode_attention,
+                    sds((S, H, hd), jnp.bfloat16), pages, pages,
+                    sds((S, max_pages), jnp.int32),
+                    sds((S, max_pages * page_size), jnp.float32),
+                )
+    qkv = sds((2, 1024, 12, 64), jnp.bfloat16)
+    mask = sds((2, 1024), jnp.int32)
+    compile_for_v5e(
+        jax.grad(
+            lambda q, k, v, m: flash_attention(
+                q, k, v, m, 128, 128, True
+            ).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        ),
+        qkv, qkv, qkv, mask,
+    )

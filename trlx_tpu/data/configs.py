@@ -262,8 +262,9 @@ class TrainConfig:
     host_retries: int = 2
     host_retry_backoff: float = 0.5
     # PPO only: dispatch the next epoch's rollout programs BEFORE the
-    # current epoch's updates drain (one host-sync saved per cycle — the
-    # dominant per-cycle cost on tunneled/remote runtimes). Semantics:
+    # current epoch's updates drain (one host-sync saved per cycle;
+    # whether that still pays on a directly attached chip is ROADMAP
+    # S4's measurement). Semantics:
     # each epoch trains on experience generated by the PREVIOUS epoch's
     # policy (staleness of exactly one update phase) instead of the
     # reference's strictly on-policy refresh. Default off = reference

@@ -106,8 +106,8 @@ def _use_unrolled_layers(
     two hosts could compile different programs (different collective
     sequences -> hang). bytes_limit is a hardware constant, identical
     across same-generation hosts, so comparing the static estimate
-    against it is multi-host safe; runtimes that expose no stats (e.g.
-    tunneled devices) just use the depth ceiling.
+    against it is multi-host safe; the CPU backend reports no stats and
+    just uses the depth ceiling.
 
     `bytes_are_per_device`: False when the caller could only compute a
     GLOBAL estimate under a multi-device mesh (jit tracers hide the
@@ -121,18 +121,10 @@ def _use_unrolled_layers(
         return n_layers <= int(env)
     if n_layers > _UNROLL_MAX_LAYERS:
         return False
-    try:
-        if jax.device_count() > 1 and not bytes_are_per_device:
-            return True
-        stats = jax.local_devices()[0].memory_stats() or {}
-        limit = stats.get("bytes_limit")
-        if limit and static_bytes > 0.9 * limit:
-            return False
-    except Exception:
-        # runtimes that expose no memory stats: the depth ceiling above
-        # already accepted this layer count, so unroll
+    if jax.device_count() > 1 and not bytes_are_per_device:
         return True
-    return True
+    limit = (jax.local_devices()[0].memory_stats() or {}).get("bytes_limit")
+    return not (limit and static_bytes > 0.9 * limit)
 
 
 def decide_unroll(spec: ModelSpec, weight_params, batch_size: int,

@@ -8,17 +8,28 @@ At decode batch sizes that gather dominates the step — BENCH_r04/r05
 put decode MFU at ~0.20 against 0.60+ for training. This module removes
 it, following the PagedAttention (vLLM) design on the TPU grid model:
 
-- Grid ``(slot, kv-head-group, page)``; the per-slot page table rides in
-  as a **scalar-prefetch** operand (host int32 — data, never shape), so
-  each page-step's BlockSpec index map reads ``page_table[s, p]`` and
-  DMAs exactly that page of the global pool into VMEM. The gathered
-  [T, hd] context never exists in HBM.
-- Each program holds one slot's query row for one group of
-  ``H // Hkv`` query heads (GQA runs natively against the compact KV)
-  and walks the slot's pages with an **online-softmax** carry (running
-  max / denominator / f32 accumulator in VMEM scratch, the same
-  recurrence as ops/pallas_attention's flash kernel), writing the
-  attention output once on the last page-step.
+- Grid ``(slot, page)``; the per-slot page table rides in as a
+  **scalar-prefetch** operand (host int32 — data, never shape), so each
+  page-step's BlockSpec index map reads ``page_table[s, p]`` and DMAs
+  exactly that page of the global pool — the whole page across every kv
+  head, ``(1, page_size, Hkv, hd)`` — into VMEM. The gathered [T, hd]
+  context never exists in HBM.
+- Every block keeps its last two dimensions FULL (Pallas' TPU block
+  rule: divisible by (8, 128) or equal to the array's own extent): the
+  pool block ends in ``(Hkv, hd)``, the query/output ride as
+  ``[S, Hkv, G, hd]`` with block ``(1, Hkv, G, hd)``, the bias as
+  ``[S, max_pages, 1, page_size]`` and the int8 scales as whole
+  ``(page_size, Hkv)`` pages. A whole page at gpt-j widths is
+  64 * 16 * 256 * 2 B = 512 KB per operand — far inside VMEM — and the
+  pool layout stays the one the jnp path, serve/layouts.py's head-dim
+  sharding and every paged test share.
+- Each program holds one slot's query rows and walks the slot's pages
+  with an **online-softmax** carry per kv head (running max /
+  denominator / f32 accumulator in VMEM scratch, the same recurrence as
+  ops/pallas_attention's flash kernel) — a static loop over the kv
+  heads, each scoring its ``G = H // Hkv`` query heads (GQA runs
+  natively against the compact KV) — writing the attention output once
+  on the last page-step.
 - Validity is the SAME additive bias row the jnp path uses
   (``0`` / ``NEG_INF`` per logical position, from the slot's ``valid``
   lane), so sentinel pages — clamped to page 0 for the DMA — contribute
@@ -34,10 +45,12 @@ in shard_map under a serve mesh — KV pools and attention heads shard on
 ``tp`` (serve/layouts.py) and a bare Mosaic custom call has no GSPMD
 rule, so the wrapper is what keeps tp=2 greedy parity (PR 11) intact.
 
-CPU/tier-1: ``interpret=True`` (forced off-TPU, overridable for tests)
-runs the same kernel logic through the Pallas interpreter — the
-``make kernels`` target and tests/test_paged_kernel.py exercise it
-without hardware.
+Off-TPU the same kernel logic runs through the Pallas interpreter
+(ops/pallas_mode.py decides, never a caller) — the ``make kernels``
+target and tests/test_paged_kernel.py exercise it without hardware;
+tests/test_kernel_lowering.py lowers and compiles it for the TPU from
+the CPU host, and chip_smoke.py checks it against the jnp path on the
+chip.
 """
 
 import functools
@@ -46,6 +59,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from trlx_tpu.ops import pallas_mode
 
 NEG_INF = -1e9  # matches trlx_tpu.models.transformer.NEG_INF
 
@@ -59,10 +74,10 @@ def _decode_kernel(
     # scalar prefetch
     pt_ref,  # [S, max_pages] int32 page table (host data)
     # tensor operands (per-block views; see BlockSpecs below)
-    q_ref,  # [1, G, hd] this slot's query row, one kv-head group
-    k_ref,  # [1, page_size, 1, hd] the page the index map gathered
-    v_ref,  # [1, page_size, 1, hd]
-    bias_ref,  # [1, 1, page_size] additive 0/NEG_INF validity bias
+    q_ref,  # [1, Hkv, G, hd] this slot's query rows, grouped by kv head
+    k_ref,  # [1, page_size, Hkv, hd] the page the index map gathered
+    v_ref,  # [1, page_size, Hkv, hd]
+    bias_ref,  # [1, 1, 1, page_size] additive 0/NEG_INF validity bias
     *rest,  # (k_scale_ref, v_scale_ref when quantized), o_ref, scratch
     quantized: bool,
 ):
@@ -70,8 +85,8 @@ def _decode_kernel(
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
-    p = pl.program_id(2)
-    hd = q_ref.shape[-1]
+    p = pl.program_id(1)
+    Hkv, hd = q_ref.shape[1], q_ref.shape[3]
 
     @pl.when(p == 0)
     def _init():
@@ -79,45 +94,48 @@ def _decode_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0]  # [G, hd], compute dtype
-    k = k_ref[0, :, 0, :]  # [page_size, hd]
-    v = v_ref[0, :, 0, :]
-    if quantized:
-        # fused dequant: int8 codes x per-(row, head) f32 scale, cast to
-        # the compute dtype the jnp oracle dequantizes to
-        k = (k.astype(jnp.float32) * ks_ref[0, :, 0][:, None]).astype(
-            q.dtype
-        )
-        v = (v.astype(jnp.float32) * vs_ref[0, :, 0][:, None]).astype(
-            q.dtype
-        )
-    s = jax.lax.dot_general(
-        q, k,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [G, page_size]
     scale = jax.lax.rsqrt(jnp.float32(hd))
-    s = s * scale + bias_ref[0]  # bias [1, page_size] broadcasts over G
+    bias = bias_ref[0, 0]  # [1, page_size], broadcasts over G
+    for h in range(Hkv):  # static: one online-softmax carry per kv head
+        q = q_ref[0, h]  # [G, hd], compute dtype
+        k = k_ref[0, :, h, :]  # [page_size, hd]
+        v = v_ref[0, :, h, :]
+        if quantized:
+            # fused dequant: int8 codes x per-(row, head) f32 scale, cast
+            # to the compute dtype the jnp oracle dequantizes to
+            k = (k.astype(jnp.float32) * ks_ref[0, :, h][:, None]).astype(
+                q.dtype
+            )
+            v = (v.astype(jnp.float32) * vs_ref[0, :, h][:, None]).astype(
+                q.dtype
+            )
+        s = jax.lax.dot_general(
+            q, k,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [G, page_size]
+        s = s * scale + bias
 
-    m_prev = m_scr[:, :1]  # [G, 1]
-    l_prev = l_scr[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    probs = jnp.exp(s - m_new)
-    l_new = alpha * l_prev + probs.sum(axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        probs.astype(v.dtype), v,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_prev = m_scr[h][:, :1]  # [G, 1]
+        l_prev = l_scr[h][:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        probs = jnp.exp(s - m_new)
+        l_new = alpha * l_prev + probs.sum(axis=-1, keepdims=True)
+        acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+            probs.astype(v.dtype), v,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+        l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
-    @pl.when(p == pl.num_programs(2) - 1)
+    @pl.when(p == pl.num_programs(1) - 1)
     def _finish():
-        o_ref[0] = (
-            acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
-        ).astype(o_ref.dtype)
+        for h in range(Hkv):
+            o_ref[0, h] = (
+                acc_scr[h] / jnp.maximum(l_scr[h][:, :1], 1e-30)
+            ).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -126,7 +144,6 @@ def paged_decode_attention(
     v_pages,
     page_table: jnp.ndarray,
     bias: jnp.ndarray,
-    interpret=None,
 ) -> jnp.ndarray:
     """One fused decode step of paged attention.
 
@@ -160,63 +177,55 @@ def paged_decode_attention(
     if H % Hkv:
         raise ValueError(f"H={H} not a multiple of Hkv={Hkv}")
     G = H // Hkv
-    bias3 = bias.reshape(S, max_pages, page_size).astype(jnp.float32)
+    # query heads for kv-head h are the contiguous block [h*G, (h+1)*G)
+    # — the same grouping attention_scores' GQA reshape uses
+    q4 = q.reshape(S, Hkv, G, hd)
+    bias4 = bias.reshape(S, max_pages, 1, page_size).astype(jnp.float32)
 
-    def page_of(s, h, p, pt):
+    def page_of(s, p, pt):
         # sentinel (>= num_pages) clamps to page 0: a real DMA target
         # whose contribution the bias then zeroes — mirrors the jnp
         # path's jnp.clip gather
         pid = pt[s, p]
         return jnp.where(pid < num_pages, pid, 0)
 
+    def pool_spec(*tail):
+        return pl.BlockSpec(
+            (1, page_size, *tail),
+            lambda s, p, pt: (page_of(s, p, pt), *([0] * (len(tail) + 1))),
+        )
+
+    q_spec = pl.BlockSpec((1, Hkv, G, hd), lambda s, p, pt: (s, 0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, G, hd), lambda s, h, p, pt: (s, h, 0)),
-        pl.BlockSpec(
-            (1, page_size, 1, hd),
-            lambda s, h, p, pt: (page_of(s, h, p, pt), 0, h, 0),
-        ),
-        pl.BlockSpec(
-            (1, page_size, 1, hd),
-            lambda s, h, p, pt: (page_of(s, h, p, pt), 0, h, 0),
-        ),
-        pl.BlockSpec((1, 1, page_size), lambda s, h, p, pt: (s, p, 0)),
+        q_spec,
+        pool_spec(Hkv, hd),
+        pool_spec(Hkv, hd),
+        pl.BlockSpec((1, 1, 1, page_size), lambda s, p, pt: (s, p, 0, 0)),
     ]
-    # query heads for kv-head h are the contiguous block [h*G, (h+1)*G)
-    # — the same grouping attention_scores' GQA reshape uses
-    operands = [q, k_codes, v_codes, bias3]
+    operands = [q4, k_codes, v_codes, bias4]
     if quantized:
-        in_specs += [
-            pl.BlockSpec(
-                (1, page_size, 1),
-                lambda s, h, p, pt: (page_of(s, h, p, pt), 0, h),
-            ),
-            pl.BlockSpec(
-                (1, page_size, 1),
-                lambda s, h, p, pt: (page_of(s, h, p, pt), 0, h),
-            ),
-        ]
+        in_specs += [pool_spec(Hkv), pool_spec(Hkv)]
         operands += [k_scales, v_scales]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(S, Hkv, max_pages),
+        grid=(S, max_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, G, hd), lambda s, h, p, pt: (s, h, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((G, 128), jnp.float32),  # running max (lane-bcast)
-            pltpu.VMEM((G, 128), jnp.float32),  # running denominator
-            pltpu.VMEM((G, hd), jnp.float32),  # f32 output accumulator
+            pltpu.VMEM((Hkv, G, 128), jnp.float32),  # running max (lane-bcast)
+            pltpu.VMEM((Hkv, G, 128), jnp.float32),  # running denominator
+            pltpu.VMEM((Hkv, G, hd), jnp.float32),  # f32 output accumulator
         ],
     )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     out = pl.pallas_call(
         functools.partial(_decode_kernel, quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, hd), q.dtype),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((S, Hkv, G, hd), q.dtype),
+        interpret=pallas_mode.interpret(),
+        name="paged_decode_attention",
     )(page_table.astype(jnp.int32), *operands)
-    return out
+    return out.reshape(S, H, hd)
 
 
 # --------------------------------------------------------------------- #
@@ -224,7 +233,7 @@ def paged_decode_attention(
 # --------------------------------------------------------------------- #
 
 
-def make_paged_decode_fn(mesh=None, interpret=None):
+def make_paged_decode_fn(mesh=None):
     """Adapter for ``transformer.block_apply(paged_decode_fn=...)``.
 
     The returned fn has the seam's contract — ``fn(q1, k_pages, v_pages,
@@ -236,25 +245,13 @@ def make_paged_decode_fn(mesh=None, interpret=None):
     host-shaped data. Heads tp doesn't divide fall back to replication,
     matching ``layouts._fit_spec_to_shape``.
     """
-    try:  # jax >= 0.8
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    import inspect
-
-    _check_kw = (
-        "check_vma"
-        if "check_vma" in inspect.signature(shard_map).parameters
-        else "check_rep"
-    )
 
     def paged_decode(q1, k_pages, v_pages, page_table, bias_row):
         if mesh is None or mesh.size == 1:
             return paged_decode_attention(
-                q1, k_pages, v_pages, page_table, bias_row,
-                interpret=interpret,
+                q1, k_pages, v_pages, page_table, bias_row
             )
         quantized = isinstance(k_pages, (tuple, list))
         Hkv = (k_pages[0] if quantized else k_pages).shape[2]
@@ -266,16 +263,14 @@ def make_paged_decode_fn(mesh=None, interpret=None):
         kv_spec = (pool_spec, P(None, None, head_ax)) if quantized \
             else pool_spec
         return shard_map(
-            lambda q, k, v, pt, b: paged_decode_attention(
-                q, k, v, pt, b, interpret=interpret
-            ),
+            paged_decode_attention,
             mesh=mesh,
             in_specs=(q_spec, kv_spec, kv_spec, P(None, None),
                       P(None, None)),
             out_specs=q_spec,
             # pallas_call's out_shape carries no varying-mesh-axes type;
-            # skip the vma/rep check for this purely per-shard kernel
-            **{_check_kw: False},
+            # skip the vma check for this purely per-shard kernel
+            check_vma=False,
         )(q1, k_pages, v_pages, page_table, bias_row)
 
     return paged_decode

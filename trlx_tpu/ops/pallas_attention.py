@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from trlx_tpu.ops import pallas_mode
+
 NEG_INF = -1e9  # matches trlx_tpu.models.transformer.NEG_INF
 
 
@@ -189,7 +191,7 @@ def _flash_forward(q, k, v, kv_mask, block_q, block_k, causal):
             jax.ShapeDtypeStruct((B * H, Tp, hd), q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, Tp), jnp.float32),
         ],
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_mode.interpret(),
     )(qf, kf, vf, maskf[:, None, :])
 
     out = out.reshape(B, H, Tp, hd).transpose(0, 2, 1, 3)[:, :T]
@@ -360,7 +362,7 @@ def _flash_backward(res, g, block_q, block_k, causal):
     )
     maskf = pad(kv_mask)[:, None, :]  # [B, 1, Tp]
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_mode.interpret()
     full = lambda: pl.BlockSpec(  # noqa: E731
         (1, Tp, hd), lambda bh, blk: (bh, 0, 0), memory_space=pltpu.VMEM
     )
@@ -492,21 +494,8 @@ def make_pallas_attention_fn(
     the wrapper a multichip jit would gather the global batch per chip."""
     from trlx_tpu.models.transformer import attention_scores, causal_mask_bias
 
-    try:  # jax >= 0.8
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    # the replication/varying-axes check kwarg was renamed check_rep ->
-    # check_vma across jax versions; resolve whichever this one has
-    import inspect
-
-    _check_kw = (
-        "check_vma"
-        if "check_vma" in inspect.signature(shard_map).parameters
-        else "check_rep"
-    )
 
     min_t = _MIN_FUSED_T if min_fused_t is None else min_fused_t
 
@@ -534,8 +523,8 @@ def make_pallas_attention_fn(
             in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
             out_specs=qkv_spec,
             # pallas_call's out_shape carries no varying-mesh-axes type;
-            # skip the vma/rep check for this purely per-shard kernel
-            **{_check_kw: False},
+            # skip the vma check for this purely per-shard kernel
+            check_vma=False,
         )(q, k, v, attention_mask)
 
     pallas_attention.takes_raw_mask = True

@@ -44,12 +44,8 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.5 re-exports shard_map at top level; 0.4.x does not
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from trlx_tpu.data.configs import ModelSpec
 from trlx_tpu.models.transformer import apply_blocks, attention_scores

@@ -27,12 +27,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 NEG_INF = -1e9  # matches trlx_tpu.models.transformer.NEG_INF
 
@@ -123,7 +119,7 @@ def _ring_attention_local(
 
     # initial accumulators derived from q (not jnp.zeros) so they carry q's
     # varying-mesh-axes type — scan carries must keep a consistent vma type
-    # under shard_map (jax >= 0.8 typing rule)
+    # under shard_map
     base = jnp.swapaxes(q, 1, 2).astype(jnp.float32) * 0.0  # [B, H, Tc, hd]
     # local block first, then n-1 rotations — the final block is consumed
     # without a further (wasted) ppermute hop

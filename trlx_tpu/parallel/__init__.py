@@ -45,6 +45,7 @@ from trlx_tpu.parallel.sharding import (  # noqa: F401
 )
 from trlx_tpu.parallel.runtime import (  # noqa: F401
     broadcast_host_floats,
+    device_summary,
     initialize_runtime,
     is_main_process,
     process_count,

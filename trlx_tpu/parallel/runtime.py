@@ -14,10 +14,22 @@ need no further setup — they are compiled into the SPMD program.
 """
 
 import os
+import sys
 
 import jax
 
 _initialized = False
+
+
+def device_summary() -> str:
+    """``platform=... device_kind=... devices=N`` as JAX reports them —
+    the line every entry point logs once at start, so a run that landed
+    on the wrong backend says so in its first lines."""
+    devices = jax.devices()
+    return (
+        f"platform={devices[0].platform} "
+        f"device_kind={devices[0].device_kind!r} devices={len(devices)}"
+    )
 
 
 def initialize_runtime(coordinator_address: str = None,
@@ -46,6 +58,8 @@ def initialize_runtime(coordinator_address: str = None,
             process_id=process_id,
         )
     _initialized = True
+    # once per process (the trainers' constructor lands here first)
+    print(f"[trlx_tpu] {device_summary()}", file=sys.stderr, flush=True)
 
 
 def process_count() -> int:

@@ -226,32 +226,6 @@ def shard_batch(mesh: Mesh, tree):
     return jax.device_put(tree, batch_sharding(mesh))
 
 
-def _layout_format_factory():
-    """``(major_to_minor, sharding) -> device_put target`` across the jax
-    layout-API rename, or None when neither spelling exists.
-
-    jax >= 0.5 spells it ``Format(Layout(major_to_minor=...), sharding)``;
-    0.4.x spells the same pair ``Layout(DeviceLocalLayout(major_to_minor=
-    ...), sharding)``. Older/stripped builds expose neither — the caller
-    must then skip the relayout instead of dying at import time (this is
-    a size-gated optimization, never a correctness requirement)."""
-    try:
-        from jax.experimental.layout import Format, Layout
-
-        return lambda m2m, sharding: Format(
-            Layout(major_to_minor=m2m), sharding
-        )
-    except ImportError:
-        try:
-            from jax.experimental.layout import DeviceLocalLayout, Layout
-
-            return lambda m2m, sharding: Layout(
-                DeviceLocalLayout(major_to_minor=m2m), sharding
-            )
-        except ImportError:
-            return None
-
-
 def relayout_for_decode(params: Params,
                         min_bytes: int = 2 << 30) -> Params:
     """Frozen-trunk attention projections (wq/wk/wv) moved to the
@@ -269,8 +243,8 @@ def relayout_for_decode(params: Params,
     copies are re-materialized HBM traffic on every rollout dispatch.
 
     Only the AOT compile path honors custom layouts, and its
-    Compiled.call dispatch skips jit's C++ fastpath — ~seconds per
-    dispatch on tunneled runtimes. That trade only pays when the copies
+    Compiled.call dispatch skips jit's C++ fastpath (a Python-side
+    signature hash per call). That trade only pays when the copies
     rival HBM headroom, so the pass is SIZE-GATED: a no-op (same object
     returned — callers key the aot_jit decision on identity) unless the
     target stacks total at least `min_bytes` (default 2 GiB: gpt-j-6B's
@@ -279,14 +253,11 @@ def relayout_for_decode(params: Params,
     frozen subtree through unchanged, so the layout survives updates.
     Checkpoint restore rebuilds default layouts — callers re-apply after
     a restore if they care. DONATES the source stacks (the caller's
-    input tree must be re-bound from the return value); degrades
-    gracefully — with a warning — when the runtime rejects the
-    relayout, keeping whatever moved."""
-    make_format = _layout_format_factory()
-    if make_format is None:
-        # jax versions without a usable custom-layout API: the pass is a
-        # no-op (same-object return keeps callers on the fast jit path)
-        return params
+    input tree must be re-bound from the return value). A runtime that
+    refuses the relayout raises: past the size gate the layout is the
+    margin between fitting and OOM, so a silent default-layout run
+    would only fail later and further from the cause."""
+    from jax.experimental.layout import Format, Layout
 
     blocks = params.get("frozen_base", {}).get("blocks")
     if not blocks or "attn" not in blocks:
@@ -317,27 +288,14 @@ def relayout_for_decode(params: Params,
     # one leaf at a time WITH source donation: near the HBM limit the
     # whole-tree form holds old + new copies of all three stacks at once
     # (+2.6 GB at gpt-j-6B — itself an OOM); donating bounds the peak to
-    # one extra stack. A partial success keeps whatever moved (each moved
-    # leaf is a complete, valid array).
-    moved = {}
-    for name, x in targets.items():
-        try:
-            moved[name] = jax.device_put(
-                x, make_format((0, 2, 1), x.sharding),
-                donate=True,
-            )
-        except Exception as e:  # noqa: BLE001 - capability probe by doing
-            import warnings
-
-            warnings.warn(
-                f"relayout_for_decode: could not relayout '{name}' "
-                f"({type(e).__name__}: {str(e)[:200]}); decode keeps the "
-                f"default layout for it",
-                stacklevel=2,
-            )
-            break
-    if not moved:
-        return params
+    # one extra stack.
+    moved = {
+        name: jax.device_put(
+            x, Format(Layout(major_to_minor=(0, 2, 1)), x.sharding),
+            donate=True,
+        )
+        for name, x in targets.items()
+    }
     new_attn = {**attn, **moved}
     return {
         **params,

@@ -216,6 +216,12 @@ def serve_config_from_args(args) -> ServeConfig:
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     serve_cfg = serve_config_from_args(args)
+    from trlx_tpu.parallel import device_summary
+    from trlx_tpu.utils.compile_cache import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    print(f"[trlx_tpu.serve] {device_summary()} compile_cache={cache_dir}",
+          file=sys.stderr, flush=True)
     engine = InferenceEngine.from_checkpoint(
         args.checkpoint, config=args.config, serve=serve_cfg
     )

@@ -1,15 +1,16 @@
 """Device monitor: HBM occupancy gauges from ``memory_stats()``.
 
-Sampled at metric-emission boundaries (not per step): ``memory_stats()``
-is a cheap local call on directly-attached runtimes, but tunneled/remote
-runtimes may not expose it at all — the first failure latches and the
-monitor stays silent for the rest of the process instead of re-raising
-(or re-trying) on every log interval.
+Sampled at metric-emission boundaries (not per step). The CPU backend
+reports no memory stats: the first empty sample there latches the
+monitor silent for the rest of the process. On any other backend an
+empty or failing ``memory_stats()`` is an error — the HBM gauges are
+what the trainers' memory-fit check and the serve sizing rest on, and a
+TPU run without them must not look like a healthy one.
 """
 
 from trlx_tpu.telemetry.registry import MetricsRegistry
 
-_available = True  # latches False on the first failed sample
+_available = True  # latches False on the CPU backend's first empty sample
 
 _GAUGES = {
     "bytes_in_use": "device/hbm_in_use_gb",
@@ -22,14 +23,17 @@ def sample_device_stats(registry: MetricsRegistry) -> None:
     global _available
     if not _available:
         return
-    try:
-        import jax
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats() or {}
-    except Exception:
-        _available = False
-        return
+    device = jax.local_devices()[0]
+    stats = device.memory_stats()
     if not stats:
+        if device.platform != "cpu":
+            raise RuntimeError(
+                f"{device.platform} device '{device.device_kind}' reports "
+                f"no memory_stats(); the device/hbm_* gauges cannot be "
+                f"sampled"
+            )
         _available = False
         return
     for key, gauge in _GAUGES.items():

@@ -16,9 +16,8 @@ XLA-compile cost.
 Durations are HOST wall-clock between span entry and exit. JAX dispatch
 is asynchronous, so a span around a dispatch measures trace/compile/
 enqueue time — device execution lands in whichever later span first
-blocks on the result (typically the metrics fetch). That asymmetry is
-exactly the signal that matters on tunneled/remote runtimes, where
-dispatch latency — not device time — dominates the loop.
+blocks on the result (typically the metrics fetch). Read a span as
+"what the host waited for", not as device time.
 
 Every span also feeds the metrics registry: a ``time/<name>`` histogram
 observation, and a ``compile/<name>_first_s`` gauge on the first call.

@@ -39,7 +39,7 @@ from trlx_tpu.ops.losses import ilql_losses_chunked
 from trlx_tpu.ops.sampling import SamplingParams, warp_top_k
 from trlx_tpu.trainers import BaseRLTrainer, register_trainer
 from trlx_tpu.utils import Clock, rampup_decay_schedule
-from trlx_tpu.utils.aotjit import aot_jit, formats_of
+from trlx_tpu.utils.aotjit import aot_jit
 from trlx_tpu.utils.tokenizer import load_tokenizer
 from trlx_tpu.utils.trackers import make_tracker, samples_table
 
@@ -250,36 +250,26 @@ class JaxILQLTrainer(BaseRLTrainer):
         def train_step_indexed(params, opt_state, dataset: ILQLBatch, idx):
             """Train on dataset rows `idx` — the dataset stays device-
             resident across the whole run and the host sends only a [B]
-            index array per step (a sync on tunneled/remote devices costs
-            ~100 ms regardless of payload, so per-batch uploads dominate
-            the loop otherwise)."""
+            index array per step instead of uploading every batch."""
             batch = jax.tree_util.tree_map(lambda x: x[idx], dataset)
             return train_step(params, opt_state, batch)
 
-        # plain jit (fast C++ dispatch) unless the 6B-class relayout
-        # engaged — then the AOT path + pinned output formats keep the
-        # custom at-rest layouts alive across donated updates (see the
-        # PPO trainer's identical note)
-        if self._layout_faithful:
-            params_fmt = formats_of(self.params)
-            opt_fmt = formats_of(self.opt_state)
-            self._train_step = aot_jit(
-                train_step, donate_argnums=(0, 1),
-                out_shardings=(params_fmt, opt_fmt, None),
-            )
-            self._train_step_indexed = aot_jit(
-                train_step_indexed, donate_argnums=(0, 1),
-                out_shardings=(params_fmt, opt_fmt, None),
-            )
-            self._sync = aot_jit(
-                lambda p: sync_targets(p, m.alpha), out_shardings=params_fmt
-            )
-        else:
-            self._train_step = jax.jit(train_step, donate_argnums=(0, 1))
-            self._train_step_indexed = jax.jit(
-                train_step_indexed, donate_argnums=(0, 1)
-            )
-            self._sync = jax.jit(lambda p: sync_targets(p, m.alpha))
+        # plain jit, or the AOT path with pinned output formats when the
+        # relayout engaged, or plain jit with pinned output shardings
+        # under a mesh (BaseRLTrainer._step_jit)
+        jit_, pin = self._step_jit()
+        params_out = pin and pin(self.params)
+        train_out = pin and (params_out, pin(self.opt_state), None)
+        self._train_step = jit_(
+            train_step, donate_argnums=(0, 1), out_shardings=train_out
+        )
+        self._train_step_indexed = jit_(
+            train_step_indexed, donate_argnums=(0, 1),
+            out_shardings=train_out,
+        )
+        self._sync = jit_(
+            lambda p: sync_targets(p, m.alpha), out_shardings=params_out
+        )
         self._generate_fn = generate_fn
         self._generate_jitted = {}
 
@@ -320,8 +310,7 @@ class JaxILQLTrainer(BaseRLTrainer):
     def act(self, batch):
         query, mask = batch
         out = self.generate(query, mask)
-        # one batched device->host fetch (round trips dominate on tunneled
-        # device topologies)
+        # one batched device->host fetch, not one per field
         sequences, gen_tokens = jax.device_get(
             (out.sequences, out.gen_tokens)
         )

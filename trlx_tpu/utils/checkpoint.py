@@ -89,16 +89,39 @@ def _is_array_tree(obj: Any) -> bool:
     )
 
 
-def _has_empty_leaf(obj: Any) -> bool:
-    """Any zero-size array leaf — e.g. ILQL's ``frozen_base.blocks`` at
-    ``num_layers_unfrozen: -1`` (everything trainable, zero frozen
-    layers). Orbax's ocdbt backend writes nothing for them and then fails
-    its own post-save validation ("N params are missing in checkpoint");
-    such trees go through the per-param (non-ocdbt) writer, whose format
-    the default reader restores transparently."""
-    return any(
-        getattr(x, "size", 1) == 0 for x in jax.tree_util.tree_leaves(obj)
+def _is_empty(x: Any) -> bool:
+    return getattr(x, "size", 1) == 0
+
+
+def _empty_leaves_out(tree: Any) -> Any:
+    """``tree`` with every zero-size array leaf — e.g. the hydra's
+    ``frozen_base.blocks`` when every layer is unfrozen (ILQL's shipped
+    ``num_layers_unfrozen: -1``: zero frozen layers) — swapped for a
+    0-d int8 placeholder. Orbax refuses zero-size arrays outright
+    ("Cannot save arrays with zero size"), and they hold no bytes to
+    save: the placeholder keeps the tree structure on disk, and
+    :func:`_empty_leaves_back` rebuilds the real leaves from the restore
+    template's shapes."""
+    return jax.tree_util.tree_map(
+        lambda x: np.zeros((), np.int8) if _is_empty(x) else x, tree
     )
+
+
+def _empty_leaves_back(template: Any, restored: Any, shardings: Any = None):
+    """Inverse of :func:`_empty_leaves_out` after a restore: wherever
+    ``template`` has a zero-size leaf, a zero-size array of its shape and
+    dtype (placed under the matching ``shardings`` entry when given)
+    replaces the placeholder orbax read back."""
+
+    def back(t, r, sh=None):
+        if not _is_empty(t):
+            return r
+        empty = np.zeros(t.shape, t.dtype)
+        return jax.device_put(empty, sh) if sh is not None else empty
+
+    if shardings is None:
+        return jax.tree_util.tree_map(back, template, restored)
+    return jax.tree_util.tree_map(back, template, restored, shardings)
 
 
 def _main_process() -> bool:
@@ -323,13 +346,13 @@ def save_components(components: Dict[str, Any], directory: str) -> None:
             shutil.rmtree(staging)  # leftover from a previous crashed save
         os.makedirs(staging)
         meta = {}
-        with ocp.PyTreeCheckpointer() as ckptr, ocp.PyTreeCheckpointer(
-            use_ocdbt=False
-        ) as plain_ckptr:
+        with ocp.PyTreeCheckpointer() as ckptr:
             for name, obj in components.items():
                 if _is_array_tree(obj):
-                    writer = plain_ckptr if _has_empty_leaf(obj) else ckptr
-                    writer.save(os.path.join(staging, name), obj, force=True)
+                    ckptr.save(
+                        os.path.join(staging, name), _empty_leaves_out(obj),
+                        force=True,
+                    )
                 else:
                     meta[name] = obj
         # integrity manifest over everything the writers produced (built
@@ -541,12 +564,13 @@ def restore_components(template: Dict[str, Any], directory: str) -> Dict[str, An
                 # restore WITH the template's shardings: arrays land
                 # directly on the current mesh (and reshard correctly when
                 # restoring onto a different topology than the save ran on)
+                item = _empty_leaves_out(obj)
                 restore_args = ocp.checkpoint_utils.construct_restore_args(
-                    obj
+                    item
                 )
-                out[name] = ckptr.restore(
-                    path, item=obj, restore_args=restore_args
-                )
+                out[name] = _empty_leaves_back(obj, ckptr.restore(
+                    path, item=item, restore_args=restore_args
+                ))
             else:
                 out[name] = meta[name]
     from trlx_tpu import telemetry
@@ -582,7 +606,8 @@ def restore_component_sharded(
             f"(found on disk: {sorted(os.listdir(resolved))})"
         )
     restore_args = jax.tree_util.tree_map(
-        lambda sds, sh: ocp.ArrayRestoreArgs(
+        lambda sds, sh: ocp.RestoreArgs() if _is_empty(sds)
+        else ocp.ArrayRestoreArgs(
             sharding=sh, dtype=getattr(sds, "dtype", None)
         ),
         template, shardings,
@@ -592,8 +617,10 @@ def restore_component_sharded(
         # what makes the ITEM-IS-A-SUBSET restore legal (without it the
         # tree structures must match exactly)
         out = ckptr.restore(
-            path, item=template, restore_args=restore_args, transforms={}
+            path, item=_empty_leaves_out(template),
+            restore_args=restore_args, transforms={},
         )
+    out = _empty_leaves_back(template, out, shardings)
     from trlx_tpu import telemetry
 
     telemetry.inc("checkpoint/restores")

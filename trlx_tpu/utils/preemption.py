@@ -31,9 +31,9 @@ class PreemptionGuard:
         self._enabled = enabled
         self._prev = None
         self._installed = False
-        # Cross-process agreement runs a collective; on high-dispatch-latency
-        # runtimes (~100ms/sync through a tunnel) doing that EVERY step can
-        # dwarf small-model step time. Callers pass a deterministic interval
+        # Cross-process agreement runs a collective and a host sync; doing
+        # that EVERY step puts a pipeline bubble into small-model steps
+        # (gpt2-124M: 29 ms a step on a v5e — chip_smoke.py). Callers pass a deterministic interval
         # (trainers use min(train.log_interval, 8) — capped so worst-case
         # detection lag stays within eviction grace windows) so all ranks
         # hit the allgather at the same boundaries and skip it in between.
