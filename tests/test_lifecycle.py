@@ -287,10 +287,21 @@ def test_drain_under_load_e2e(http_engine):
     drain is clean and dumps the flight recorder."""
     registry = telemetry.start().registry
     srv = InferenceServer(http_engine, port=0).start(warmup=True)
+    release = threading.Event()
     try:
         status, _, body = _http(srv.port, "/readyz")
         assert status == 200 and body["ready"] is True
 
+        # hold the decode steps while the drill looks at the draining
+        # server: a toy engine otherwise finishes the burst, drains and
+        # closes its socket under the drill's own requests
+        step = srv.batcher.runtime.step
+
+        def held_step(seed):
+            release.wait(timeout=60.0)
+            return step(seed)
+
+        srv.batcher.runtime.step = held_step
         rows = [[1, 2, 3], [4, 5], [6, 7], [8, 9, 1], [2, 2], [3, 1, 4]]
         out, threads = _burst(srv.port, rows)
         # wait until the engine actually holds live work
@@ -298,6 +309,12 @@ def test_drain_under_load_e2e(http_engine):
         while not srv.batcher._live and time.monotonic() < deadline:
             time.sleep(0.01)
         assert srv.batcher._live, "burst never reached the slots"
+        # ...and has accepted the whole burst: a request that arrives
+        # once the drain has begun is refused (429), by design
+        while (registry.counters.get("serve/requests", 0.0) < len(rows)
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        assert registry.counters["serve/requests"] == len(rows)
 
         status, _, body = _http(srv.port, "/admin/drain", "POST", {})
         assert status == 202 and body["draining"] is True
@@ -316,6 +333,7 @@ def test_drain_under_load_e2e(http_engine):
         assert "draining" in body["error"]
         assert int(headers["Retry-After"]) >= 1
 
+        release.set()
         for t in threads:
             t.join(timeout=60.0)
         for i, (status, _, body) in enumerate(out):
@@ -328,6 +346,7 @@ def test_drain_under_load_e2e(http_engine):
         assert registry.counters["serve/flight_dumps"] >= 1.0
         assert registry.counters.get("serve/request_errors", 0.0) == 0.0
     finally:
+        release.set()
         srv.stop()
         telemetry.start()
 

@@ -248,7 +248,7 @@ def test_trace_static_decode_approximation(fresh_registry):
 
 def test_trace_perfetto_export_parses_and_nests(fresh_registry, tmp_path):
     tel = telemetry.current()
-    t0 = tel.tracer.t0_monotonic
+    t0 = tel.tracer.t0
     tr = RequestTrace(trace_id="feed", received=t0 + 1.0)
     tr.enqueued = t0 + 1.0
     tr.admitted = t0 + 1.5

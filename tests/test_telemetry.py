@@ -100,7 +100,7 @@ def test_span_nesting_and_chrome_trace_jsonl(tmp_path):
     # first occurrence of each name is flagged (compile attribution)
     assert outer.get("args", {}).get("first_call") is True
     assert inners[0].get("args", {}).get("first_call") is True
-    assert "args" not in inners[1]
+    assert inners[1]["args"] == {"parent": "rollout"}
     # spans fed the registry: time/* histograms + compile/* first gauges
     assert reg.hists["time/rollout"].count == 1
     assert reg.hists["time/reward_fn"].count == 2
@@ -328,6 +328,32 @@ def test_smoke_learn_writes_summary_and_valid_trace(smoke_run):
         assert ev["ts"] >= 0 and ev["dur"] >= 0
         names.add(ev["name"])
     assert {"rollout", "reward_fn", "ppo_update"} <= names
+
+
+def test_smoke_learn_names_the_ppo_cycle_phase_by_phase(smoke_run):
+    """The rollout span splits into fetch / decode_text / reward_fn /
+    store; the dispatch has a span of its own; the wait for the update
+    program has a name (``ppo_stats_fetch``) right after ``ppo_update``'s
+    dispatch."""
+    tmp, _ = smoke_run
+    events = [json.loads(line)
+              for line in open(os.path.join(tmp, "trace.jsonl"))]
+    by = {}
+    for ev in events:
+        by.setdefault(ev["name"], []).append(ev)
+    for child in ("rollout_fetch", "rollout_decode_text", "reward_fn",
+                  "rollout_store"):
+        assert len(by[child]) == 2 * len(by["rollout"])  # one a chunk
+        assert all(e["args"]["parent"] == "rollout" for e in by[child])
+    assert len(by["rollout_dispatch"]) == len(by["rollout"])
+    assert all(e.get("args", {}).get("parent") in (None, "rollout_refresh")
+               for e in by["rollout_dispatch"])
+    # log_interval 1: every update is followed by its fetch
+    assert len(by["ppo_stats_fetch"]) == len(by["ppo_update"]) >= 2
+    for update, fetch in zip(by["ppo_update"], by["ppo_stats_fetch"]):
+        assert fetch["ts"] >= update["ts"] + update["dur"] - 0.01
+        assert "parent" not in fetch.get("args", {})
+    assert "rollout_dispatch_stale" not in by and "rollout_harvest" not in by
 
 
 def test_trainer_with_telemetry_false_records_nothing():

@@ -166,11 +166,14 @@ class PPOOrchestrator(Orchestrator):
                 f"{n_chunks * self.chunk_size} rollouts",
                 stacklevel=2,
             )
+        from trlx_tpu.utils.profiling import annotate
+
         bank_tokens, bank_mask = self._prompt_bank()
-        pendings = [
-            trainer.rollout(bank_tokens, bank_mask, self._next_idx())
-            for _ in range(n_chunks)
-        ]
+        with annotate("rollout_dispatch"):
+            pendings = [
+                trainer.rollout(bank_tokens, bank_mask, self._next_idx())
+                for _ in range(n_chunks)
+            ]
         return {"pendings": pendings, "n_chunks": n_chunks}
 
     def finish_experience(self, handle):
@@ -195,7 +198,7 @@ class PPOOrchestrator(Orchestrator):
             return self._finish_experience(handle)
 
     def _finish_experience(self, handle):
-        from trlx_tpu import supervisor
+        from trlx_tpu import supervisor, telemetry
         from trlx_tpu.supervisor import chaos
         from trlx_tpu.utils.profiling import annotate
 
@@ -254,18 +257,20 @@ class PPOOrchestrator(Orchestrator):
 
             # THE one (blocking) device->host fetch per chunk; the async
             # copy above usually has it staged already
-            sequences, seq_kl_host, scores_host = jax.device_get(
-                fetch_trees[i]
-            )
+            with telemetry.span("rollout_fetch"):
+                sequences, seq_kl_host, scores_host = jax.device_get(
+                    fetch_trees[i]
+                )
             if i + 1 < n_chunks:
                 start_fetch(i + 1)
 
             if device_reward:
                 scores = np.asarray(scores_host, np.float32)
             else:
-                texts = trainer.tokenizer.batch_decode(
-                    sequences, skip_special_tokens=True
-                )
+                with telemetry.span("rollout_decode_text"):
+                    texts = trainer.tokenizer.batch_decode(
+                        sequences, skip_special_tokens=True
+                    )
                 with annotate("reward_fn"):
                     scores = self.score(texts)
             all_scores.append(scores)
@@ -273,21 +278,21 @@ class PPOOrchestrator(Orchestrator):
             # score lands on each row's last REAL response token (parity:
             # reference ppo_orchestrator.py:92), computed ON DEVICE — the
             # tiny scores array rides the dispatch
-            rewards = trainer.finalize_rewards(kl_rewards, out.gen_mask,
-                                               scores)
+            with telemetry.span("rollout_store"):
+                rewards = trainer.finalize_rewards(
+                    kl_rewards, out.gen_mask, scores
+                )
+                trainer.push_to_store(PPORLBatch(
+                    query_tensors=query,
+                    response_tensors=out.gen_tokens,
+                    logprobs=logprobs,
+                    values=values,
+                    rewards=rewards,
+                    response_masks=out.gen_mask,
+                    query_masks=qmask,
+                ))
             mean_kl = float(seq_kl_host.mean())
             all_kls.append(mean_kl)
-
-            batch = PPORLBatch(
-                query_tensors=query,
-                response_tensors=out.gen_tokens,
-                logprobs=logprobs,
-                values=values,
-                rewards=rewards,
-                response_masks=out.gen_mask,
-                query_masks=qmask,
-            )
-            trainer.push_to_store(batch)
             self.clock.tick(len(sequences))
             # per-chunk progress heartbeat: a multi-minute harvest of many
             # chunks is healthy as long as chunks keep landing

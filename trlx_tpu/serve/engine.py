@@ -1027,6 +1027,8 @@ class InferenceEngine:
                     compute_dtype=compute, unroll_layers=unroll,
                 )
 
+            # the program's name in a device trace, as its span's
+            run.__name__ = f"run_generate_b{B}p{P}g{G}"
             fn = self._decode_fns[bucket] = aot_jit(run)
         return fn
 

@@ -197,6 +197,7 @@ class SlotPoolRuntime:
             if engine.serve.speculation != "off" else 0
         )
         self._verify_step_fn = None
+        self.fetch_s = 0.0  # the last step's wait for its result (_fetch)
         self.warmed = False
 
     def _view_shardings(self):
@@ -242,6 +243,10 @@ class SlotPoolRuntime:
                         mask, slot_ids, max_new, compute_dtype=compute,
                     )
 
+            # the program's name in a device trace (jit_run_prefill_b4p128),
+            # as its span's: a reader finds it without guessing
+            Bp, P = bucket
+            run.__name__ = f"run_prefill{'_sfx' if suffix else ''}_b{Bp}p{P}"
             # host args (tokens/mask/slot_ids/max_new[/tables/start])
             # replicate; pool + state keep their build shardings in AND
             # out — the step loop's signatures are pinned, so
@@ -295,6 +300,7 @@ class SlotPoolRuntime:
                     paged_decode_fn=paged_decode_fn,
                 )
 
+            run.__name__ = "run_decode_step"
             self._step_fn = aot_jit(
                 run, donate_argnums=(3, 4) if self._donate else (),
                 in_shardings=(
@@ -330,6 +336,7 @@ class SlotPoolRuntime:
                     proposals, n_proposed, cfg, compute_dtype=compute,
                 )
 
+            run.__name__ = "run_verify_step"
             self._verify_step_fn = aot_jit(
                 run, donate_argnums=(3, 4) if self._donate else (),
                 in_shardings=(
@@ -354,6 +361,7 @@ class SlotPoolRuntime:
 
     STEP_SPAN = "serve/slot_step"
     VERIFY_SPAN = "serve/spec_verify"
+    FETCH_SPAN = "serve/step_fetch"  # child of either of the two above
 
     # -- device calls ------------------------------------------------------ #
 
@@ -386,8 +394,6 @@ class SlotPoolRuntime:
     def step(self, seed: int):
         """One decode step for every slot; returns host-side
         (tokens [S], emitted [S], finished [S]) numpy arrays."""
-        import jax
-
         e = self.engine
         fn = self._decode_fn()
         with telemetry.span(self.STEP_SPAN):
@@ -395,7 +401,7 @@ class SlotPoolRuntime:
                 e.blocks, e.embed, e.ln_f, self.pool, self.state,
                 np.int32(seed),
             )
-            return jax.device_get((tok, emitted, finished))
+            return self._fetch((tok, emitted, finished))
 
     def verify(self, seed: int, proposals: np.ndarray,
                n_proposed: np.ndarray):
@@ -403,8 +409,6 @@ class SlotPoolRuntime:
         K proposals + the free token in one batched pass. Returns
         host-side (cand [S, K+1], counts [S], finished [S]) — each
         slot emits ``cand[s, :counts[s]]``."""
-        import jax
-
         e = self.engine
         fn = self._verify_fn()
         with telemetry.span(self.VERIFY_SPAN):
@@ -414,7 +418,20 @@ class SlotPoolRuntime:
                 np.ascontiguousarray(proposals, np.int32),
                 np.asarray(n_proposed, np.int32),
             )
-            return jax.device_get((cand, counts, finished))
+            return self._fetch((cand, counts, finished))
+
+    def _fetch(self, outputs):
+        """The step's blocking device->host fetch: what the host waited
+        for the step's result (``fetch_s``, the flight record's
+        ``fetch_ms``). The programs dispatched before the step (this
+        iteration's prefills) run ahead of it and are waited out here."""
+        import jax
+
+        start = monotonic()
+        with telemetry.span(self.FETCH_SPAN):
+            host = jax.device_get(outputs)
+        self.fetch_s = monotonic() - start
+        return host
 
     def reset_lanes(self) -> None:
         """Fresh all-free per-slot lanes, REUSING the pool buffers — the
@@ -574,6 +591,9 @@ class SlotScheduler:
         # reset by _run after each step's record lands in the ring
         self._fr_admitted = 0
         self._fr_evicted = 0
+        # the current iteration's host phases, for its flight record
+        self._admit_s = 0.0
+        self._harvest_s = 0.0
         # -- speculation (docs "Speculative decoding") ------------------ #
         #: propose -> verify -> accept per step when serve.speculation
         #: is on; per-slot host state lives in _speculators (lookup
@@ -1270,6 +1290,13 @@ class SlotScheduler:
             telemetry.set_gauge(
                 "serve/spec_acceptance_rate", self._spec_acceptance_rate()
             )
+        with telemetry.span("serve/harvest"):
+            self._harvest(cand, counts, finished, span)
+
+    def _harvest(self, cand, counts, finished, span: str) -> None:
+        """Host half of a step: hand each live slot its tokens, complete
+        and free the finished ones. ``_harvest_s`` is the flight record's
+        ``harvest_ms``."""
         done_at = monotonic()
         emitted_total = 0
         for slot in list(self._live):
@@ -1318,6 +1345,7 @@ class SlotScheduler:
                         "serve/tokens_per_sec", emitted_total / hist.last
                     )
         telemetry.set_gauge("serve/slot_occupancy", self._occupancy())
+        self._harvest_s = monotonic() - done_at
 
     def _reset_cache(self) -> None:
         """Fresh allocator + radix tree. The lanes are gone whenever this
@@ -1656,6 +1684,12 @@ class SlotScheduler:
             "admitted": self._fr_admitted,
             "occupancy": round(self._occupancy(), 4),
             "step_ms": round((end - start) * 1000.0, 3),
+            # the iteration's host phases: admission before the step
+            # (prefill dispatches included), the wait for the step's
+            # result, and the harvest after it
+            "admit_ms": round(self._admit_s * 1000.0, 3),
+            "fetch_ms": round(self.runtime.fetch_s * 1000.0, 3),
+            "harvest_ms": round(self._harvest_s * 1000.0, 3),
         }
         if self.cache is not None:
             rec["pages_free"] = self.cache.free_pages()
@@ -1728,9 +1762,7 @@ class SlotScheduler:
     def _run(self) -> None:
         sup_cm = self.run_supervisor
         if sup_cm is None:
-            import contextlib
-
-            sup_cm = contextlib.nullcontext()
+            sup_cm = supervisor.NULL_CM
         with sup_cm:
             while not self._stop.is_set():
                 # one coherent snapshot of the cross-thread poll state
@@ -1741,6 +1773,7 @@ class SlotScheduler:
                     draining = self._draining
                     queue_empty = not self._queue
                 self._update_brownout(monotonic())
+                admit_start = monotonic()
                 if swap_pending:
                     # admission pauses so _live can empty; queued +
                     # in-flight requests finish on the ADMITTED version
@@ -1748,7 +1781,12 @@ class SlotScheduler:
                         self._apply_pending_swap()
                         continue
                 else:
-                    self._admit()
+                    # an empty queue leaves _admit nothing to place: it
+                    # gets no span of its own
+                    with (supervisor.NULL_CM if queue_empty
+                          else telemetry.span("serve/admit")):
+                        self._admit()
+                self._admit_s = monotonic() - admit_start
                 if draining:
                     if not self._live and queue_empty:
                         self._drained.set()

@@ -13,8 +13,8 @@ is the host-side-only fix; nothing here crosses into a jitted program:
   timestamps at every lifecycle edge: received, enqueued, admitted
   (with pages reserved, prefix blocks hit, and queue re-entries on page
   starvation), prefill start/end (bucket + suffix length), first token,
-  per-step token times aggregated to ITL count/total/min/max (never
-  stored raw), harvested, responded. :meth:`complete` derives the SLO
+  per-step token times (kept, one stamp per token, and aggregated to
+  ITL count/total/min/max), harvested, responded. :meth:`complete` derives the SLO
   family — ``serve/ttft``, ``serve/itl``, ``serve/queue_time``,
   ``serve/prefill_time``, ``serve/decode_time``, the
   ``serve/request_latency`` histogram labeled per scheduler path
@@ -192,9 +192,10 @@ def slo_engine(target: Optional[float] = None):
         tel.slo.target = float(target)
     return tel.slo
 
-#: Perfetto track ids: one per request, starting clear of tid 0 (the
-#: process-level span track the tracer already uses)
-_TID = itertools.count(1)
+#: Perfetto track ids: one per request, starting clear of the tracer's
+#: per-thread tracks (0, 1, 2... in order of each thread's first span)
+REQUEST_TID_BASE = 1 << 20
+_TID = itertools.count(REQUEST_TID_BASE)
 
 
 def new_trace_id() -> str:
@@ -214,7 +215,7 @@ class RequestTrace:
         "prefill_start", "prefill_end", "first_token", "last_token",
         "harvested", "responded", "queue_reentries", "pages_reserved",
         "prefix_blocks_hit", "bucket", "suffix_len",
-        "itl_count", "itl_total", "itl_min", "itl_max",
+        "itl_count", "itl_total", "itl_min", "itl_max", "token_times",
         "replays", "model_version", "tenant",
     )
 
@@ -240,6 +241,9 @@ class RequestTrace:
         self.itl_total = 0.0
         self.itl_min = 0.0
         self.itl_max = 0.0
+        #: one monotonic stamp per emitted token (slots path): at most
+        #: ``max_new_tokens`` floats, freed with the request
+        self.token_times: List[float] = []
         #: crash-only recovery: poisoned-step/admission re-queues this
         #: request survived (trlx_tpu.serve.slots replay path)
         self.replays = 0
@@ -256,7 +260,10 @@ class RequestTrace:
         """One emitted token at ``now`` (the step's harvest timestamp).
         The first sets TTFT's numerator; later ones fold their gap into
         the ITL aggregate AND the global ``serve/itl`` histogram (the
-        per-gap distribution — raw timestamps are never stored)."""
+        per-gap distribution). Every stamp is kept in ``token_times``:
+        the gaps between them are this request's ``serve/itl``
+        observations, one for one."""
+        self.token_times.append(now)
         if not self.first_token:
             self.first_token = now
         else:
@@ -402,6 +409,13 @@ class RequestTrace:
             "tokens": self.itl_count + 1 if self.first_token else 0,
             "queue_reentries": self.queue_reentries,
         }
+        if self.token_times:
+            # each token's time since receipt: a slow stream, token by
+            # token (token_ms[0] is ttft_ms)
+            base = self.received or self.enqueued
+            out["token_ms"] = [
+                round((t - base) * ms, 3) for t in self.token_times
+            ]
         if self.replays:
             out["replays"] = self.replays
         if self.model_version:

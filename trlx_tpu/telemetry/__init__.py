@@ -48,6 +48,7 @@ from trlx_tpu.telemetry.flops import (  # noqa: F401  (re-exports)
 )
 from trlx_tpu.telemetry.registry import MetricsRegistry, TimingHist  # noqa: F401
 from trlx_tpu.telemetry.tracer import SpanTracer
+from trlx_tpu.utils import profiling
 
 #: counters pre-registered at session start so ``fault/*`` keys appear in
 #: every emission from the first iteration — a dashboard shows 0, not a
@@ -82,11 +83,13 @@ _PREDECLARED_COUNTERS = (
     # (trlx_tpu.utils.aotjit): a sharding/layout drift that recompiles
     # every step shows up as a counter climbing with iter, not silence
     "compile/recompiles",
-    # chaos drills fired (supervisor.chaos) and span-ring overflow
-    # (tracer) — both are "the instrumentation itself acted" signals
-    # that must read 0, not absent, on a healthy run
+    # every backend compile JAX itself reports (aot_jit or plain jax.jit,
+    # persistent-cache hit or not), also counted per {span=...} it fell
+    # in: after warm-up a healthy run holds this still
+    "compile/backend_compiles",
+    # chaos drills fired (supervisor.chaos): "the instrumentation itself
+    # acted" — must read 0, not absent, on a healthy run
     "chaos/injections",
-    "telemetry/trace_events_dropped",
 )
 
 
@@ -178,11 +181,18 @@ class TelemetrySession:
 
 _session: Optional[TelemetrySession] = None
 _NULL_CM = contextlib.nullcontext()  # reusable & reentrant
+_listening = False  # the jax.monitoring listener is registered, once
 
 
 def start(run_dir: str = "", force_dir: bool = False) -> TelemetrySession:
-    """Activate a fresh session (a new run = fresh metrics); returns it."""
-    global _session
+    """Activate a fresh session (a new run = fresh metrics); returns it.
+    The process's first session registers the compile listener."""
+    global _session, _listening
+    if not _listening:
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        _listening = True
     _session = TelemetrySession(run_dir=run_dir, force_dir=force_dir)
     return _session
 
@@ -209,10 +219,33 @@ def current() -> Optional[TelemetrySession]:
 
 
 def span(name: str):
-    """Context manager timing one named phase; no-op without a session."""
+    """Context manager around one named phase: THE place a program span
+    is made. With a session it is timed and recorded (SpanTracer); while
+    profiler annotations are on (``profiling.set_annotations``) it also
+    opens one ``jax.profiler.TraceAnnotation`` of the same name, session
+    or not; with neither it is a shared no-op."""
     if _session is None:
-        return _NULL_CM
+        return profiling.trace_annotation(name) or _NULL_CM
     return _session.tracer.span(name)
+
+
+#: the event the benchmark harness counts too: one per backend compile
+#: request, on the thread that asked for it
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_jax_duration(event: str, duration: float, **_kwargs) -> None:
+    """Lay each compile JAX reports to the span it fell in."""
+    session = _session
+    if session is None or event != _COMPILE_EVENT:
+        return
+    registry = session.registry
+    registry.inc("compile/backend_compiles")
+    registry.inc(
+        "compile/backend_compiles",
+        labels={"span": session.tracer.current_span() or "none"},
+    )
+    registry.observe("compile/backend_compile_s", duration)
 
 
 def inc(name: str, n: float = 1.0, labels=None) -> None:

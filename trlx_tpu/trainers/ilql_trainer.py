@@ -562,10 +562,10 @@ class JaxILQLTrainer(BaseRLTrainer):
                     self.params = self._sync(self.params)
 
                 if self.iter_count % cfg.log_interval == 0:
-                    host = {
-                        k: float(v)
-                        for k, v in jax.device_get(stats).items()
-                    }
+                    # the wait for the update program lands here
+                    with annotate("ilql_stats_fetch"):
+                        fetched = jax.device_get(stats)
+                    host = {k: float(v) for k, v in fetched.items()}
                     sps = clock.samples_per_second()
                     host.update(
                         iter=self.iter_count,

@@ -745,10 +745,9 @@ class JaxPPOTrainer(BaseRLTrainer):
                 # rollout-after-update chain — one host sync saved per
                 # cycle. Cost: that experience is one update phase stale
                 # (train.continuous_rollouts docs).
-                with annotate("rollout_dispatch_stale"):
-                    pending_exp = self.orch.start_experience(
-                        m.num_rollouts, self.iter_count
-                    )
+                pending_exp = self.orch.start_experience(
+                    m.num_rollouts, self.iter_count
+                )
             for item in loader:
                 with annotate("ppo_update"):
                     chaos.maybe_inject("ppo_update")
@@ -764,10 +763,11 @@ class JaxPPOTrainer(BaseRLTrainer):
 
                 intervals = self.intervals(self.iter_count)
                 if intervals["do_log"]:
-                    host_stats = {
-                        k: float(v)
-                        for k, v in jax.device_get(stats).items()
-                    }
+                    # the dispatch above returned at once: the wait for
+                    # the update program itself lands in this fetch
+                    with annotate("ppo_stats_fetch"):
+                        fetched = jax.device_get(stats)
+                    host_stats = {k: float(v) for k, v in fetched.items()}
                     sps = clock.samples_per_second()
                     host_stats.update(
                         iter=self.iter_count,
@@ -805,8 +805,7 @@ class JaxPPOTrainer(BaseRLTrainer):
                 # this epoch's updates (a preemption mid-epoch above
                 # abandons them — the dispatched device work is moot)
                 self.store.clear_history()
-                with annotate("rollout_harvest"):
-                    info = self.orch.finish_experience(pending_exp)
+                info = self.orch.finish_experience(pending_exp)
                 log_fn({"iter": self.iter_count, "epoch": self.epoch, **info,
                         **self._telemetry_stats(clock.samples_per_second())})
                 if self._preempt(log_fn, guard, sup=sup):
