@@ -635,24 +635,43 @@ def init_slot_state(num_slots: int, buffer_len: int, vocab_size: int,
     )
 
 
+# The pool is PER-LAYER LEAVES, never a stacked [L, ...] array: per
+# segment (the structure generate() keeps, so hydra policies never
+# concatenate their trunk) a tuple of L_seg (k, v) entries. Each leaf is
+# its own donated buffer and block_apply's scatter is the only write a
+# program makes to it, so XLA aliases input to output and a step writes
+# its fresh rows in place. A layer sliced out of a stacked donated array
+# is a NEW buffer: the stacked pool made every step slice each layer out,
+# scatter into the slice and copy it back (measured on v5e: 46% of a
+# gpt-j-6B decode step). tests/test_paged.py pins the structure.
+
+
 def init_slot_pool(spec: ModelSpec, seg_sizes, num_slots: int,
                    buffer_len: int, cache_dtype=jnp.bfloat16):
-    """Per-segment stacked (k, v) pool buffers [L_seg, S, T, Hkv, hd] —
-    the same segment structure generate() keeps, so hydra policies never
-    concatenate their trunk."""
+    """Contiguous pool: per segment, per layer, (k, v) buffers
+    [S, T, Hkv, hd] — one region per slot."""
+    shape = (num_slots, buffer_len, spec.kv_heads, spec.head_dim)
     return tuple(
-        init_kv_cache(spec, size, num_slots, buffer_len, cache_dtype)
+        tuple(
+            (jnp.zeros(shape, cache_dtype), jnp.zeros(shape, cache_dtype))
+            for _ in range(size)
+        )
         for size in seg_sizes
     )
 
 
 def init_page_pool(spec: ModelSpec, seg_sizes, num_pages: int,
                    page_size: int, cache_dtype=jnp.bfloat16):
-    """Per-segment (k, v) PAGE pools [L_seg, num_pages, page_size, Hkv,
-    hd]: the block-granular replacement for init_slot_pool — HBM is
-    sized in pages shared by all slots, not slots x worst-case length."""
+    """PAGE pool: per segment, per layer, (k, v) pages [num_pages,
+    page_size, Hkv, hd] — the block-granular replacement for
+    init_slot_pool: HBM is sized in pages shared by all slots, not slots
+    x worst-case length. The int8 tier makes each of k/v a ``(codes,
+    scales)`` pair (transformer.init_paged_kv_cache)."""
     return tuple(
-        init_paged_kv_cache(spec, size, num_pages, page_size, cache_dtype)
+        tuple(
+            init_paged_kv_cache(spec, num_pages, page_size, cache_dtype)
+            for _ in range(size)
+        )
         for size in seg_sizes
     )
 
@@ -666,24 +685,31 @@ def _segments_of(blocks):
     return segments, seg_sizes
 
 
-def _kv_layer(entry, i):
-    """Layer ``i`` of one side of a per-segment pool entry — a plain
-    [L, ...] array (bf16 tier) or the int8 tier's (codes, scales) pair;
-    tree_map indexes both uniformly."""
-    return jax.tree_util.tree_map(lambda x: x[i], entry)
-
-
-def _kv_set_layer(entry, i, new):
-    return jax.tree_util.tree_map(
-        lambda c, l: c.at[i].set(l), entry, new
-    )
-
-
 def _pool_page_geometry(pool):
     """(num_pages, page_size) of a page pool in either KV tier."""
-    k0 = pool[0][0]
-    k0 = k0[0] if isinstance(k0, (tuple, list)) else k0
-    return k0.shape[1], k0.shape[2]
+    pages = jax.tree_util.tree_leaves(pool)[0]  # layer 0's k pages/codes
+    return pages.shape[0], pages.shape[1]
+
+
+def _apply_layers_with_pool(spec, segments, seg_sizes, pool, h, **block_kw):
+    """The serve programs' unrolled layer loop: layer ``n`` reads and
+    writes its own pool leaves ``pool[seg][i]`` through block_apply
+    (whose ``kv_cache`` is one layer's (k, v)) and nothing else touches
+    them. Returns (new_pool, h)."""
+    flags = ArchFlags.for_spec(spec)
+    new_pool, layer = [], 0
+    for seg, size, seg_pool in zip(segments, seg_sizes, pool):
+        new_seg = []
+        for i in range(size):
+            with jax.named_scope(f"layer{layer}"):
+                p_i = jax.tree_util.tree_map(lambda x, i=i: x[i], seg)
+                h, kv = block_apply(
+                    spec, flags, p_i, h, kv_cache=seg_pool[i], **block_kw
+                )
+            new_seg.append(kv)
+            layer += 1
+        new_pool.append(tuple(new_seg))
+    return tuple(new_pool), h
 
 
 def prefill_into_slots(
@@ -761,16 +787,20 @@ def prefill_into_slots(
             seg, cache_segs[i], spec, h, bias, positions,
             cache_offset=jnp.int32(0), attention_fn=attention_fn,
         )
-    h_last = layer_norm(ln_f, h[:, -1:], spec.layer_norm_epsilon)
-    logits0 = project_logits(embed, spec, h_last)[:, 0]  # [Bp, V]
+    with jax.named_scope("head"):
+        h_last = layer_norm(ln_f, h[:, -1:], spec.layer_norm_epsilon)
+        logits0 = project_logits(embed, spec, h_last)[:, 0]  # [Bp, V]
 
     rows = slot_ids.astype(jnp.int32)
-    new_pool = []
-    for (k_pool, v_pool), (k_new, v_new) in zip(pool, cache_segs):
-        new_pool.append((
-            k_pool.at[:, rows, :P].set(k_new, mode="drop"),
-            v_pool.at[:, rows, :P].set(v_new, mode="drop"),
-        ))
+    with jax.named_scope("kv_write"):
+        new_pool = tuple(
+            tuple(
+                (k.at[rows, :P].set(k_new[i], mode="drop"),
+                 v.at[rows, :P].set(v_new[i], mode="drop"))
+                for i, (k, v) in enumerate(seg_pool)
+            )
+            for seg_pool, (k_new, v_new) in zip(pool, cache_segs)
+        )
 
     valid_rows = jnp.concatenate(
         [prompt_mask, jnp.zeros((B, T - P), jnp.int32)], axis=1
@@ -787,7 +817,7 @@ def prefill_into_slots(
         finished=state.finished.at[rows].set(False, mode="drop"),
         logits=state.logits.at[rows].set(logits0, mode="drop"),
     )
-    return tuple(new_pool), new_state
+    return new_pool, new_state
 
 
 def _prefill_into_pages(
@@ -808,7 +838,6 @@ def _prefill_into_pages(
             f"page table extent {max_pages} x {page_size} != slot buffer "
             f"length {T}"
         )
-    flags = ArchFlags.for_spec(spec)
     suffix_len = prompt_mask.sum(axis=-1)  # [Bp] real (unmatched) tokens
     if start is None:
         start = jnp.zeros((B,), jnp.int32)
@@ -818,7 +847,10 @@ def _prefill_into_pages(
     positions = start[:, None] + jnp.arange(P)[None, :]
     h = embed_tokens(embed, spec, prompt_tokens, positions, compute_dtype)
 
-    quantized = isinstance(pool[0][0], (tuple, list))
+    # by the first leaf, not by pool[0][0]: a segment may hold no layer
+    # (every block trainable leaves the frozen trunk empty)
+    pool_dtype = jax.tree_util.tree_leaves(pool)[0].dtype
+    quantized = pool_dtype == jnp.int8
     if not prefix_context:
         # no committed prefix: local causal prefill (the exact ops the
         # contiguous path runs), then one block-scatter into the pages.
@@ -826,8 +858,7 @@ def _prefill_into_pages(
         # dtype and quantization happens once at the scatter — the same
         # source dtype block_apply's decode-time quantize sees, so page
         # content stays a pure function of token content (radix dedupe).
-        cache_dtype = compute_dtype if quantized \
-            else jax.tree_util.tree_leaves(pool)[0].dtype
+        cache_dtype = compute_dtype if quantized else pool_dtype
         cache_segs = [
             init_kv_cache(spec, size, B, P, cache_dtype)
             for size in seg_sizes
@@ -841,24 +872,25 @@ def _prefill_into_pages(
         pos_buf = jnp.arange(P)
         pids = page_tables[:, pos_buf // page_size]  # [Bp, P]
         ioff = pos_buf % page_size  # [P], broadcasts against pids
-        new_pool = []
-        for entry, (k_new, v_new) in zip(pool, cache_segs):
-            if quantized:
-                (k_pool, k_sc), (v_pool, v_sc) = entry
-                kq, ks = quantize_kv(k_new)  # [L,Bp,P,Hkv(,hd)]
-                vq, vs = quantize_kv(v_new)
-                new_pool.append((
-                    (k_pool.at[:, pids, ioff].set(kq, mode="drop"),
-                     k_sc.at[:, pids, ioff].set(ks, mode="drop")),
-                    (v_pool.at[:, pids, ioff].set(vq, mode="drop"),
-                     v_sc.at[:, pids, ioff].set(vs, mode="drop")),
+
+        with jax.named_scope("kv_write"):
+            new_pool = []
+            for seg_pool, fresh in zip(pool, cache_segs):
+                if quantized:
+                    # (codes [L,Bp,P,Hkv,hd], scales [L,Bp,P,Hkv]) for
+                    # each of k, v: the nesting of a pool entry
+                    fresh = tuple(quantize_kv(x) for x in fresh)
+                # one scatter per layer of that layer's fresh rows
+                new_pool.append(tuple(
+                    jax.tree_util.tree_map(
+                        lambda pages, x, i=i: pages.at[pids, ioff].set(
+                            x[i], mode="drop"
+                        ),
+                        entry, fresh,
+                    )
+                    for i, entry in enumerate(seg_pool)
                 ))
-            else:
-                k_pool, v_pool = entry
-                new_pool.append((
-                    k_pool.at[:, pids, ioff].set(k_new, mode="drop"),
-                    v_pool.at[:, pids, ioff].set(v_new, mode="drop"),
-                ))
+            new_pool = tuple(new_pool)
     else:
         # prefix-suffix prefill: each suffix token attends to the
         # committed prefix pages (gathered inside block_apply's paged
@@ -871,27 +903,20 @@ def _prefill_into_pages(
         bias = jnp.where(allowed, 0.0, NEG_INF).astype(
             jnp.float32
         )[:, None]  # [Bp, 1, P, T]
-        new_pool = []
-        for seg, size, (k_c, v_c) in zip(segments, seg_sizes, pool):
-            for i in range(size):
-                p_i = jax.tree_util.tree_map(lambda x, i=i: x[i], seg)
-                h, (k_l, v_l) = block_apply(
-                    spec, flags, p_i, h, bias, positions,
-                    kv_cache=(_kv_layer(k_c, i), _kv_layer(v_c, i)),
-                    cache_row_offsets=start,
-                    page_table=page_tables, page_size=page_size,
-                    attention_fn=attention_fn,
-                )
-                k_c = _kv_set_layer(k_c, i, k_l)
-                v_c = _kv_set_layer(v_c, i, v_l)
-            new_pool.append((k_c, v_c))
+        new_pool, h = _apply_layers_with_pool(
+            spec, segments, seg_sizes, pool, h,
+            mask_bias=bias, positions=positions, cache_row_offsets=start,
+            page_table=page_tables, page_size=page_size,
+            attention_fn=attention_fn,
+        )
 
     # first-step logits from the last REAL suffix token (right padding:
     # per-row gather, not the shared last column)
-    last_idx = jnp.maximum(suffix_len - 1, 0)
-    h_last = h[jnp.arange(B), last_idx]  # [Bp, D]
-    h_normed = layer_norm(ln_f, h_last, spec.layer_norm_epsilon)
-    logits0 = project_logits(embed, spec, h_normed)  # [Bp, V]
+    with jax.named_scope("head"):
+        last_idx = jnp.maximum(suffix_len - 1, 0)
+        h_last = h[jnp.arange(B), last_idx]  # [Bp, D]
+        h_normed = layer_norm(ln_f, h_last, spec.layer_norm_epsilon)
+        logits0 = project_logits(embed, spec, h_normed)  # [Bp, V]
 
     rows = slot_ids.astype(jnp.int32)
     valid_rows = (
@@ -913,7 +938,7 @@ def _prefill_into_pages(
             page_tables.astype(jnp.int32), mode="drop"
         ),
     )
-    return tuple(new_pool), new_state
+    return new_pool, new_state
 
 
 def verify_step(
@@ -978,7 +1003,6 @@ def verify_step(
     Tc = K + 1  # candidates forwarded: the free token + K proposals
     T = state.valid.shape[1]
     segments, seg_sizes = _segments_of(blocks)
-    flags = ArchFlags.for_spec(spec)
 
     emitting = state.active & ~state.finished
     # clamp proposals to the per-slot budget: t0 spends one token, so at
@@ -1042,22 +1066,15 @@ def verify_step(
 
     positions = state.pos[:, None] + jnp.arange(Tc)[None, :]  # [S, Tc]
     h = embed_tokens(embed, spec, cand, positions, compute_dtype)
-    new_pool = []
-    for seg, size, (k_c, v_c) in zip(segments, seg_sizes, pool):
-        for i in range(size):
-            p_i = jax.tree_util.tree_map(lambda x, i=i: x[i], seg)
-            h, (k_l, v_l) = block_apply(
-                spec, flags, p_i, h, bias, positions,
-                kv_cache=(_kv_layer(k_c, i), _kv_layer(v_c, i)),
-                cache_row_offsets=state.offset,
-                page_table=pt_v, page_size=page_size,
-                attention_fn=attention_fn,
-            )
-            k_c = _kv_set_layer(k_c, i, k_l)
-            v_c = _kv_set_layer(v_c, i, v_l)
-        new_pool.append((k_c, v_c))
-    h_normed = layer_norm(ln_f, h, spec.layer_norm_epsilon)
-    L = project_logits(embed, spec, h_normed)  # [S, Tc, V]
+    new_pool, h = _apply_layers_with_pool(
+        spec, segments, seg_sizes, pool, h,
+        mask_bias=bias, positions=positions,
+        cache_row_offsets=state.offset,
+        page_table=pt_v, page_size=page_size, attention_fn=attention_fn,
+    )
+    with jax.named_scope("head"):
+        h_normed = layer_norm(ln_f, h, spec.layer_norm_epsilon)
+        L = project_logits(embed, spec, h_normed)  # [S, Tc, V]
 
     # acceptance: proposal j (emitted index j, 1-based over proposals)
     # survives iff it matches the greedy token of the distribution after
@@ -1122,7 +1139,7 @@ def verify_step(
         logits=next_logits,
         pages=state.pages,
     )
-    return tuple(new_pool), new_state, cand, counts, finished
+    return new_pool, new_state, cand, counts, finished
 
 
 def decode_step(
@@ -1159,7 +1176,6 @@ def decode_step(
     """
     S = state.offset.shape[0]
     segments, seg_sizes = _segments_of(blocks)
-    flags = ArchFlags.for_spec(spec)
 
     step_logits = state.logits
     if config.eos_token_id >= 0 and config.min_new_tokens > 0:
@@ -1202,24 +1218,17 @@ def decode_step(
         pt_step = jnp.where(
             emitted[:, None], state.pages, jnp.int32(num_pages)
         )
-    new_pool = []
-    for seg, size, (k_c, v_c) in zip(segments, seg_sizes, pool):
-        for i in range(size):
-            p_i = jax.tree_util.tree_map(lambda x, i=i: x[i], seg)
-            h, (k_l, v_l) = block_apply(
-                spec, flags, p_i, h, bias, pos,
-                kv_cache=(_kv_layer(k_c, i), _kv_layer(v_c, i)),
-                cache_row_offsets=state.offset,
-                page_table=pt_step if paged else None,
-                page_size=page_size if paged else None,
-                attention_fn=attention_fn,
-                paged_decode_fn=paged_decode_fn if paged else None,
-            )
-            k_c = _kv_set_layer(k_c, i, k_l)
-            v_c = _kv_set_layer(v_c, i, v_l)
-        new_pool.append((k_c, v_c))
-    h_normed = layer_norm(ln_f, h, spec.layer_norm_epsilon)
-    next_logits = project_logits(embed, spec, h_normed)[:, 0]  # [S, V]
+    new_pool, h = _apply_layers_with_pool(
+        spec, segments, seg_sizes, pool, h,
+        mask_bias=bias, positions=pos, cache_row_offsets=state.offset,
+        page_table=pt_step if paged else None,
+        page_size=page_size if paged else None,
+        attention_fn=attention_fn,
+        paged_decode_fn=paged_decode_fn if paged else None,
+    )
+    with jax.named_scope("head"):
+        h_normed = layer_norm(ln_f, h, spec.layer_norm_epsilon)
+        next_logits = project_logits(embed, spec, h_normed)[:, 0]  # [S, V]
 
     adv = emitted.astype(jnp.int32)
     new_state = SlotState(
@@ -1233,4 +1242,4 @@ def decode_step(
         logits=next_logits,
         pages=state.pages,
     )
-    return tuple(new_pool), new_state, tok, emitted, finished
+    return new_pool, new_state, tok, emitted, finished

@@ -344,16 +344,20 @@ def block_apply(
     Hkv = spec.kv_heads
     eps = spec.layer_norm_epsilon
 
-    x = layer_norm(p["ln_1"], h, eps)
-    attn = p["attn"]
-    q = _project(x, attn["wq"], attn.get("bq")).reshape(B, T, H, hd)
-    k = _project(x, attn["wk"], attn.get("bk")).reshape(B, T, Hkv, hd)
-    v = _project(x, attn["wv"], attn.get("bv")).reshape(B, T, Hkv, hd)
-    if flags.use_rotary:
-        q = apply_rotary(q, positions, spec.rotary_dim,
-                         flags.rotary_interleaved, spec.rope_theta)
-        k = apply_rotary(k, positions, spec.rotary_dim,
-                         flags.rotary_interleaved, spec.rope_theta)
+    # the named scopes (attn, kv_write, kv_read, mlp) land in every op's
+    # metadata, so a device trace says which phase of which layer an XLA
+    # op belongs to (docs/source/observability.rst)
+    with jax.named_scope("attn"):
+        x = layer_norm(p["ln_1"], h, eps)
+        attn = p["attn"]
+        q = _project(x, attn["wq"], attn.get("bq")).reshape(B, T, H, hd)
+        k = _project(x, attn["wk"], attn.get("bk")).reshape(B, T, Hkv, hd)
+        v = _project(x, attn["wv"], attn.get("bv")).reshape(B, T, Hkv, hd)
+        if flags.use_rotary:
+            q = apply_rotary(q, positions, spec.rotary_dim,
+                             flags.rotary_interleaved, spec.rope_theta)
+            k = apply_rotary(k, positions, spec.rotary_dim,
+                             flags.rotary_interleaved, spec.rope_theta)
 
     def expand_kv(t):
         """H-wide KV for attention fns that can't consume the compact GQA
@@ -364,7 +368,7 @@ def block_apply(
             return t
         return jnp.repeat(t, H // Hkv, axis=2)
 
-    new_cache = None
+    new_cache = a = None
     if kv_cache is not None and page_table is not None:
         if cache_row_offsets is None:
             raise ValueError(
@@ -381,96 +385,106 @@ def block_apply(
             k_cache, v_cache = k_entry, v_entry
         num_pages = k_cache.shape[0]
         max_pages = page_table.shape[1]
-        # logical buffer position of each fresh token, then page-id
-        # gather -> physical (page row, in-page offset) scatter
-        pos_buf = cache_row_offsets[:, None] + jnp.arange(T)[None, :]
-        page_idx = pos_buf // page_size
-        in_off = pos_buf % page_size
-        pids = jnp.where(
-            page_idx < max_pages,
-            jnp.take_along_axis(
-                page_table, jnp.minimum(page_idx, max_pages - 1), axis=1
-            ),
-            num_pages,  # out past the table: drop like a sentinel page
-        )
-        if quantized:
-            kq, ks = quantize_kv(k)  # codes [B,T,Hkv,hd], scale [B,T,Hkv]
-            vq, vs = quantize_kv(v)
-            k_full = k_cache.at[pids, in_off].set(kq, mode="drop")
-            v_full = v_cache.at[pids, in_off].set(vq, mode="drop")
-            k_sc = k_sc.at[pids, in_off].set(ks, mode="drop")
-            v_sc = v_sc.at[pids, in_off].set(vs, mode="drop")
-            new_cache = ((k_full, k_sc), (v_full, v_sc))
-        else:
-            k_full = k_cache.at[pids, in_off].set(
-                k.astype(k_cache.dtype), mode="drop"
+        with jax.named_scope("kv_write"):
+            # logical buffer position of each fresh token, then page-id
+            # gather -> physical (page row, in-page offset) scatter
+            pos_buf = cache_row_offsets[:, None] + jnp.arange(T)[None, :]
+            page_idx = pos_buf // page_size
+            in_off = pos_buf % page_size
+            pids = jnp.where(
+                page_idx < max_pages,
+                jnp.take_along_axis(
+                    page_table, jnp.minimum(page_idx, max_pages - 1),
+                    axis=1,
+                ),
+                num_pages,  # out past the table: drop like a sentinel page
             )
-            v_full = v_cache.at[pids, in_off].set(
-                v.astype(v_cache.dtype), mode="drop"
-            )
-            new_cache = (k_full, v_full)
+            if quantized:
+                kq, ks = quantize_kv(k)  # codes [B,T,Hkv,hd], scale [B,T,Hkv]
+                vq, vs = quantize_kv(v)
+                k_full = k_cache.at[pids, in_off].set(kq, mode="drop")
+                v_full = v_cache.at[pids, in_off].set(vq, mode="drop")
+                k_sc = k_sc.at[pids, in_off].set(ks, mode="drop")
+                v_sc = v_sc.at[pids, in_off].set(vs, mode="drop")
+                new_cache = ((k_full, k_sc), (v_full, v_sc))
+            else:
+                k_full = k_cache.at[pids, in_off].set(
+                    k.astype(k_cache.dtype), mode="drop"
+                )
+                v_full = v_cache.at[pids, in_off].set(
+                    v.astype(v_cache.dtype), mode="drop"
+                )
+                new_cache = (k_full, v_full)
         if paged_decode_fn is not None and T == 1:
             # fused kernel: page-table walk + online softmax in one
             # pallas_call against the just-updated pool; bias collapses
             # to the per-row validity lane [B, max_pages * page_size]
-            a = paged_decode_fn(
-                q[:, 0],
-                new_cache[0],
-                new_cache[1],
-                page_table,
-                mask_bias.reshape(B, -1),
-            )[:, None]
+            with jax.named_scope("attn"):
+                a = paged_decode_fn(
+                    q[:, 0],
+                    new_cache[0],
+                    new_cache[1],
+                    page_table,
+                    mask_bias.reshape(B, -1),
+                )[:, None]
         else:
             # gather-by-page AFTER the scatter: within one prefill
             # program a row may legitimately read pages another row just
             # wrote (the radix cache admits same-batch prefix sharers
             # against pages whose content materializes earlier in this
             # same program)
-            ctx_pt = jnp.clip(page_table, 0, num_pages - 1)
-            if quantized:
-                k_ctx = dequantize_kv(k_full[ctx_pt], k_sc[ctx_pt], q.dtype)
-                v_ctx = dequantize_kv(v_full[ctx_pt], v_sc[ctx_pt], q.dtype)
-            else:
-                k_ctx = k_full[ctx_pt].astype(q.dtype)
-                v_ctx = v_full[ctx_pt].astype(q.dtype)
-            k_ctx = k_ctx.reshape(B, max_pages * page_size, Hkv, hd)
-            v_ctx = v_ctx.reshape(B, max_pages * page_size, Hkv, hd)
-            a = attention_fn(
-                q, expand_kv(k_ctx), expand_kv(v_ctx), mask_bias,
-            )
+            with jax.named_scope("kv_read"):
+                ctx_pt = jnp.clip(page_table, 0, num_pages - 1)
+                if quantized:
+                    k_ctx = dequantize_kv(
+                        k_full[ctx_pt], k_sc[ctx_pt], q.dtype
+                    )
+                    v_ctx = dequantize_kv(
+                        v_full[ctx_pt], v_sc[ctx_pt], q.dtype
+                    )
+                else:
+                    k_ctx = k_full[ctx_pt].astype(q.dtype)
+                    v_ctx = v_full[ctx_pt].astype(q.dtype)
+                k_ctx = expand_kv(
+                    k_ctx.reshape(B, max_pages * page_size, Hkv, hd)
+                )
+                v_ctx = expand_kv(
+                    v_ctx.reshape(B, max_pages * page_size, Hkv, hd)
+                )
     elif kv_cache is not None:
         k_cache, v_cache = kv_cache
-        if cache_row_offsets is not None:
-            if T != 1:
-                raise ValueError(
-                    f"cache_row_offsets (per-row cache writes) requires a "
-                    f"single fresh token per row, got T={T}"
+        with jax.named_scope("kv_write"):
+            if cache_row_offsets is not None:
+                if T != 1:
+                    raise ValueError(
+                        f"cache_row_offsets (per-row cache writes) "
+                        f"requires a single fresh token per row, got T={T}"
+                    )
+                rows = jnp.arange(B)
+                k_full = k_cache.at[rows, cache_row_offsets].set(
+                    k[:, 0].astype(k_cache.dtype), mode="drop"
                 )
-            rows = jnp.arange(B)
-            k_full = k_cache.at[rows, cache_row_offsets].set(
-                k[:, 0].astype(k_cache.dtype), mode="drop"
-            )
-            v_full = v_cache.at[rows, cache_row_offsets].set(
-                v[:, 0].astype(v_cache.dtype), mode="drop"
-            )
-        else:
-            k_full = jax.lax.dynamic_update_slice_in_dim(
-                k_cache, k.astype(k_cache.dtype), cache_offset, axis=1
-            )
-            v_full = jax.lax.dynamic_update_slice_in_dim(
-                v_cache, v.astype(v_cache.dtype), cache_offset, axis=1
-            )
-        new_cache = (k_full, v_full)
-        a = attention_fn(
-            q,
-            expand_kv(k_full.astype(q.dtype)),
-            expand_kv(v_full.astype(q.dtype)),
-            mask_bias,
-        )
+                v_full = v_cache.at[rows, cache_row_offsets].set(
+                    v[:, 0].astype(v_cache.dtype), mode="drop"
+                )
+            else:
+                k_full = jax.lax.dynamic_update_slice_in_dim(
+                    k_cache, k.astype(k_cache.dtype), cache_offset, axis=1
+                )
+                v_full = jax.lax.dynamic_update_slice_in_dim(
+                    v_cache, v.astype(v_cache.dtype), cache_offset, axis=1
+                )
+            new_cache = (k_full, v_full)
+        with jax.named_scope("kv_read"):
+            k_ctx = expand_kv(k_full.astype(q.dtype))
+            v_ctx = expand_kv(v_full.astype(q.dtype))
     else:
-        a = attention_fn(q, expand_kv(k), expand_kv(v), mask_bias)
+        k_ctx, v_ctx = expand_kv(k), expand_kv(v)
 
-    a = _project(a.reshape(B, T, D), attn["wo"], attn.get("bo"))
+    with jax.named_scope("attn"):
+        if a is None:  # not the fused paged kernel's
+            a = attention_fn(q, k_ctx, v_ctx, mask_bias)
+        a = _project(a.reshape(B, T, D), attn["wo"], attn.get("bo"))
 
     def mlp(mlp_in):
         mp = p["mlp"]
@@ -483,12 +497,14 @@ def block_apply(
             mp["b_out"],
         )
 
-    if flags.parallel_block:
-        mlp_in = layer_norm(p["ln_2"], h, eps) if flags.separate_mlp_ln else x
-        return h + a + mlp(mlp_in), new_cache
+    with jax.named_scope("mlp"):
+        if flags.parallel_block:
+            mlp_in = layer_norm(p["ln_2"], h, eps) \
+                if flags.separate_mlp_ln else x
+            return h + a + mlp(mlp_in), new_cache
 
-    h = h + a
-    return h + mlp(layer_norm(p["ln_2"], h, eps)), new_cache
+        h = h + a
+        return h + mlp(layer_norm(p["ln_2"], h, eps)), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -607,22 +623,22 @@ def dequantize_kv(codes: jnp.ndarray, scale: jnp.ndarray, dtype):
 
 def init_paged_kv_cache(
     spec: ModelSpec,
-    n_layers: int,
     num_pages: int,
     page_size: int,
     dtype=jnp.bfloat16,
 ):
-    """(k, v) page-pool buffers [L, num_pages, page_size, Hkv, hd]: one
-    global pool of fixed-size KV pages shared by every slot, addressed
-    through per-slot page tables (block_apply's paged mode).
+    """ONE layer's (k, v) page buffers [num_pages, page_size, Hkv, hd]:
+    fixed-size KV pages shared by every slot, addressed through per-slot
+    page tables (block_apply's paged mode). A pool is a tuple of these,
+    one per layer (generation.init_page_pool).
 
     ``dtype=jnp.int8`` selects the quantized tier: each of k/v becomes a
-    ``(codes int8 [L, num_pages, page_size, Hkv, hd], scales f32
-    [L, num_pages, page_size, Hkv])`` pair (see :func:`quantize_kv`) —
+    ``(codes int8 [num_pages, page_size, Hkv, hd], scales f32
+    [num_pages, page_size, Hkv])`` pair (see :func:`quantize_kv`) —
     hd bytes of codes + 4 bytes of scale per (token, head) instead of
     2*hd bf16 bytes, so the same HBM holds ~2x the pages.
     """
-    shape = (n_layers, num_pages, page_size, spec.kv_heads, spec.head_dim)
+    shape = (num_pages, page_size, spec.kv_heads, spec.head_dim)
     if jnp.dtype(dtype) == jnp.int8:
         sshape = shape[:-1]
         return (

@@ -241,7 +241,7 @@ def make_paged_decode_fn(mesh=None):
     [S, max_pages * page_size] — and runs the fused kernel, under
     shard_map when ``mesh`` spans more than one device: query/output
     heads and the pool's Hkv axis split over ``tp`` (the serve layout,
-    serve/layouts.KV_POOL_SPEC), page tables and the bias row replicated
+    serve/layouts.KV_POOL_SPECS), page tables and the bias row replicated
     host-shaped data. Heads tp doesn't divide fall back to replication,
     matching ``layouts._fit_spec_to_shape``.
     """

@@ -13,8 +13,8 @@ of reusing the training specs:
   "fsdp"`` — a 6B policy fits a v5e-4 slice) or stays replicated
   (``"replicated"`` — no all-gathers on the decode critical path when
   per-chip HBM affords it).
-- **KV pages** shard on the *head* dimension (axis 3 of
-  ``[L, pages, page_size, Hkv, hd]``) under ``tp`` — the same split as
+- **KV pages** shard on the *head* dimension (axis 2 of each layer's
+  ``[pages, page_size, Hkv, hd]`` leaf) under ``tp`` — the same split as
   the attention projections, so gather→score→scatter needs no KV
   collectives at all. Crucially the page *tables* stay host-side int32
   data (replicated), never shape: the radix cache, allocator, and
@@ -46,9 +46,11 @@ from trlx_tpu.parallel.sharding import _fit_spec_to_shape, _path_names
 #: data parallelism is replica processes — ROADMAP item 3 — not an axis)
 SERVE_AXES = ("tp", "fsdp")
 
-#: KV pool spec — paged [L, pages, page_size, Hkv, hd] and contiguous
-#: [L, slots, buffer_len, Hkv, hd] both carry heads on axis 3
-KV_POOL_SPEC = P(None, None, None, "tp", None)
+#: KV pool leaf specs by rank — the pool is per-layer leaves
+#: (generation.init_page_pool / init_slot_pool), every one with heads on
+#: axis 2: paged pages [pages, page_size, Hkv, hd], contiguous regions
+#: [slots, buffer_len, Hkv, hd], int8 scale planes [pages, page_size, Hkv]
+KV_POOL_SPECS = {4: P(None, None, "tp", None), 3: P(None, None, "tp")}
 
 
 def build_serve_mesh(mesh_config: Optional[Dict[str, int]]) -> Mesh:
@@ -147,19 +149,12 @@ def decode_param_shardings(mesh: Mesh, views: Any,
 
 def kv_pool_shardings(mesh: Mesh, pool: Any) -> Any:
     """NamedSharding pytree for a KV pool (paged or contiguous): heads
-    (axis 3) over tp, everything else replicated. Works on arrays or
-    ShapeDtypeStructs; an Hkv that tp doesn't divide replicates."""
+    (axis 2 of every per-layer leaf) over tp, everything else
+    replicated. Works on arrays or ShapeDtypeStructs; an Hkv that tp
+    doesn't divide replicates."""
 
     def leaf(x):
-        nd = getattr(x, "ndim", 0)
-        if nd == 5:
-            spec = KV_POOL_SPEC
-        elif nd == 4:
-            # int8 tier scale planes [L, num_pages, page_size, Hkv]:
-            # same head split as the codes they scale
-            spec = P(None, None, None, "tp")
-        else:
-            spec = P()
+        spec = KV_POOL_SPECS.get(getattr(x, "ndim", 0), P())
         spec = _fit_spec_to_shape(spec, x.shape, mesh)
         return NamedSharding(mesh, spec)
 

@@ -13,8 +13,9 @@ paged KV blocks, Kwon et al., SOSP '23):
   ``prefill_into_slots`` — one executable per (batch, prompt_len)
   admission bucket (two under the paged layout: plain + the
   ``prefill_suffix`` prefix-context variant) — and ``decode_step`` —
-  ONE executable for all slots. Pool and state are donated on
-  accelerators, so a step updates the pool in place; warmup runs every
+  ONE executable for all slots. The pool is per-layer leaves, each
+  donated on accelerators and written by one scatter, so a step
+  updates it in place (``serve/decode_alias_bytes``); warmup runs every
   prefill bucket against the live pool with out-of-bounds sentinel slot
   ids (scatters ``mode="drop"`` — compiles the shape, touches nothing),
   then one decode step. Steady state is first-compiles only:
@@ -83,6 +84,7 @@ clamps best-effort tenants' ``max_new_tokens`` to
 so the fleet router can shed upstream before forwarding.
 """
 
+import sys
 import threading
 from collections import deque
 from typing import Dict, List, Optional
@@ -461,6 +463,34 @@ class SlotPoolRuntime:
 
     # -- warmup ------------------------------------------------------------ #
 
+    def _report_decode_memory(self) -> None:
+        """What the compiler made of the decode step's pool writes, as
+        two gauges and one log line: ``serve/decode_alias_bytes`` (bytes
+        of arguments aliased to outputs: the whole pool + the lanes when
+        every layer's scatter lands in place; 0 on the CPU, which has no
+        donation) and ``serve/decode_temp_bytes`` (the program's
+        temporaries: prefetched weight slices and a few layers' gathered
+        pages, never a pool's worth). A second pool in either number is
+        a copy a later change brought back."""
+        e = self.engine
+        stats = self._decode_fn().compiled_for(
+            e.blocks, e.embed, e.ln_f, self.pool, self.state, np.int32(0),
+        ).memory_analysis()
+        if stats is None:  # a backend without the analysis
+            return
+        alias, temp = stats.alias_size_in_bytes, stats.temp_size_in_bytes
+        telemetry.set_gauge("serve/decode_alias_bytes", alias)
+        telemetry.set_gauge("serve/decode_temp_bytes", temp)
+        from trlx_tpu.serve import layouts
+
+        pool = layouts.tree_bytes_per_device(self.pool)
+        print(
+            f"[trlx_tpu.serve] decode step: {alias / 2**30:.3f} GiB of "
+            f"arguments aliased to outputs (pool {pool / 2**30:.3f} GiB "
+            f"per device), {temp / 2**30:.3f} GiB of temporaries",
+            file=sys.stderr, flush=True,
+        )
+
     def warmup(self) -> Dict[str, float]:
         """Compile every admission bucket + the decode step up front.
         All rows aim at the sentinel slot, so the live pool is untouched;
@@ -493,6 +523,7 @@ class SlotPoolRuntime:
                         suffix=suffix,
                     )
         self.step(0)
+        self._report_decode_memory()
         if self.spec_k > 0:
             # compile the verifier against the all-free pool: every row
             # is non-emitting, so the sentinel-gated table drops every

@@ -62,7 +62,10 @@ class _AotJit:
     def lower(self, *args, **kwargs):  # passthrough for introspection
         return self._jitted.lower(*args, **kwargs)
 
-    def __call__(self, *args):
+    def compiled_for(self, *args):
+        """The executable for these arguments' signature (compiled on
+        first use) — its ``memory_analysis()`` / ``as_text()`` are how a
+        caller checks what the compiler made of the program."""
         leaves, treedef = jax.tree_util.tree_flatten(args)
         key = (treedef, tuple(_leaf_sig(x) for x in leaves))
         compiled = self._cache.get(key)
@@ -80,7 +83,10 @@ class _AotJit:
                 telemetry.inc("compile/recompiles")
             compiled = self._jitted.lower(*args).compile()
             self._cache[key] = compiled
-        return compiled(*args)
+        return compiled
+
+    def __call__(self, *args):
+        return self.compiled_for(*args)(*args)
 
 
 def aot_jit(fun, **jit_kwargs):
