@@ -1,8 +1,6 @@
 """What both drivers share: the program's config object, the benchmark's
 weights in the program's (hydra) layout, counters and compile events."""
 
-import math
-
 import jax
 import jax.numpy as jnp
 
@@ -104,12 +102,6 @@ def fault_counters() -> dict:
         return {}
     return {k: v for k, v in tel.registry.counters.items()
             if k.startswith("fault/") or k == "compile/recompiles"}
-
-
-def percentile(values, q: float) -> float:
-    """The q-quantile by rank: the smallest value with at least q of the sample at or below it."""
-    s = sorted(values)
-    return s[max(math.ceil(q * len(s)) - 1, 0)]
 
 
 def annotation(name: str):

@@ -16,6 +16,7 @@ import time
 import jax
 
 from benchmarks.drivers import common
+from benchmarks.lib import gaps as G
 from benchmarks.lib import reference as R
 from benchmarks.lib import traffic as T
 
@@ -84,22 +85,38 @@ class Cell:
             rec["req"].done.wait(timeout=WAIT_S + 60.0)
 
     def _fresh_hists(self):
-        """The stock histograms keep their last 512 observations; the window
-        wants all of them. Put large ones in their place, by name."""
+        """The stock span histograms keep their last 512 observations; the
+        window wants all of them. Put large ones in their place, by name.
+        (Token gaps need none: every request keeps its own token stamps.)"""
         from trlx_tpu.telemetry import TimingHist
 
-        names = ["serve/itl"] + [k for k in list(self.registry.hists) if k.startswith("time/serve/")]
         hists = {}
-        for name in names:
+        for name in [k for k in list(self.registry.hists) if k.startswith("time/serve/")]:
             h = TimingHist(window=1_000_000)
             h.first = 0.0  # so that every observation lands in the window
             self.registry.hists[name] = hists[name] = h
         return hists
 
     # ------------------------------------------------------------ window
+    def _itl_observed(self):
+        """How many token gaps the program has put into its own ``serve/itl`` so far."""
+        hist = self.registry.hists.get("serve/itl")
+        return hist.count if hist is not None else 0
+
+    def _token_gaps(self, flight):
+        """Every token gap of every request sent, from the requests' own
+        stamps, each with the stamp it ended at and the label of the
+        scheduler's step it ended in (what ran ahead of that step); and
+        how many steps the labelling could not join to their admissions."""
+        sent = [r for r in self.records if r["token_times"]]
+        labels, mislabelled = G.step_labels(
+            flight, [(r["admitted"], r["bucket"]) for r in sent if r["admitted"] and r["bucket"]])
+        return G.label_gaps([g for r in sent for g in G.request_gaps(r["token_times"])], flight, labels), mislabelled
+
     def window(self, seconds, tracer=None):
         before = (self.compiles.count, common.fault_counters())
         hists = self._fresh_hists()
+        itl_before = self._itl_observed()
         t0 = self.clock()
         if tracer:
             tracer.start()
@@ -107,8 +124,10 @@ class Cell:
             tracer.stop()
         time.sleep(max(t0 + seconds - self.clock(), 0.0))
         t1 = self.clock()
+        itl_observed = self._itl_observed() - itl_before
         observed = {name: list(h.window) for name, h in hists.items()}
-        flight = [r for r in self.sched.flight.snapshot() if t0 <= r["t"] <= t1] if self.sched.flight else []
+        flight_all = self.sched.flight.snapshot() if self.sched.flight else []
+        flight = [r for r in flight_all if t0 <= r["t"] <= t1]
         after = (self.compiles.count, common.fault_counters())
         self.stop.set()
         for t in self.threads:
@@ -118,25 +137,38 @@ class Cell:
             ok = req is not None and req.done.is_set() and req.error is None and bool(req.result)
             tr = req.trace if req is not None else None
             r.update(ok=ok, n_out=len(req.result) if ok else 0, last=req.result[-1] if ok else None,
+                     token_times=list(tr.token_times) if tr is not None else [],
+                     bucket=tr.bucket if tr is not None else None,
                      **{f: getattr(tr, f) if tr is not None else 0.0
                         for f in ("enqueued", "admitted", "first_token", "harvested")})
         sent = [r for r in self.records if t0 <= r["submit"] < t1]
         done = [r for r in sent if r["ok"]]
         first_in = sum(1 for r in self.records if r["ok"] and t0 <= r["first_token"] <= t1)
         self.sample = self._sample(done)
-        # where a run reads far off, these say whether the engine stalled and when
-        gaps = [(b["t"] - a["t"], a["t"] - t0) for a, b in zip(flight, flight[1:])]
-        longest = max(gaps) if gaps else (0.0, 0.0)
+        # a gap counts where its end stamp lies in the window, as a first token does
+        gaps, mislabelled = self._token_gaps(flight_all)
+        gaps = [g for g in gaps if t0 <= g[1] <= t1]
+        # where a run reads far off, these say whether the engine stalled, when, and in which phase
+        between = [(b["t"] - a["t"], a["t"] - t0) for a, b in zip(flight, flight[1:])]
+        longest = max(between) if between else (0.0, 0.0)
+        slowest = max(flight, key=lambda r: r["step_ms"], default=None)
         self.timeline = {"steps": len(flight), "longest_step_gap_ms": longest[0] * 1e3, "at_s": longest[1],
                          "slot_step_max_ms": max(observed.get("time/serve/slot_step", [0.0])) * 1e3,
-                         "ttft_max_ms": max(((r["first_token"] - r["submit"]) * 1e3 for r in done), default=0.0)}
+                         "ttft_max_ms": max(((r["first_token"] - r["submit"]) * 1e3 for r in done), default=0.0),
+                         "longest_step": slowest and {
+                             "at_s": slowest["t"] - t0,
+                             **{k: slowest[k] for k in ("step", "active", "admitted", "step_ms", "admit_ms", "fetch_ms",
+                                                        "harvest_ms") if k in slowest}},
+                         # the program's own count of gaps beside the benchmark's, from the requests' stamps
+                         "token_gaps": {"by_token_times": len(gaps), "by_serve_itl_count": itl_observed,
+                                        "steps_mislabelled": mislabelled}}
         counters = dict(self.registry.counters)
         self.measured = {
             "t0": t0, "t1": t1, "seconds": t1 - t0, "sent": sent, "done": done,
             # every output token stamped inside the window: a first token, or one gap after the token before it
-            "tokens_emitted": first_in + len(observed.get("serve/itl", [])),
+            "tokens_emitted": first_in + len(gaps),
             "requests": [r for r in self.records if r["ok"]],
-            "itl_s": observed.get("serve/itl", []),
+            "gaps": gaps,
             "slot_step_s": observed.get("time/serve/slot_step", []),
             "prefill_s": [x for k, v in observed.items() if k.startswith("time/serve/prefill") for x in v],
             "flight": flight, "slots": self.engine.slot_count(),
@@ -156,10 +188,19 @@ class Cell:
         metrics = {
             "serve_tokens_per_s": m["tokens_emitted"] / m["seconds"],
             "ttft_mean_ms": sum(ttft) / len(ttft),
-            "ttft_p95_ms": common.percentile(ttft, 0.95),
-            "itl_p95_ms": common.percentile(m["itl_s"], 0.95) * 1e3,
+            "ttft_p95_ms": G.percentile(ttft, 0.95),
+            # the token-gap tail: the mean of the gaps from the 90th to the 99th percentile by rank
+            "itl_tail_ms": G.band_mean([g[0] for g in m["gaps"]]) * 1e3,
         }
         return metrics, len(m["sent"]), len(m["sent"]) - len(m["done"])
+
+    def dump(self):
+        """The window's labelled gaps and flight records, for the look at a tail (``--dump 1``; not a result)."""
+        m = self.measured
+        return {"seconds": m["seconds"], "sent": len(m["sent"]), "tokens_emitted": m["tokens_emitted"],
+                "timeline": self.timeline,
+                "gaps": [[g * 1e3, end - m["t0"], label] for g, end, label in m["gaps"]],
+                "flight": [{**r, "t": r["t"] - m["t0"]} for r in m["flight"]]}
 
     def release(self):
         self.sched.stop()

@@ -1,5 +1,5 @@
 """A percentile, over the requests sent in the window, of the time between two of a request's stamps."""
-from benchmarks.drivers.common import percentile
+from benchmarks.lib.gaps import percentile
 
 
 def read(ctx, start, end, q=0.95, scale=1e3):

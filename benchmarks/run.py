@@ -13,7 +13,9 @@ a later PR adds files and an entry in ``BENCHMARK.json``.
 ``--rehearse 1`` runs the same path at the tiny sizes the files give under
 ``rehearse``, on whatever backend there is, and prints no device metric.
 ``--probe 1`` also reads the control and the planted faults (for setting
-limits; the driver never passes it)."""
+limits; the driver never passes it). ``--dump 1`` writes what the driver's
+``dump()`` keeps of the window (the serve cell: every token gap with its
+label, the flight records) under ``chiprun_out/dump/``, for a look at a tail."""
 
 import argparse
 import importlib
@@ -32,6 +34,13 @@ sys.path.insert(0, ROOT)
 def load(kind: str, name: str) -> dict:
     with open(os.path.join(HERE, kind, f"{name}.json")) as f:
         return json.load(f)
+
+
+def write_out(kind: str, name: str, payload: dict, indent=1):
+    """What ``--probe`` and ``--dump`` keep: ``chiprun_out/<kind>/<name>.json`` (the chip tool brings that directory back)."""
+    os.makedirs(os.path.join(ROOT, "chiprun_out", kind), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out", kind, f"{name}.json"), "w") as f:
+        json.dump(payload, f, indent=indent)
 
 
 def say(text: str):
@@ -72,6 +81,7 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, default=0)
     ap.add_argument("--rehearse", type=int, default=0)
     ap.add_argument("--probe", type=int, default=0)
+    ap.add_argument("--dump", type=int, default=0)
     args = ap.parse_args(argv)
 
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -120,6 +130,8 @@ def main(argv=None) -> int:
     metrics["setup_s"] = setup_s
     device["memory_peak_bytes"] = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices)
     say("window closed" + ("" if args.rehearse else f": {metrics}"))  # a CPU rehearsal's rates are not device numbers
+    if args.dump and hasattr(run, "dump"):
+        write_out("dump", f"{args.workload}.{args.seed}", {"metrics": metrics, **run.dump()}, indent=None)
     run.release()
     correct, compared, extra = run.check(probe=bool(args.probe))
     say(f"correct={correct}")
@@ -158,9 +170,8 @@ def main(argv=None) -> int:
     if args.rehearse:  # a rehearsal's timings are not device metrics: none is printed
         out = {}
     if args.probe:
-        os.makedirs(os.path.join(ROOT, "chiprun_out", "probe"), exist_ok=True)
-        with open(os.path.join(ROOT, "chiprun_out", "probe", f"{args.workload}.{args.seed}.json"), "w") as f:
-            json.dump({"compared": compared, "extra": extra, "metrics": metrics, "device": device}, f, indent=1)
+        write_out("probe", f"{args.workload}.{args.seed}",
+                  {"compared": compared, "extra": extra, "metrics": metrics, "device": device})
 
     from benchmarks.lib import lastline
 

@@ -1,5 +1,6 @@
-"""The readers PR 25 added, on a hand-made trace and flight list with known
-answers, the nothing-to-read cases included; both cells at rehearsal size."""
+"""The readers PR 25 and PR 28 added, on a hand-made trace, flight list and
+gap list with known answers, the nothing-to-read cases included; both cells
+at rehearsal size."""
 import json
 import os
 import subprocess
@@ -7,7 +8,7 @@ import sys
 
 import pytest
 
-from benchmarks.readers import flight_sum, host_span, program_gap, program_ms
+from benchmarks.readers import flight_sum, gap_stat, host_span, program_gap, program_ms
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 MS = 1_000_000  # ns
@@ -107,6 +108,19 @@ def test_host_span_per_cycle():
     assert host_span.read({"trace": None, "notes": {}}, spans=["rollout"], per="rollout") is None
 
 
+def test_gap_stat_reads_a_rank_and_notes_what_it_stands_among():
+    gaps = [(0.037, 1.0 + i, "none") for i in range(15)] + [(0.057, 20.0 + i, "b1p64") for i in range(4)] + [(0.08, 30.0, "multi")]
+    ctx = {"measured": {"gaps": gaps}, "notes": {}}
+    assert gap_stat.read(ctx, q=0.95) == pytest.approx(57.0)  # the 19th of 20
+    assert gap_stat.read(ctx, q=0.5) == pytest.approx(37.0)
+    note = ctx["notes"]["token_gaps"]
+    assert note["n"] == 20 and note["p50_ms"] == pytest.approx(37.0) and note["p99_ms"] == pytest.approx(80.0)
+    assert note["labels"]["b1p64"] == {"share": 0.2, "median_ms": pytest.approx(57.0)}
+    assert note["p95_at"]["label"] == "b1p64" and note["p95_at"]["cluster_above_ms"] == pytest.approx(80.0)
+    assert gap_stat.read({"measured": {"gaps": []}, "notes": {}}) is None
+    assert gap_stat.read({"measured": {}, "notes": {}}) is None  # the PPO cell: nothing to read
+
+
 @pytest.mark.parametrize("cell", ["gpt2-xl.ppo-sentiments", "gpt-j-6b.serve-closed16"])
 def test_both_cells_rehearse(cell):
     done = subprocess.run([sys.executable, "benchmarks/run.py", "--workload", cell, "--seed", "3000000019", "--seconds", "3",
@@ -115,3 +129,7 @@ def test_both_cells_rehearse(cell):
     assert done.returncode == 0, done.stderr[-2000:]
     line = json.loads(done.stdout.strip().splitlines()[-1])
     assert line["correct"] is True and line["metrics"] == {}  # a rehearsal prints no device metric
+    if "serve" in cell:  # the gaps' reader ran and found the window's gaps, every one of them labelled
+        note = line["notes"]["token_gaps"]
+        assert note["n"] == line["notes"]["timeline"]["token_gaps"]["by_token_times"] > 100
+        assert "none" in note["labels"] and "unknown" not in note["labels"]
