@@ -704,3 +704,146 @@ def test_contiguous_fallback_still_serves(fresh_registry):
         assert registry.counters.get("compile/recompiles", 0.0) == 0.0
     finally:
         s.stop()
+
+
+# --------------------------------------------------------------------- #
+# two classes of page (a model with window layers)
+# --------------------------------------------------------------------- #
+
+
+def two_class_cache(pages=32, window_pages=12, page_size=4, window=8):
+    return RadixCache(pages, page_size, window_pages=window_pages,
+                      window=window)
+
+
+def commit_with_windows(cache, tokens):
+    """What admission + prefill do for one prompt: full-class pages for
+    every block, a window-class page attached to every whole block."""
+    n = -(-len(tokens) // cache.page_size)
+    pages = cache.alloc(n)
+    cache.commit(tokens, pages)
+    wpages = cache.alloc_window(len(tokens) // cache.page_size)
+    for page, wpage in zip(pages, wpages):
+        assert cache.attach_window(page, wpage)
+    return pages, wpages
+
+
+def test_two_class_match_retains_the_window_pages_before_its_end():
+    cache = two_class_cache()
+    toks = list(range(100, 124))  # 6 blocks of 4; window = 2 blocks
+    pages, wpages = commit_with_windows(cache, toks)
+    cache.release_all(pages)
+    cache.release_window(wpages)
+    got, wmap = cache.match_classes(toks + [1, 2])
+    assert got == pages and wmap == {4: wpages[4], 5: wpages[5]}
+    assert cache.window_allocator.refcount(wpages[5]) == 1
+    assert cache.window_allocator.refcount(wpages[3]) == 0
+
+
+def test_two_class_match_is_cut_back_where_window_pages_are_gone():
+    cache = two_class_cache()
+    toks = list(range(100, 124))
+    pages, wpages = commit_with_windows(cache, toks)
+    cache.release_all(pages)
+    cache.release_window(wpages)
+    node_of = cache._node_of_wpage
+    cache._drop_wpage(node_of[wpages[4]])  # block 4's window page is gone
+    got, wmap = cache.match_classes(toks + [1, 2])
+    # blocks 0..3 are the longest prefix whose last 2 window pages are kept
+    assert got == pages[:4] and sorted(wmap) == [2, 3]
+    cache.release_all(got)
+    cache.release_window(list(wmap.values()))
+    for wpage in wpages[:4]:
+        cache._drop_wpage(node_of[wpage])
+    assert cache.match_classes(toks + [1, 2]) == ([], {})  # or to nothing
+
+
+def test_window_pages_deep_inside_a_prefix_are_evicted_first():
+    cache = two_class_cache(window_pages=8)
+    toks = list(range(100, 124))  # 6 blocks, window 2 blocks
+    pages, wpages = commit_with_windows(cache, toks)
+    cache.release_all(pages)
+    cache.release_window(wpages)
+    assert cache.window_free_pages() == 2
+    assert cache.evict_window(4) == 4
+    # blocks 0..3 have 2 or more committed blocks below them: no match
+    # that reads their window pages can end there
+    assert sorted(cache._node_of_wpage) == sorted(wpages[4:])
+    got, wmap = cache.match_classes(toks + [1])
+    assert len(got) == 6 and sorted(wmap) == [4, 5]  # the whole match survives
+
+
+def test_a_question_below_a_document_does_not_make_its_last_pages_deep():
+    cache = two_class_cache(window_pages=10)
+    doc = list(range(100, 124))  # 6 blocks, window 2 blocks
+    pages, wpages = commit_with_windows(cache, doc)
+    cache.release_all(pages)
+    cache.release_window(wpages)
+    # a turn: the document (a hit) and a question of two whole blocks below it
+    got, wmap = cache.match_classes(doc + list(range(8)) + [1])
+    fresh = cache.alloc(2)
+    cache.commit(doc + list(range(8)), got + fresh)
+    qw = cache.alloc_window(2)
+    for page, wpage in zip(fresh, qw):
+        assert cache.attach_window(page, wpage)
+    cache.release_all(got + fresh)
+    cache.release_window(list(wmap.values()) + qw)
+    # the document's last block has two blocks below it now, but prompts END
+    # on it: blocks 4 and 5 stay; blocks 0..3 go first
+    assert cache.evict_window(4) == 4
+    assert sorted(cache._node_of_wpage) == sorted(wpages[4:] + qw)
+    got, wmap = cache.match_classes(doc + [9, 9, 9, 9, 9])  # the next question
+    assert len(got) == 6 and sorted(wmap) == [4, 5]
+
+
+def test_window_class_exhaustion_refuses_and_reservations_hold():
+    cache = two_class_cache(window_pages=6)
+    assert cache.alloc_window(0, reserve=4) == []
+    assert cache.window_available_pages() == 2
+    assert cache.alloc_window(3) is None  # a newcomer cannot eat the reserve
+    mine = cache.alloc_window(3, reserved=True)  # its holder can
+    assert len(mine) == 3 and cache.window_reserved == 1
+    cache.release_window(mine[:1], behind=True, back_to_reserve=True)
+    assert cache.window_reserved == 2 and cache.window_pages_freed == 1
+    assert cache.window_free_pages() == 4 and cache.window_available_pages() == 2
+
+
+def test_a_shared_window_page_outlives_the_first_release():
+    cache = two_class_cache()
+    toks = list(range(100, 116))
+    pages, wpages = commit_with_windows(cache, toks)  # the owner's refs
+    got, wmap = cache.match_classes(toks + [9])  # a sharer maps the tail
+    assert sorted(wmap) == [2, 3]
+    cache.release_window([wpages[3]], behind=True)  # the owner passes it
+    assert cache.window_allocator.refcount(wpages[3]) == 1  # the sharer's
+    assert wpages[3] in cache._node_of_wpage
+    cache.release_window([wmap[3]])
+    assert cache.window_allocator.refcount(wpages[3]) == 0
+    assert wpages[3] in cache._node_of_wpage  # cached: the trie keeps it
+
+
+def test_evicting_a_block_frees_both_of_its_pages():
+    cache = two_class_cache(pages=4, window_pages=4)
+    toks = list(range(100, 116))
+    pages, wpages = commit_with_windows(cache, toks)
+    cache.release_all(pages)
+    cache.release_window(wpages)
+    assert cache.free_pages() == 0 and cache.window_free_pages() == 0
+    assert cache.alloc(1) is not None  # evicts a batch of leaves
+    assert cache.window_free_pages() == cache.allocator.free_count() + 1
+    assert cache.cached_pages() == len(cache._node_of_wpage)
+
+
+def test_one_class_eviction_order_is_least_recently_used_leaf_first():
+    """The heap keeps the order a fresh scan per victim gave: leaves by
+    ``last_used``, a parent once its last child is gone."""
+    cache = RadixCache(8, 2)
+    a = cache.alloc(3)
+    cache.commit([1, 2, 3, 4, 5, 6], a)  # chain a0 - a1 - a2
+    b = cache.alloc(2)
+    cache.commit([1, 2, 9, 9, 8, 8], [a[0]] + b)  # a0 - b0 - b1
+    cache.release_all(a + b)
+    cache.match([1, 2, 3, 4, 5, 6, 0])  # touch the a-chain
+    cache.release_all(a)
+    assert cache.evict(3) == 3  # b1, b0 (now a leaf), then a2
+    assert sorted(cache._node_of_page) == sorted(a[:2])

@@ -518,3 +518,119 @@ def test_soak_mixed_lengths_no_recompiles_no_leaks(engine, fresh_registry):
         assert fresh_registry.counters.get("serve/request_errors", 0.0) == 0.0
     finally:
         s.stop()
+
+
+# --------------------------------------------------------------------- #
+# two classes of page: the window class through the scheduler
+# --------------------------------------------------------------------- #
+
+
+def _window_blocks_needed(pos, window=16, page_size=8):
+    """Logical pages the query at ``pos`` still reads, and the one it
+    writes: from the page of ``pos - window + 1`` on."""
+    return list(range(max((pos - window + 1) // page_size, 0),
+                      pos // page_size + 1))
+
+
+def test_a_window_page_is_released_at_the_step_that_passes_it():
+    from test_cohere2_moe import build, prompt_of
+
+    sched = build()
+    req = sched.submit(prompt_of(13), max_new_tokens=16)  # one-shot class
+    sched._admit()
+    (slot, live), = sched._live.items()
+    rt, ring = sched.runtime, sched.runtime.ring_pages
+    freed, steps = [sched.cache.window_pages_freed], 0
+    while sched._live:
+        pos = 13 + steps  # the position this step writes
+        sched._step()
+        steps += 1
+        freed.append(sched.cache.window_pages_freed)
+        if sched._live:
+            # exactly the pages the step's window reached stay mapped ...
+            assert sorted(live.wmap) == _window_blocks_needed(pos)
+            # ... and the ring shows them and nothing else
+            row = sched._wtable[slot]
+            assert {int(p) for p in row if p < rt.num_window_pages} == \
+                set(live.wmap.values())
+            assert all(row[b % ring] == p for b, p in live.wmap.items())
+    # 13 + 16 positions, window 16, pages of 8: page 0 goes when position
+    # 23 is written (its last key, 7, is 16 behind), never earlier
+    assert freed[23 - 13] == 0 and freed[23 - 13 + 1] == 1
+    assert req.result and sched.cache.window_reserved == 0
+
+
+def test_a_sharers_window_keeps_a_page_the_owner_has_passed():
+    from test_cohere2_moe import SPEC, build, prompt_of, reference_logits
+
+    sched = build()
+    doc = prompt_of(32)  # 4 whole pages, committed by the first request
+    first = sched.submit(doc + [5], max_new_tokens=16)
+    sched._admit()
+    second = sched.submit(doc + [6, 7], max_new_tokens=4)
+    sched._admit()
+    assert second.trace.prefix_blocks_hit == 4
+    a, b = (sched._live[s] for s in sorted(sched._live, key=lambda s: sched._live[s].request.seq))
+    shared = b.wmap[3]  # page 3 of the document, from the trie
+    assert a.wmap[3] == shared
+    assert sched.cache.window_allocator.refcount(shared) == 2
+    while not second.done.is_set():
+        sched._step()
+    # the sharer is gone; the owner still reads page 3 (position 33 + 5)
+    assert sched.cache.window_allocator.refcount(shared) == 1
+    while sched._live:
+        sched._step()
+    assert 3 not in a.wmap and sched.cache.window_allocator.refcount(shared) == 0
+    assert shared in sched.cache._node_of_wpage  # the trie keeps it cached
+    for req, prompt in ((first, doc + [5]), (second, doc + [6, 7])):
+        ref = reference_logits(SPEC, prompt, req.result)
+        assert req.result == [int(t) for t in ref.argmax(-1)]
+    cache = sched.cache
+    assert not any(cache.allocator._ref) and not any(cache.window_allocator._ref)
+    assert cache.window_reserved == 0
+
+
+@pytest.mark.parametrize("short_class", ["window", "full"])
+def test_exhaustion_of_either_class_queues(short_class):
+    from test_cohere2_moe import build, prompt_of
+
+    # one request of 16 + 16 tokens takes 4 pages of each class
+    sizes = {"pages": 64, "window_pages": 24}
+    sizes["window_pages" if short_class == "window" else "pages"] = 6
+    sched = build(**sizes)
+    reqs = [sched.submit(prompt_of(16, seed=i), max_new_tokens=16)
+            for i in range(2)]
+    sched._admit()
+    assert len(sched._live) == 1 and sched.queue_depth() == 1
+    assert sched._starved and reqs[1].trace.queue_reentries == 1
+    while not reqs[0].done.is_set():
+        sched._admit()
+        sched._step()
+    sched._admit()  # the first one's pages came back: the second is admitted
+    assert len(sched._live) == 1 and sched.queue_depth() == 0
+    while sched._live:
+        sched._step()
+    assert all(len(r.result) == 16 for r in reqs)
+    assert sched.cache.window_reserved == 0
+
+
+def test_flight_record_and_counters_of_a_two_class_model(fresh_registry):
+    from test_cohere2_moe import build, prompt_of
+
+    sched = build()
+    req = sched.submit(prompt_of(40), max_new_tokens=12)
+    sched._admit()
+    while sched._live:
+        sched._step()
+    sched._record_step(0.0, 1.0)
+    rec = sched.flight.snapshot()[-1]
+    assert {"pairs_here", "experts_hit", "pages_full", "pages_window",
+            "window_freed", "moe_load"} <= set(rec)
+    counters = fresh_registry.counters
+    assert rec["pairs_here"] == counters["serve/moe/pairs_here"] > 0
+    assert rec["window_freed"] == counters["serve/window_pages_freed"] >= 3
+    assert counters["serve/prefill_chunks"] == 2  # 40 = 16 + 16 + a rest of 8
+    gauges = fresh_registry.gauges
+    assert gauges["serve/pages_in_use{class=window}"] == rec["pages_window"]
+    assert gauges["serve/moe/load_max_over_mean"] >= 1.0
+    assert len(req.result) == 12

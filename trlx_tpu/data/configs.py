@@ -29,7 +29,7 @@ class ModelSpec:
     contract when importing pretrained HF weights.
     """
 
-    arch: str = "gpt2"  # gpt2 | gptj | gptneox | llama
+    arch: str = "gpt2"  # gpt2 | gptj | gptneox | llama | cohere2_moe
     vocab_size: int = 50257
     n_layer: int = 12
     n_head: int = 12
@@ -41,18 +41,78 @@ class ModelSpec:
     tie_lm_head: bool = True  # gpt2 ties lm_head to wte; gptj/neox do not
     n_kv_heads: int = 0  # grouped-query attention (llama); 0 => n_head
     rope_theta: float = 10000.0
+    head_size: int = 0  # width of one head; 0 => d_model // n_head
+    # A model whose layers differ repeats ``layer_pattern`` over its depth:
+    # layer n is of kind ``layer_pattern[n % len]``, "full" (causal over the
+    # whole context) or "window" (the last ``window`` keys). Empty: every
+    # layer is full, as the dense families are. ``rope_kinds`` names the
+    # kinds whose q/k are rotated; a kind left out carries no positions.
+    layer_pattern: tuple = ()
+    window: int = 0
+    rope_kinds: tuple = ("full", "window")
+    # Routed experts (0 = a dense FFN): the router scores all ``n_experts``
+    # and takes ``experts_per_token``; this process holds the
+    # ``experts_held`` experts from ``expert_offset`` on (0 held => all)
+    # and computes their part of the sum. ``n_shared_experts`` run on every
+    # token and are averaged.
+    n_experts: int = 0
+    experts_per_token: int = 0
+    n_shared_experts: int = 0
+    expert_width: int = 0  # 0 => d_ff
+    experts_held: int = 0
+    expert_offset: int = 0
+    logit_scale: float = 1.0
 
     def __post_init__(self):
         if self.d_ff == 0:
             object.__setattr__(self, "d_ff", 4 * self.d_model)
+        for name in ("layer_pattern", "rope_kinds"):  # lists from JSON/YAML
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+        if self.n_experts:
+            if self.expert_width == 0:
+                object.__setattr__(self, "expert_width", self.d_ff)
+            if self.experts_held == 0:
+                object.__setattr__(self, "experts_held", self.n_experts)
+            if not 0 < self.experts_per_token <= self.n_experts:
+                raise ValueError("experts_per_token must be in 1..n_experts")
+            if self.expert_offset + self.experts_held > self.n_experts:
+                raise ValueError(
+                    "expert_offset + experts_held exceeds n_experts"
+                )
         if self.d_model % self.n_head != 0:
             raise ValueError("d_model must be divisible by n_head")
         if self.n_kv_heads and self.n_head % self.n_kv_heads != 0:
             raise ValueError("n_head must be divisible by n_kv_heads")
+        if any(k not in ("full", "window") for k in self.layer_pattern):
+            raise ValueError(
+                f"layer_pattern {self.layer_pattern} names a kind other "
+                f"than full | window"
+            )
+        if "window" in self.layer_pattern:
+            if self.window <= 0:
+                raise ValueError("a window layer needs window > 0")
+            if "full" not in self.layer_pattern:
+                raise ValueError(
+                    "layer_pattern needs a full layer beside its window "
+                    "layers (the full class carries the slot's lanes)"
+                )
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_head
+        return self.head_size or self.d_model // self.n_head
+
+    def layer_kind(self, layer: int) -> str:
+        """"full" | "window": the kind of layer ``layer`` (0-based)."""
+        if not self.layer_pattern:
+            return "full"
+        return self.layer_pattern[layer % len(self.layer_pattern)]
+
+    @property
+    def page_classes(self) -> tuple:
+        """The classes of KV page a paged pool of this model keeps, one
+        per kind of layer present: ("full",) for a dense model."""
+        kinds = set(self.layer_pattern) or {"full"}
+        return tuple(k for k in ("full", "window") if k in kinds)
 
     @property
     def kv_heads(self) -> int:

@@ -26,6 +26,7 @@ a fori_loop over layers with the stacked cache carried whole (same in-place
 property, O(1) program size; ~14% slower at 12 layers).
 """
 
+import functools
 import math
 import os
 from typing import Any, Callable, Dict, NamedTuple, Optional
@@ -37,6 +38,7 @@ from trlx_tpu.data.configs import ModelSpec
 from trlx_tpu.models.transformer import (
     ArchFlags,
     NEG_INF,
+    _mixed_layers,
     apply_blocks_with_cache,
     attention_scores,
     block_apply,
@@ -48,6 +50,7 @@ from trlx_tpu.models.transformer import (
     quantize_kv,
     positions_from_mask,
     project_logits,
+    rope_of,
 )
 from trlx_tpu.ops.sampling import SamplingParams, sample_token
 from trlx_tpu.utils import tree_bytes
@@ -295,6 +298,12 @@ def generate(
     B, P = prompt_tokens.shape
     G = config.gen_size
     S = P + G
+    if _mixed_layers(spec):
+        raise NotImplementedError(
+            f"generate() runs every layer over one contiguous cache; arch "
+            f"'{spec.arch}' mixes {spec.layer_pattern} layers and is served "
+            f"through the paged slot pool (serve.scheduler: slots)"
+        )
     if S > spec.n_positions:
         raise ValueError(
             f"prompt ({P}) + gen_size ({G}) = {S} exceeds the model's "
@@ -660,19 +669,31 @@ def init_slot_pool(spec: ModelSpec, seg_sizes, num_slots: int,
     )
 
 
-def init_page_pool(spec: ModelSpec, seg_sizes, num_pages: int,
+def init_page_pool(spec: ModelSpec, seg_sizes, num_pages,
                    page_size: int, cache_dtype=jnp.bfloat16):
     """PAGE pool: per segment, per layer, (k, v) pages [num_pages,
     page_size, Hkv, hd] — the block-granular replacement for
     init_slot_pool: HBM is sized in pages shared by all slots, not slots
     x worst-case length. The int8 tier makes each of k/v a ``(codes,
-    scales)`` pair (transformer.init_paged_kv_cache)."""
+    scales)`` pair (transformer.init_paged_kv_cache).
+
+    ``num_pages`` is one count, or ``{class: count}`` for a model whose
+    layers differ: every layer of a kind keeps ``num_pages[kind]`` pages,
+    and all layers of a kind are addressed through one table (a page id
+    of the window class names that page in every window layer)."""
+    def pages_of(layer):
+        if isinstance(num_pages, dict):
+            return num_pages[spec.layer_kind(layer)]
+        return num_pages
+
+    first = [sum(seg_sizes[:i]) for i in range(len(seg_sizes))]
     return tuple(
         tuple(
-            init_paged_kv_cache(spec, num_pages, page_size, cache_dtype)
-            for _ in range(size)
+            init_paged_kv_cache(spec, pages_of(lo + i), page_size,
+                                cache_dtype)
+            for i in range(size)
         )
-        for size in seg_sizes
+        for lo, size in zip(first, seg_sizes)
     )
 
 
@@ -691,25 +712,116 @@ def _pool_page_geometry(pool):
     return pages.shape[0], pages.shape[1]
 
 
-def _apply_layers_with_pool(spec, segments, seg_sizes, pool, h, **block_kw):
+#: the short name of a layer's kind in a named scope (``layer3.full/attn``)
+KIND_TAG = {"full": "full", "window": "win"}
+
+
+def _apply_layers_with_pool(spec, segments, seg_sizes, pool, h,
+                            by_kind=None, **block_kw):
     """The serve programs' unrolled layer loop: layer ``n`` reads and
     writes its own pool leaves ``pool[seg][i]`` through block_apply
     (whose ``kv_cache`` is one layer's (k, v)) and nothing else touches
-    them. Returns (new_pool, h)."""
+    them. Returns (new_pool, h).
+
+    ``by_kind`` ({"full": kwargs, "window": kwargs}) gives each layer the
+    arguments of ITS kind (its class of page table, its mask or reader)
+    over the shared ``block_kw``; the layer's kind then also stands in
+    its scope (``layer2.win``), and whether it rotates follows
+    ``ModelSpec.rope_kinds``. Without it every layer is the same, as the
+    dense families' are."""
     flags = ArchFlags.for_spec(spec)
     new_pool, layer = [], 0
     for seg, size, seg_pool in zip(segments, seg_sizes, pool):
         new_seg = []
         for i in range(size):
-            with jax.named_scope(f"layer{layer}"):
+            scope, kw = f"layer{layer}", block_kw
+            if by_kind is not None:
+                kind = spec.layer_kind(layer)
+                scope = f"{scope}.{KIND_TAG[kind]}"
+                kw = {**block_kw, **by_kind[kind],
+                      "use_rope": rope_of(spec, flags, layer)}
+            with jax.named_scope(scope):
                 p_i = jax.tree_util.tree_map(lambda x, i=i: x[i], seg)
                 h, kv = block_apply(
-                    spec, flags, p_i, h, kv_cache=seg_pool[i], **block_kw
+                    spec, flags, p_i, h, kv_cache=seg_pool[i], **kw
                 )
             new_seg.append(kv)
             layer += 1
         new_pool.append(tuple(new_seg))
     return tuple(new_pool), h
+
+
+def paged_context_attention(q, k_pages, v_pages, table, page_base, q_pos,
+                            window: int, page_size: int,
+                            pages_per_block: int = 8):
+    """Attention of q [B, P, H, hd] (logical positions ``q_pos`` [B, P])
+    against the keys a page table holds, in blocks of ``pages_per_block``
+    pages with a running softmax: the float32 scores of a 1,024-token
+    chunk against a 29k context are 15 GB and are never formed; a block's
+    are [B, H, P, pages_per_block * page_size]. Table entry ``i`` of row
+    ``b`` holds logical page ``page_base[b] + i`` (``page_base`` None: 0).
+    Key position ``kp`` is seen by query position ``qp`` iff ``kp <= qp``
+    and, under ``window`` > 0, ``kp > qp - window``; an entry at the
+    sentinel is seen by nobody. The loop stops at the last block any
+    query reaches, so a chunk early in a long prompt pays for the context
+    it has. Grouped-query heads run against the compact K/V."""
+    B, P, H, hd = q.shape
+    num_pages, ps, Hkv, _ = k_pages.shape
+    if ps != page_size:
+        raise ValueError(f"pool page size {ps} != page_size {page_size}")
+    G = H // Hkv
+    ppb = pages_per_block
+    n_entries = table.shape[1]
+    n_blocks = -(-n_entries // ppb)
+    table = jnp.pad(table, ((0, 0), (0, n_blocks * ppb - n_entries)),
+                    constant_values=num_pages)
+    base = jnp.zeros((B,), jnp.int32) if page_base is None else page_base
+    q5 = q.reshape(B, P, Hkv, G, hd)
+    scale = jax.lax.rsqrt(jnp.float32(hd))
+    # the last table entry any query reaches, in blocks
+    reach = jnp.max(q_pos // page_size - base[:, None]) + 1
+    hi = jnp.clip(-(-reach // ppb), 1, n_blocks)
+    qp = q_pos[:, None, None, :, None]  # [B, 1, 1, P, 1]
+
+    def body(i, carry):
+        m, l, acc = carry
+        ids = jax.lax.dynamic_slice_in_dim(table, i * ppb, ppb, axis=1)
+        live = ids < num_pages  # [B, ppb]
+        ids = jnp.where(live, ids, 0)
+        k = k_pages[ids].reshape(B, ppb * ps, Hkv, hd).astype(q.dtype)
+        v = v_pages[ids].reshape(B, ppb * ps, Hkv, hd).astype(q.dtype)
+        page = base[:, None] + i * ppb + jnp.arange(ppb)[None, :]
+        kp = (page[:, :, None] * ps + jnp.arange(ps)[None, None, :])
+        kp = kp.reshape(B, 1, 1, 1, ppb * ps)
+        seen = (kp <= qp) & jnp.repeat(live, ps, axis=1)[:, None, None, None]
+        if window > 0:
+            seen = seen & (kp > qp - window)
+        s = jnp.einsum("bphgd,bkhd->bhgpk", q5, k).astype(jnp.float32)
+        s = jnp.where(seen, s * scale, NEG_INF)
+        m_new = jnp.maximum(m, s.max(-1))
+        alpha = jnp.exp(m - m_new)
+        probs = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
+        l = alpha * l + probs.sum(-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bhgpk,bkhd->bhgpd", probs.astype(v.dtype), v
+        ).astype(jnp.float32)
+        return m_new, l, acc
+
+    m0 = jnp.full((B, Hkv, G, P), 2.0 * NEG_INF, jnp.float32)
+    l0 = jnp.zeros((B, Hkv, G, P), jnp.float32)
+    acc0 = jnp.zeros((B, Hkv, G, P, hd), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, hi, body, (m0, l0, acc0))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]  # [B, Hkv, G, P, hd]
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, P, H, hd).astype(q.dtype)
+
+
+def moe_stats_array(stats):
+    """[L, 4] float32 (pairs_here, experts_hit, load_max, load_mean) from
+    the per-layer tuples block_apply collected; None for a dense model."""
+    if not stats:
+        return None
+    return jnp.stack([jnp.stack([jnp.asarray(x, jnp.float32) for x in s])
+                      for s in stats])
 
 
 def prefill_into_slots(
@@ -729,6 +841,8 @@ def prefill_into_slots(
     page_size: Optional[int] = None,
     start: Optional[jnp.ndarray] = None,  # [Bp] int32 page-aligned prefix
     prefix_context: bool = False,
+    window_tables: Optional[jnp.ndarray] = None,  # [Bp, Rp] int32
+    window_base: Optional[jnp.ndarray] = None,  # [Bp] int32 logical page
 ):
     """Write a prompt bucket's KV + first-step logits into pool slots.
 
@@ -758,6 +872,16 @@ def prefill_into_slots(
     prefix hit skips the matched tokens' forward entirely); with
     ``False`` (all-zero ``start``) attention stays local to the prompt,
     which is cheaper and exactly mirrors the contiguous prefill.
+
+    A model with window layers keeps two classes of page and is always
+    prefilled with ``prefix_context=True``: ``window_tables`` [Bp, Rp]
+    maps, for the window class, logical pages ``window_base[b] + i``
+    (the pages the suffix writes and the ``window`` positions before it),
+    and attention runs in blocks against the pool
+    (:func:`paged_context_attention`), the window cut in its mask. One
+    such call is also one CHUNK of a long prompt: a chunk that is not the
+    last carries the sentinel in ``slot_ids`` and leaves only its pages
+    behind. Returns ``(pool, state, moe_stats [L, 4])`` for such a model.
     """
     B, P = prompt_tokens.shape
     T = state.valid.shape[1]
@@ -772,6 +896,7 @@ def prefill_into_slots(
             spec, segments, seg_sizes, embed, ln_f, pool, state,
             prompt_tokens, prompt_mask, slot_ids, max_new, compute_dtype,
             attention_fn, page_tables, page_size, start, prefix_context,
+            window_tables, window_base,
         )
     real_len = prompt_mask.sum(axis=-1)
 
@@ -824,6 +949,7 @@ def _prefill_into_pages(
     spec, segments, seg_sizes, embed, ln_f, pool, state,
     prompt_tokens, prompt_mask, slot_ids, max_new, compute_dtype,
     attention_fn, page_tables, page_size, start, prefix_context,
+    window_tables=None, window_base=None,
 ):
     """Paged half of prefill_into_slots (see its docstring): suffix
     forward + block-scatter through per-row page tables; state rows
@@ -851,7 +977,40 @@ def _prefill_into_pages(
     # (every block trainable leaves the frozen trunk empty)
     pool_dtype = jax.tree_util.tree_leaves(pool)[0].dtype
     quantized = pool_dtype == jnp.int8
-    if not prefix_context:
+    moe_stats = []
+    if _mixed_layers(spec):
+        # two classes of page: each kind of layer writes through its own
+        # table and reads it back in blocks, the window cut in the mask
+        if not prefix_context or window_tables is None:
+            raise ValueError(
+                "a model with window layers is prefilled through the "
+                "prefix-context program with its window-class tables"
+            )
+        attend = functools.partial(
+            paged_context_attention, q_pos=positions, page_size=page_size
+        )
+        new_pool, h = _apply_layers_with_pool(
+            spec, segments, seg_sizes, pool, h,
+            by_kind={
+                "full": dict(
+                    page_table=page_tables,
+                    paged_attend_fn=functools.partial(
+                        attend, table=page_tables, page_base=None, window=0
+                    ),
+                ),
+                "window": dict(
+                    page_table=window_tables, page_base=window_base,
+                    paged_attend_fn=functools.partial(
+                        attend, table=window_tables,
+                        page_base=window_base, window=spec.window,
+                    ),
+                ),
+            },
+            mask_bias=None, positions=positions, cache_row_offsets=start,
+            page_size=page_size, attention_fn=attention_fn,
+            token_mask=prompt_mask > 0, moe_stats=moe_stats,
+        )
+    elif not prefix_context:
         # no committed prefix: local causal prefill (the exact ops the
         # contiguous path runs), then one block-scatter into the pages.
         # int8 tier: the LOCAL buffer stays full-precision in the compute
@@ -938,6 +1097,8 @@ def _prefill_into_pages(
             page_tables.astype(jnp.int32), mode="drop"
         ),
     )
+    if moe_stats:
+        return new_pool, new_state, moe_stats_array(moe_stats)
     return new_pool, new_state
 
 
@@ -1154,6 +1315,7 @@ def decode_step(
     compute_dtype=jnp.bfloat16,
     attention_fn=attention_scores,
     paged_decode_fn=None,
+    window_table: Optional[jnp.ndarray] = None,  # [S, R] int32 ring
 ):
     """One decode step for every pool slot: sample from each slot's
     carried logits, forward the sampled tokens against the pool (per-slot
@@ -1173,6 +1335,14 @@ def decode_step(
     ``paged_decode_fn`` (``serve.attention: pallas``) is forwarded to
     each layer's ``block_apply`` so the paged gather + score runs as the
     fused kernel; ``None`` keeps the jnp oracle path.
+
+    ``window_table`` [S, R] (a model with window layers; the host owns
+    and advances it) is each slot's ring over the window class: logical
+    page ``n`` sits at entry ``n % R``. Its validity lane is computed
+    here from the slot's write position, so what lies outside the window
+    is masked and the attention programs see a short table and no window
+    argument. Such a model also returns its routing counts, ``moe_stats``
+    [L, 4], as a sixth output.
     """
     S = state.offset.shape[0]
     segments, seg_sizes = _segments_of(blocks)
@@ -1218,14 +1388,51 @@ def decode_step(
         pt_step = jnp.where(
             emitted[:, None], state.pages, jnp.int32(num_pages)
         )
-    new_pool, h = _apply_layers_with_pool(
-        spec, segments, seg_sizes, pool, h,
-        mask_bias=bias, positions=pos, cache_row_offsets=state.offset,
-        page_table=pt_step if paged else None,
-        page_size=page_size if paged else None,
-        attention_fn=attention_fn,
-        paged_decode_fn=paged_decode_fn if paged else None,
-    )
+    moe_stats = []
+    if _mixed_layers(spec):
+        if not paged or window_table is None:
+            raise ValueError(
+                "a model with window layers decodes over the paged pool "
+                "with its window-class ring table"
+            )
+        R = window_table.shape[1]
+        # ring entry r, offset o holds the newest position p <= cur of
+        # page r (mod R); it is seen iff it lies inside the window
+        cur = state.offset[:, None]  # [S, 1] the position written now
+        j = jnp.arange(R * page_size)[None, :]
+        cur_page = cur // page_size
+        page = cur_page - (cur_page - j // page_size) % R
+        p = page * page_size + j % page_size
+        seen = (p >= 0) & (p <= cur) & (p > cur - spec.window) \
+            & emitted[:, None]
+        wbias = jnp.where(seen, 0.0, NEG_INF)[:, None, None, :].astype(
+            jnp.float32
+        )
+        new_pool, h = _apply_layers_with_pool(
+            spec, segments, seg_sizes, pool, h,
+            by_kind={
+                "full": dict(mask_bias=bias, page_table=pt_step),
+                "window": dict(
+                    mask_bias=wbias, ring=True,
+                    page_table=jnp.where(
+                        emitted[:, None], window_table, PAGE_SENTINEL
+                    ),
+                ),
+            },
+            positions=pos, cache_row_offsets=state.offset,
+            page_size=page_size, attention_fn=attention_fn,
+            paged_decode_fn=paged_decode_fn,
+            token_mask=emitted[:, None], moe_stats=moe_stats,
+        )
+    else:
+        new_pool, h = _apply_layers_with_pool(
+            spec, segments, seg_sizes, pool, h,
+            mask_bias=bias, positions=pos, cache_row_offsets=state.offset,
+            page_table=pt_step if paged else None,
+            page_size=page_size if paged else None,
+            attention_fn=attention_fn,
+            paged_decode_fn=paged_decode_fn if paged else None,
+        )
     with jax.named_scope("head"):
         h_normed = layer_norm(ln_f, h, spec.layer_norm_epsilon)
         next_logits = project_logits(embed, spec, h_normed)[:, 0]  # [S, V]
@@ -1242,4 +1449,7 @@ def decode_step(
         logits=next_logits,
         pages=state.pages,
     )
+    if moe_stats:
+        return (new_pool, new_state, tok, emitted, finished,
+                moe_stats_array(moe_stats))
     return new_pool, new_state, tok, emitted, finished

@@ -95,6 +95,13 @@ def spec_from_hf_config(hf_config) -> ModelSpec:
             n_kv_heads=hf_config.num_key_value_heads,
             rope_theta=getattr(hf_config, "rope_theta", 10000.0),
         )
+    if mt == "cohere2_moe":
+        from trlx_tpu.models.transformer import require_supported
+
+        require_supported(
+            ModelSpec(arch=mt, n_experts=1, experts_per_token=1),
+            hf_import=hf_config.model_type,
+        )
     raise ValueError(f"unsupported HF model_type '{mt}'")
 
 

@@ -188,6 +188,7 @@ class HydraPolicy:
             positions,
             remat=self.remat,
             attention_fn=self._attn(),
+            first_layer=self.spec.n_layer - self.k,
         )
         return layer_norm(branch["ln_f"], h, self.spec.layer_norm_epsilon)
 

@@ -20,6 +20,15 @@ Architecture variants (selected by ModelSpec.arch):
   one layernorm, unbiased attention projections, untied head.
 - "gptneox": rotary, parallel residual with separate MLP layernorm, biased
   projections, untied head.
+- "cohere2_moe": parallel block on one scale-only LayerNorm, grouped-query
+  attention with an explicit head size, window (RoPE) and full (NoPE)
+  layers by ``ModelSpec.layer_pattern``, a routed + shared expert FFN,
+  tied head times ``logit_scale``.
+
+A block is three parts, one small function per kind: the **mixer**
+(:func:`_qkv`: projections, RoPE or none; the window is the caller's mask),
+the **cache** writer/reader (:func:`_paged_cache`, :func:`_slot_cache`, or
+none) and the **FFN** (:func:`_dense_ffn` | :func:`moe_ffn`).
 """
 
 from dataclasses import dataclass
@@ -46,6 +55,8 @@ class ArchFlags:
     rotary_interleaved: bool = False  # gptj rotates every-two; neox rotates halves
     rmsnorm: bool = False  # llama: RMSNorm (scale only, no mean/bias)
     swiglu: bool = False  # llama: silu(gate) * up MLP instead of gelu
+    centred_scale_norm: bool = False  # cohere: LayerNorm without a bias
+    moe: bool = False  # routed + shared experts in the FFN's place
 
     @classmethod
     def for_spec(cls, spec: ModelSpec) -> "ArchFlags":
@@ -58,6 +69,11 @@ class ArchFlags:
             return cls(True, True, True, True)
         if arch == "llama":
             return cls(False, True, False, True, rmsnorm=True, swiglu=True)
+        if arch == "cohere2_moe":
+            if not spec.n_experts:
+                raise ValueError("arch 'cohere2_moe' needs n_experts > 0")
+            return cls(True, True, False, False, rotary_interleaved=True,
+                       centred_scale_norm=True, moe=True)
         raise ValueError(f"unknown arch '{spec.arch}'")
 
 
@@ -77,6 +93,7 @@ def init_block_params(
     leading axis `n_layers`."""
     flags = ArchFlags.for_spec(spec)
     d, f = spec.d_model, spec.d_ff
+    d_q = spec.n_head * spec.head_dim  # == d unless the head size is explicit
     d_kv = spec.kv_heads * spec.head_dim  # < d under grouped-query attn
     keys = jax.random.split(rng, 8)
     # GPT-2 residual scaling: two residual additions per block.
@@ -87,6 +104,8 @@ def init_block_params(
         return jnp.stack([initer(k, shape) for k in jax.random.split(key, n_layers)])
 
     def norm_params():
+        if flags.centred_scale_norm:
+            return {"scale_centred": jnp.ones((n_layers, d), dtype)}
         p = {"scale": jnp.ones((n_layers, d), dtype)}
         if not flags.rmsnorm:
             p["bias"] = jnp.zeros((n_layers, d), dtype)
@@ -95,19 +114,23 @@ def init_block_params(
     blocks: Params = {
         "ln_1": norm_params(),
         "attn": {
-            "wq": stack(lambda k, s: _dense_init(k, s, dtype), (d, d), keys[0]),
+            "wq": stack(lambda k, s: _dense_init(k, s, dtype), (d, d_q), keys[0]),
             "wk": stack(lambda k, s: _dense_init(k, s, dtype), (d, d_kv), keys[1]),
             "wv": stack(lambda k, s: _dense_init(k, s, dtype), (d, d_kv), keys[2]),
             "wo": stack(
-                lambda k, s: _dense_init(k, s, dtype, resid_scale), (d, d), keys[3]
+                lambda k, s: _dense_init(k, s, dtype, resid_scale), (d_q, d), keys[3]
             ),
         },
-        "mlp": {
-            "w_in": stack(lambda k, s: _dense_init(k, s, dtype), (d, f), keys[4]),
-            "w_out": stack(
-                lambda k, s: _dense_init(k, s, dtype, resid_scale), (f, d), keys[5]
-            ),
-        },
+    }
+    if flags.moe:
+        blocks.update(_init_moe_params(keys[4:8], spec, n_layers, dtype,
+                                       resid_scale))
+        return blocks
+    blocks["mlp"] = {
+        "w_in": stack(lambda k, s: _dense_init(k, s, dtype), (d, f), keys[4]),
+        "w_out": stack(
+            lambda k, s: _dense_init(k, s, dtype, resid_scale), (f, d), keys[5]
+        ),
     }
     if flags.swiglu:
         blocks["mlp"]["w_gate"] = stack(
@@ -128,6 +151,36 @@ def init_block_params(
     return blocks
 
 
+def _init_moe_params(keys, spec: ModelSpec, n_layers: int, dtype,
+                     resid_scale) -> Params:
+    """The expert FFN's stacked parameters: the router over ALL experts,
+    the experts HELD here ([L, held, ...]) and the shared experts, which
+    are kept as one SwiGLU of width ``n_shared * width`` (the sum of the
+    shared experts' outputs is that SwiGLU's output; the average is its
+    output over ``n_shared``)."""
+    d, f, held = spec.d_model, spec.expert_width, spec.experts_held
+    fs = max(spec.n_shared_experts, 1) * f
+
+    def normal(key, shape, scale=0.02):
+        return _dense_init(key, (n_layers, *shape), dtype, scale)
+
+    ke = jax.random.split(keys[0], 3)
+    ks = jax.random.split(keys[1], 3)
+    out = {"moe": {
+        "router": normal(keys[2], (d, spec.n_experts)),
+        "w_gate": normal(ke[0], (held, d, f)),
+        "w_up": normal(ke[1], (held, d, f)),
+        "w_down": normal(ke[2], (held, f, d), resid_scale),
+    }}
+    if spec.n_shared_experts:
+        out["shared"] = {
+            "w_gate": normal(ks[0], (d, fs)),
+            "w_up": normal(ks[1], (d, fs)),
+            "w_down": normal(ks[2], (fs, d), resid_scale),
+        }
+    return out
+
+
 def init_embed_params(rng: jax.Array, spec: ModelSpec, dtype=jnp.float32) -> Params:
     flags = ArchFlags.for_spec(spec)
     k_wte, k_wpe, k_head = jax.random.split(rng, 3)
@@ -145,8 +198,11 @@ def init_embed_params(rng: jax.Array, spec: ModelSpec, dtype=jnp.float32) -> Par
 
 
 def init_ln_f_params(spec: ModelSpec, dtype=jnp.float32) -> Params:
+    flags = ArchFlags.for_spec(spec)
+    if flags.centred_scale_norm:
+        return {"scale_centred": jnp.ones((spec.d_model,), dtype)}
     p: Params = {"scale": jnp.ones((spec.d_model,), dtype)}
-    if not ArchFlags.for_spec(spec).rmsnorm:  # RMSNorm (llama) has no bias
+    if not flags.rmsnorm:  # RMSNorm (llama) has no bias
         p["bias"] = jnp.zeros((spec.d_model,), dtype)
     return p
 
@@ -161,11 +217,17 @@ def layer_norm(p: Params, x: jnp.ndarray, eps: float) -> jnp.ndarray:
 
     Dispatches on the param structure: a norm WITHOUT a bias entry is an
     RMSNorm (llama) — scale * x / sqrt(mean(x^2) + eps), no centering —
-    so every call site (policy/ilql/generation final norms included)
+    and one whose only entry is ``scale_centred`` is a LayerNorm without
+    a bias (cohere), so every call site (policy/ilql/generation final norms included)
     handles both families unchanged.
     """
     dtype = x.dtype
     x32 = x.astype(jnp.float32)
+    if "scale_centred" in p:  # cohere: mean-centred, scale only
+        y = (x32 - x32.mean(-1, keepdims=True)) * jax.lax.rsqrt(
+            x32.var(-1, keepdims=True) + eps
+        )
+        return (y * p["scale_centred"].astype(jnp.float32)).astype(dtype)
     if "bias" not in p:  # RMSNorm
         y = x32 * jax.lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
         return (y * p["scale"].astype(jnp.float32)).astype(dtype)
@@ -279,6 +341,207 @@ def gelu_new(x: jnp.ndarray) -> jnp.ndarray:
     return 0.5 * x * (1.0 + jnp.tanh(0.7978845608028654 * (x + 0.044715 * x3)))
 
 
+def _qkv(spec: ModelSpec, flags: ArchFlags, p: Params, h, positions,
+         use_rope: bool):
+    """The mixer's front half: the shared norm and the q/k/v projections,
+    rotated where this layer carries positions (``use_rope``; a NoPE layer
+    is the same function without the rotation). Returns (x, q, k, v) with
+    x the normed input the parallel block's FFN reads too."""
+    B, T, _ = h.shape
+    H, hd, Hkv = spec.n_head, spec.head_dim, spec.kv_heads
+    x = layer_norm(p["ln_1"], h, spec.layer_norm_epsilon)
+    attn = p["attn"]
+    q = _project(x, attn["wq"], attn.get("bq")).reshape(B, T, H, hd)
+    k = _project(x, attn["wk"], attn.get("bk")).reshape(B, T, Hkv, hd)
+    v = _project(x, attn["wv"], attn.get("bv")).reshape(B, T, Hkv, hd)
+    if use_rope:
+        q = apply_rotary(q, positions, spec.rotary_dim,
+                         flags.rotary_interleaved, spec.rope_theta)
+        k = apply_rotary(k, positions, spec.rotary_dim,
+                         flags.rotary_interleaved, spec.rope_theta)
+    return x, q, k, v
+
+
+def _paged_write(kv_cache, k, v, cache_row_offsets, page_table, page_size,
+                 page_base=None, ring=False):
+    """Cache writer, paged: scatter the fresh K/V of token j of row b to
+    logical position ``cache_row_offsets[b] + j`` through the row's page
+    table. Logical page ``n`` sits at table entry ``n`` (the full class),
+    ``n - page_base[b]`` (a prefill's view of the window class) or
+    ``n % max_pages`` (``ring``: the window class at decode). Entries past
+    the table, or whose page id is the out-of-bounds sentinel, drop."""
+    T = k.shape[1]
+    k_entry, v_entry = kv_cache  # [num_pages, page_size, Hkv, hd]
+    quantized = isinstance(k_entry, (tuple, list))
+    if quantized:
+        (k_cache, k_sc), (v_cache, v_sc) = k_entry, v_entry
+    else:
+        k_cache, v_cache = k_entry, v_entry
+    num_pages = k_cache.shape[0]
+    max_pages = page_table.shape[1]
+    with jax.named_scope("kv_write"):
+        # logical buffer position of each fresh token, then page-id
+        # gather -> physical (page row, in-page offset) scatter
+        pos_buf = cache_row_offsets[:, None] + jnp.arange(T)[None, :]
+        page_idx = pos_buf // page_size
+        in_off = pos_buf % page_size
+        if ring:
+            page_idx = page_idx % max_pages
+        in_table = page_idx < max_pages
+        entry = jnp.minimum(page_idx, max_pages - 1)
+        if page_base is not None:
+            page_idx = page_idx - page_base[:, None]
+            in_table = (page_idx >= 0) & (page_idx < max_pages)
+            entry = jnp.clip(page_idx, 0, max_pages - 1)
+        pids = jnp.where(
+            in_table,
+            jnp.take_along_axis(page_table, entry, axis=1),
+            num_pages,  # out past the table: drop like a sentinel page
+        )
+        if quantized:
+            kq, ks = quantize_kv(k)  # codes [B,T,Hkv,hd], scale [B,T,Hkv]
+            vq, vs = quantize_kv(v)
+            k_full = k_cache.at[pids, in_off].set(kq, mode="drop")
+            v_full = v_cache.at[pids, in_off].set(vq, mode="drop")
+            k_sc = k_sc.at[pids, in_off].set(ks, mode="drop")
+            v_sc = v_sc.at[pids, in_off].set(vs, mode="drop")
+            return ((k_full, k_sc), (v_full, v_sc))
+        k_full = k_cache.at[pids, in_off].set(
+            k.astype(k_cache.dtype), mode="drop"
+        )
+        v_full = v_cache.at[pids, in_off].set(
+            v.astype(v_cache.dtype), mode="drop"
+        )
+        return (k_full, v_full)
+
+
+def _paged_read(new_cache, page_table, page_size, dtype):
+    """Cache reader, paged (the jnp path): each row's pages gathered back
+    into table order, [B, max_pages * page_size, Hkv, hd]."""
+    k_entry, v_entry = new_cache
+    quantized = isinstance(k_entry, (tuple, list))
+    B, max_pages = page_table.shape
+    with jax.named_scope("kv_read"):
+        if quantized:
+            (k_full, k_sc), (v_full, v_sc) = k_entry, v_entry
+            ctx_pt = jnp.clip(page_table, 0, k_full.shape[0] - 1)
+            k_ctx = dequantize_kv(k_full[ctx_pt], k_sc[ctx_pt], dtype)
+            v_ctx = dequantize_kv(v_full[ctx_pt], v_sc[ctx_pt], dtype)
+        else:
+            ctx_pt = jnp.clip(page_table, 0, k_entry.shape[0] - 1)
+            k_ctx = k_entry[ctx_pt].astype(dtype)
+            v_ctx = v_entry[ctx_pt].astype(dtype)
+        tail = k_ctx.shape[-2:]
+        return (k_ctx.reshape(B, max_pages * page_size, *tail),
+                v_ctx.reshape(B, max_pages * page_size, *tail))
+
+
+def _slot_cache(kv_cache, k, v, cache_offset, cache_row_offsets):
+    """Cache writer, contiguous: one buffer slot for every row
+    (``cache_offset``) or a slot per row (``cache_row_offsets``, T == 1).
+    The reader is the whole buffer."""
+    B, T = k.shape[:2]
+    k_cache, v_cache = kv_cache
+    with jax.named_scope("kv_write"):
+        if cache_row_offsets is not None:
+            if T != 1:
+                raise ValueError(
+                    f"cache_row_offsets (per-row cache writes) "
+                    f"requires a single fresh token per row, got T={T}"
+                )
+            rows = jnp.arange(B)
+            k_full = k_cache.at[rows, cache_row_offsets].set(
+                k[:, 0].astype(k_cache.dtype), mode="drop"
+            )
+            v_full = v_cache.at[rows, cache_row_offsets].set(
+                v[:, 0].astype(v_cache.dtype), mode="drop"
+            )
+        else:
+            k_full = jax.lax.dynamic_update_slice_in_dim(
+                k_cache, k.astype(k_cache.dtype), cache_offset, axis=1
+            )
+            v_full = jax.lax.dynamic_update_slice_in_dim(
+                v_cache, v.astype(v_cache.dtype), cache_offset, axis=1
+            )
+    return k_full, v_full
+
+
+def _dense_ffn(flags: ArchFlags, mp: Params, mlp_in):
+    if flags.swiglu:
+        gate = jax.nn.silu(_project(mlp_in, mp["w_gate"]))
+        return _project(gate * _project(mlp_in, mp["w_in"]), mp["w_out"])
+    return _project(
+        gelu_new(_project(mlp_in, mp["w_in"], mp["b_in"])),
+        mp["w_out"],
+        mp["b_out"],
+    )
+
+
+def moe_ffn(spec: ModelSpec, p: Params, x, token_mask=None):
+    """Routed + shared experts on x [B, T, D]; returns (out, stats).
+
+    The router scores ALL ``n_experts`` in float32 (sigmoid), takes the
+    top ``experts_per_token`` and normalises their scores over all of
+    those chosen. This process holds experts ``[expert_offset,
+    expert_offset + experts_held)``: the (token, expert) pairs whose
+    expert lies there are sorted by expert and computed as one grouped
+    product over the experts held (``jax.lax.ragged_dot``: a native grouped
+    matmul on the TPU, whose work follows the rows inside the groups).
+    Every such pair is computed, whatever the skew; there is no capacity
+    and nothing is dropped. What the experts held elsewhere would have
+    added is left out. ``token_mask`` [B, T] takes padding and idle rows
+    out of the pairs. The shared experts run on every token as one SwiGLU
+    of width ``n_shared * width`` whose output is divided by ``n_shared``:
+    the average of the shared experts' outputs.
+
+    stats: int32/float32 scalars (pairs_here, experts_hit, load_max,
+    load_mean) of this call, for the scheduler's counters."""
+    B, T, D = x.shape
+    N, K = B * T, spec.experts_per_token
+    held, off = spec.experts_held, spec.expert_offset
+    mp = p["moe"]
+    xf = x.reshape(N, D)
+    with jax.named_scope("router"):
+        scores = jax.nn.sigmoid(
+            xf.astype(jnp.float32) @ mp["router"].astype(jnp.float32)
+        )  # [N, E]
+        top_s, top_e = jax.lax.top_k(scores, K)  # [N, K]
+        gates = top_s / top_s.sum(-1, keepdims=True)
+    with jax.named_scope("experts"):
+        local = top_e - off
+        here = (local >= 0) & (local < held)
+        if token_mask is not None:
+            here = here & token_mask.reshape(N, 1)
+        # pairs held elsewhere sort behind every group and are never computed
+        key = jnp.where(here, local, held).reshape(N * K)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+        xs = xf[order // K]  # [N*K, D]: each pair's token, grouped by expert
+        gate = jax.lax.ragged_dot(xs, mp["w_gate"].astype(x.dtype), sizes)
+        up = jax.lax.ragged_dot(xs, mp["w_up"].astype(x.dtype), sizes)
+        y = jax.lax.ragged_dot(
+            jax.nn.silu(gate) * up, mp["w_down"].astype(x.dtype), sizes,
+            preferred_element_type=jnp.float32,
+        )  # [N*K, D]; rows past the groups are not defined
+        w = jnp.where(here, gates, 0.0).reshape(N * K)[order]
+        y = jnp.where(w[:, None] > 0, y * w[:, None], 0.0)
+        # back to (token, choice) order; a token's pairs are summed in f32
+        routed = y[jnp.argsort(order)].reshape(N, K, D).sum(1)
+    out = routed.astype(x.dtype)
+    if "shared" in p:
+        with jax.named_scope("shared"):
+            sp = p["shared"]
+            act = jax.nn.silu(_project(xf, sp["w_gate"])) * _project(
+                xf, sp["w_up"]
+            )
+            out = out + _project(act, sp["w_down"]) * jnp.asarray(
+                1.0 / spec.n_shared_experts, x.dtype
+            )
+    stats = (sizes.sum(), (sizes > 0).sum(), sizes.max(),
+             sizes.sum().astype(jnp.float32) / held)
+    return out.reshape(B, T, D), stats
+
+
 def block_apply(
     spec: ModelSpec,
     flags: ArchFlags,
@@ -293,6 +556,12 @@ def block_apply(
     page_table: Optional[jnp.ndarray] = None,
     page_size: Optional[int] = None,
     paged_decode_fn=None,
+    use_rope: Optional[bool] = None,
+    page_base: Optional[jnp.ndarray] = None,
+    ring: bool = False,
+    paged_attend_fn=None,
+    token_mask: Optional[jnp.ndarray] = None,
+    moe_stats: Optional[list] = None,
 ) -> Tuple[jnp.ndarray, Optional[Tuple[jnp.ndarray, jnp.ndarray]]]:
     """One transformer block on hidden states `h` [B, T, D].
 
@@ -338,26 +607,33 @@ def block_apply(
     ``fn(q[:, 0], k_pages, v_pages, page_table, bias_row)`` operating
     on the post-scatter pool; the jnp scatter (and T > 1 prefill) are
     unchanged, keeping the jnp path as the A/B oracle.
+
+    A model whose layers differ is served by the same function: the
+    caller hands each layer ITS class of page table and ITS mask (the
+    window lives in the mask), and says `use_rope` (None: as the arch
+    does everywhere). `page_base` / `ring` say how a window-class table
+    maps logical pages (:func:`_paged_write`). `paged_attend_fn(q,
+    k_pages, v_pages) -> [B, T, H, hd]` replaces gather + attention_fn
+    with the caller's own reader of the post-scatter pool (the blocked
+    prefill attention of models/generation.py). `token_mask` [B, T]
+    marks real tokens for the expert FFN; `moe_stats`, a list, receives
+    that layer's routing counts.
     """
     B, T, D = h.shape
     H, hd = spec.n_head, spec.head_dim
     Hkv = spec.kv_heads
     eps = spec.layer_norm_epsilon
 
-    # the named scopes (attn, kv_write, kv_read, mlp) land in every op's
-    # metadata, so a device trace says which phase of which layer an XLA
-    # op belongs to (docs/source/observability.rst)
+    # the named scopes (attn, kv_write, kv_read, mlp | router, experts,
+    # shared) land in every op's metadata, so a device trace says which
+    # phase of which layer an XLA op belongs to
+    # (docs/source/observability.rst)
     with jax.named_scope("attn"):
-        x = layer_norm(p["ln_1"], h, eps)
+        x, q, k, v = _qkv(
+            spec, flags, p, h, positions,
+            flags.use_rotary if use_rope is None else use_rope,
+        )
         attn = p["attn"]
-        q = _project(x, attn["wq"], attn.get("bq")).reshape(B, T, H, hd)
-        k = _project(x, attn["wk"], attn.get("bk")).reshape(B, T, Hkv, hd)
-        v = _project(x, attn["wv"], attn.get("bv")).reshape(B, T, Hkv, hd)
-        if flags.use_rotary:
-            q = apply_rotary(q, positions, spec.rotary_dim,
-                             flags.rotary_interleaved, spec.rope_theta)
-            k = apply_rotary(k, positions, spec.rotary_dim,
-                             flags.rotary_interleaved, spec.rope_theta)
 
     def expand_kv(t):
         """H-wide KV for attention fns that can't consume the compact GQA
@@ -377,45 +653,14 @@ def block_apply(
             )
         if page_size is None or page_size <= 0:
             raise ValueError(f"page_table given but page_size={page_size}")
-        k_entry, v_entry = kv_cache  # [num_pages, page_size, Hkv, hd]
-        quantized = isinstance(k_entry, (tuple, list))
-        if quantized:
-            (k_cache, k_sc), (v_cache, v_sc) = k_entry, v_entry
-        else:
-            k_cache, v_cache = k_entry, v_entry
-        num_pages = k_cache.shape[0]
-        max_pages = page_table.shape[1]
-        with jax.named_scope("kv_write"):
-            # logical buffer position of each fresh token, then page-id
-            # gather -> physical (page row, in-page offset) scatter
-            pos_buf = cache_row_offsets[:, None] + jnp.arange(T)[None, :]
-            page_idx = pos_buf // page_size
-            in_off = pos_buf % page_size
-            pids = jnp.where(
-                page_idx < max_pages,
-                jnp.take_along_axis(
-                    page_table, jnp.minimum(page_idx, max_pages - 1),
-                    axis=1,
-                ),
-                num_pages,  # out past the table: drop like a sentinel page
-            )
-            if quantized:
-                kq, ks = quantize_kv(k)  # codes [B,T,Hkv,hd], scale [B,T,Hkv]
-                vq, vs = quantize_kv(v)
-                k_full = k_cache.at[pids, in_off].set(kq, mode="drop")
-                v_full = v_cache.at[pids, in_off].set(vq, mode="drop")
-                k_sc = k_sc.at[pids, in_off].set(ks, mode="drop")
-                v_sc = v_sc.at[pids, in_off].set(vs, mode="drop")
-                new_cache = ((k_full, k_sc), (v_full, v_sc))
-            else:
-                k_full = k_cache.at[pids, in_off].set(
-                    k.astype(k_cache.dtype), mode="drop"
-                )
-                v_full = v_cache.at[pids, in_off].set(
-                    v.astype(v_cache.dtype), mode="drop"
-                )
-                new_cache = (k_full, v_full)
-        if paged_decode_fn is not None and T == 1:
+        new_cache = _paged_write(
+            kv_cache, k, v, cache_row_offsets, page_table, page_size,
+            page_base, ring,
+        )
+        if paged_attend_fn is not None:
+            with jax.named_scope("attn"):
+                a = paged_attend_fn(q, new_cache[0], new_cache[1])
+        elif paged_decode_fn is not None and T == 1:
             # fused kernel: page-table walk + online softmax in one
             # pallas_call against the just-updated pool; bias collapses
             # to the per-row validity lane [B, max_pages * page_size]
@@ -433,48 +678,14 @@ def block_apply(
             # wrote (the radix cache admits same-batch prefix sharers
             # against pages whose content materializes earlier in this
             # same program)
-            with jax.named_scope("kv_read"):
-                ctx_pt = jnp.clip(page_table, 0, num_pages - 1)
-                if quantized:
-                    k_ctx = dequantize_kv(
-                        k_full[ctx_pt], k_sc[ctx_pt], q.dtype
-                    )
-                    v_ctx = dequantize_kv(
-                        v_full[ctx_pt], v_sc[ctx_pt], q.dtype
-                    )
-                else:
-                    k_ctx = k_full[ctx_pt].astype(q.dtype)
-                    v_ctx = v_full[ctx_pt].astype(q.dtype)
-                k_ctx = expand_kv(
-                    k_ctx.reshape(B, max_pages * page_size, Hkv, hd)
-                )
-                v_ctx = expand_kv(
-                    v_ctx.reshape(B, max_pages * page_size, Hkv, hd)
-                )
+            k_ctx, v_ctx = _paged_read(new_cache, page_table, page_size,
+                                       q.dtype)
+            k_ctx, v_ctx = expand_kv(k_ctx), expand_kv(v_ctx)
     elif kv_cache is not None:
-        k_cache, v_cache = kv_cache
-        with jax.named_scope("kv_write"):
-            if cache_row_offsets is not None:
-                if T != 1:
-                    raise ValueError(
-                        f"cache_row_offsets (per-row cache writes) "
-                        f"requires a single fresh token per row, got T={T}"
-                    )
-                rows = jnp.arange(B)
-                k_full = k_cache.at[rows, cache_row_offsets].set(
-                    k[:, 0].astype(k_cache.dtype), mode="drop"
-                )
-                v_full = v_cache.at[rows, cache_row_offsets].set(
-                    v[:, 0].astype(v_cache.dtype), mode="drop"
-                )
-            else:
-                k_full = jax.lax.dynamic_update_slice_in_dim(
-                    k_cache, k.astype(k_cache.dtype), cache_offset, axis=1
-                )
-                v_full = jax.lax.dynamic_update_slice_in_dim(
-                    v_cache, v.astype(v_cache.dtype), cache_offset, axis=1
-                )
-            new_cache = (k_full, v_full)
+        k_full, v_full = _slot_cache(
+            kv_cache, k, v, cache_offset, cache_row_offsets
+        )
+        new_cache = (k_full, v_full)
         with jax.named_scope("kv_read"):
             k_ctx = expand_kv(k_full.astype(q.dtype))
             v_ctx = expand_kv(v_full.astype(q.dtype))
@@ -482,29 +693,27 @@ def block_apply(
         k_ctx, v_ctx = expand_kv(k), expand_kv(v)
 
     with jax.named_scope("attn"):
-        if a is None:  # not the fused paged kernel's
+        if a is None:  # not a fused paged reader's
             a = attention_fn(q, k_ctx, v_ctx, mask_bias)
-        a = _project(a.reshape(B, T, D), attn["wo"], attn.get("bo"))
+        a = _project(a.reshape(B, T, H * hd), attn["wo"], attn.get("bo"))
 
-    def mlp(mlp_in):
-        mp = p["mlp"]
-        if flags.swiglu:
-            gate = jax.nn.silu(_project(mlp_in, mp["w_gate"]))
-            return _project(gate * _project(mlp_in, mp["w_in"]), mp["w_out"])
-        return _project(
-            gelu_new(_project(mlp_in, mp["w_in"], mp["b_in"])),
-            mp["w_out"],
-            mp["b_out"],
-        )
+    if flags.moe:
+        # the parallel block: attention and experts read the same x
+        m, stats = moe_ffn(spec, p, x, token_mask)
+        if moe_stats is not None:
+            moe_stats.append(stats)
+        return h + a + m, new_cache
 
     with jax.named_scope("mlp"):
         if flags.parallel_block:
             mlp_in = layer_norm(p["ln_2"], h, eps) \
                 if flags.separate_mlp_ln else x
-            return h + a + mlp(mlp_in), new_cache
+            return h + a + _dense_ffn(flags, p["mlp"], mlp_in), new_cache
 
         h = h + a
-        return h + mlp(layer_norm(p["ln_2"], h, eps)), new_cache
+        return h + _dense_ffn(
+            flags, p["mlp"], layer_norm(p["ln_2"], h, eps)
+        ), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -513,16 +722,74 @@ def block_apply(
 
 
 def causal_mask_bias(
-    attention_mask: jnp.ndarray, dtype=jnp.float32
+    attention_mask: jnp.ndarray, dtype=jnp.float32, window: int = 0
 ) -> jnp.ndarray:
     """Additive [B, 1, T, T] bias combining causality and padding.
 
-    attention_mask: [B, T] with 1 = real token.
+    attention_mask: [B, T] with 1 = real token. ``window`` > 0 keeps, for
+    the query at buffer slot i, the keys at slots i - window < j <= i (a
+    window layer; slots are positions for the unpadded rows such a model
+    is given).
     """
     B, T = attention_mask.shape
     causal = jnp.tril(jnp.ones((T, T), bool))
+    if window > 0:
+        causal = causal & ~jnp.tril(jnp.ones((T, T), bool), -window)
     allowed = causal[None, :, :] & (attention_mask[:, None, :] > 0)
     return jnp.where(allowed, 0.0, NEG_INF).astype(dtype)[:, None, :, :]
+
+
+def window_of(spec: ModelSpec, layer: int) -> int:
+    """The window of layer ``layer``: 0 for a full layer."""
+    return spec.window if spec.layer_kind(layer) == "window" else 0
+
+
+def rope_of(spec: ModelSpec, flags: ArchFlags, layer: int) -> bool:
+    return flags.use_rotary and spec.layer_kind(layer) in spec.rope_kinds
+
+
+def _mixed_layers(spec: ModelSpec) -> bool:
+    """Whether the model has window layers beside its full ones (and so
+    two classes of KV page, two masks, per-layer rotation)."""
+    return "window" in spec.layer_pattern
+
+
+#: what a model with routed experts or window layers runs under, per
+#: setting; anything else is refused by :func:`require_supported`
+_NEW_ARCH_RUNS_UNDER = {
+    "trainer": ((), "training through a router (its place in the hydra "
+                    "split, an auxiliary load loss) is not built; serve "
+                    "the model instead"),
+    "hf_import": ((), "no converter for this checkpoint layout; build "
+                      "the model from model.model_spec"),
+    "kv_dtype": (("bf16",), "the int8 page tier is not wired to two "
+                            "classes of page; use kv_dtype: bf16"),
+    "weights_dtype": (("bf16",), "the int8 weight tier does not cover "
+                                 "expert stacks; use weights_dtype: bf16"),
+    "speculation": (("off",), "the verifier reads one class of page "
+                              "table; use speculation: off"),
+    "mesh": ((None,), "there is no expert axis in serve/layouts.py; "
+                      "serve one chip's share on the default mesh"),
+    "scheduler": (("slots",), "the static scheduler decodes over one "
+                              "contiguous cache; use scheduler: slots"),
+    "kv_layout": (("paged",), "window layers keep a second class of "
+                              "page; use kv_layout: paged"),
+}
+
+
+def require_supported(spec: ModelSpec, **settings) -> None:
+    """The one place that refuses what an arch cannot run yet: raises
+    NotImplementedError naming the setting, its value and the arch. The
+    dense families run under every setting and pass."""
+    if not (spec.n_experts or _mixed_layers(spec)):
+        return
+    for name, value in settings.items():
+        allowed, hint = _NEW_ARCH_RUNS_UNDER[name]
+        if value not in allowed:
+            raise NotImplementedError(
+                f"{name}={value!r} is not supported with arch "
+                f"'{spec.arch}' (routed experts, window layers): {hint}"
+            )
 
 
 def mask_arg_for(
@@ -555,24 +822,60 @@ def apply_blocks(
     positions: jnp.ndarray,
     remat: bool = False,
     attention_fn=attention_scores,
+    first_layer: int = 0,
 ) -> jnp.ndarray:
-    """Run stacked blocks over `h` with one lax.scan."""
+    """Run stacked blocks over `h` with one lax.scan.
+
+    A model whose layers differ (``ModelSpec.layer_pattern``) keeps one
+    stacked tree and one scanned body too: the kind of layer
+    ``first_layer + i`` rides the scan as two flags, which pick the
+    layer's mask (the window is cut out of ``mask_bias``) and gate its
+    rotation (a NoPE layer rotates by position 0, which is the identity,
+    exactly). ``first_layer`` is the depth at which this stack starts
+    (the hydra policy's top branch)."""
     flags = ArchFlags.for_spec(spec)
-
-    def body(carry, p_layer):
-        out, _ = block_apply(
-            spec, flags, p_layer, carry, mask_bias, positions,
-            attention_fn=attention_fn,
-        )
-        return out, None
-
-    if remat:
-        body = jax.checkpoint(body)
-
     n_layers = jax.tree_util.tree_leaves(blocks)[0].shape[0]
     if n_layers == 0:
         return h
-    h, _ = jax.lax.scan(body, h, blocks)
+
+    if _mixed_layers(spec):
+        if mask_bias.ndim != 4:
+            raise ValueError(
+                "a model with window layers takes the additive "
+                "[B, 1, T, T] mask (no raw-mask attention_fn)"
+            )
+        T = mask_bias.shape[-1]
+        behind = jnp.tril(jnp.ones((T, T), bool), -spec.window)
+        window_bias = jnp.where(behind, NEG_INF, mask_bias)
+        layers = range(first_layer, first_layer + n_layers)
+        is_window = jnp.array([window_of(spec, n) > 0 for n in layers])
+        has_rope = jnp.array([rope_of(spec, flags, n) for n in layers])
+
+        def body(carry, xs):
+            p_layer, win, rope = xs
+            out, _ = block_apply(
+                spec, flags, p_layer, carry,
+                jnp.where(win, window_bias, mask_bias),
+                jnp.where(rope, positions, 0),
+                attention_fn=attention_fn,
+            )
+            return out, None
+
+        xs = (blocks, is_window, has_rope)
+    else:
+        def body(carry, p_layer):
+            out, _ = block_apply(
+                spec, flags, p_layer, carry, mask_bias, positions,
+                attention_fn=attention_fn,
+                use_rope=rope_of(spec, flags, first_layer),
+            )
+            return out, None
+
+        xs = blocks
+
+    if remat:
+        body = jax.checkpoint(body)
+    h, _ = jax.lax.scan(body, h, xs)
     return h
 
 
@@ -723,7 +1026,10 @@ def project_logits(embed: Params, spec: ModelSpec, h_normed: jnp.ndarray) -> jnp
         logits = h_normed @ head["w"].astype(h_normed.dtype) + head["b"].astype(
             h_normed.dtype
         )
-    return logits.astype(jnp.float32)
+    logits = logits.astype(jnp.float32)
+    if spec.logit_scale != 1.0:
+        logits = logits * spec.logit_scale
+    return logits
 
 
 def lm_logits(

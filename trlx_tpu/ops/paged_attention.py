@@ -73,6 +73,7 @@ NEG_INF = -1e9  # matches trlx_tpu.models.transformer.NEG_INF
 def _decode_kernel(
     # scalar prefetch
     pt_ref,  # [S, max_pages] int32 page table (host data)
+    live_ref,  # [S] int32 leading table entries that hold a visible key
     # tensor operands (per-block views; see BlockSpecs below)
     q_ref,  # [1, Hkv, G, hd] this slot's query rows, grouped by kv head
     k_ref,  # [1, page_size, Hkv, hd] the page the index map gathered
@@ -85,7 +86,7 @@ def _decode_kernel(
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
-    p = pl.program_id(1)
+    s_id, p = pl.program_id(0), pl.program_id(1)
     Hkv, hd = q_ref.shape[1], q_ref.shape[3]
 
     @pl.when(p == 0)
@@ -95,6 +96,28 @@ def _decode_kernel(
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     scale = jax.lax.rsqrt(jnp.float32(hd))
+
+    # past the slot's live extent every key is masked: the page-step is
+    # skipped whole (its block index repeats, so nothing is fetched either)
+    @pl.when(p < live_ref[s_id])
+    def _page():
+        _score_page(q_ref, k_ref, v_ref, bias_ref, rest, m_scr, l_scr,
+                    acc_scr, scale, quantized)
+
+    @pl.when(p == pl.num_programs(1) - 1)
+    def _finish():
+        for h in range(Hkv):
+            o_ref[0, h] = (
+                acc_scr[h] / jnp.maximum(l_scr[h][:, :1], 1e-30)
+            ).astype(o_ref.dtype)
+
+
+def _score_page(q_ref, k_ref, v_ref, bias_ref, rest, m_scr, l_scr, acc_scr,
+                scale, quantized):
+    """One page against the slot's query rows: the online-softmax update."""
+    Hkv = q_ref.shape[1]
+    if quantized:
+        ks_ref, vs_ref = rest[:2]
     bias = bias_ref[0, 0]  # [1, page_size], broadcasts over G
     for h in range(Hkv):  # static: one online-softmax carry per kv head
         q = q_ref[0, h]  # [G, hd], compute dtype
@@ -129,13 +152,6 @@ def _decode_kernel(
         )
         m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
         l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
-
-    @pl.when(p == pl.num_programs(1) - 1)
-    def _finish():
-        for h in range(Hkv):
-            o_ref[0, h] = (
-                acc_scr[h] / jnp.maximum(l_scr[h][:, :1], 1e-30)
-            ).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -182,25 +198,45 @@ def paged_decode_attention(
     q4 = q.reshape(S, Hkv, G, hd)
     bias4 = bias.reshape(S, max_pages, 1, page_size).astype(jnp.float32)
 
-    def page_of(s, p, pt):
+    # leading table entries of each slot with a key the bias lets through:
+    # a table is walked that far and no farther (a short request in a pool
+    # sized for long ones; a ring the context has not filled)
+    seen = (bias4[:, :, 0, :] > 0.5 * NEG_INF).any(-1)  # [S, max_pages]
+    live = jnp.max(
+        jnp.where(seen, jnp.arange(1, max_pages + 1)[None, :], 0), axis=1
+    ).astype(jnp.int32)
+
+    def entry_of(s, p, live):
+        # past the live extent the last live entry repeats: Pallas fetches
+        # a block only when its index changes
+        return jnp.minimum(p, jnp.maximum(live[s] - 1, 0))
+
+    def page_of(s, p, pt, live):
         # sentinel (>= num_pages) clamps to page 0: a real DMA target
         # whose contribution the bias then zeroes — mirrors the jnp
         # path's jnp.clip gather
-        pid = pt[s, p]
+        pid = pt[s, entry_of(s, p, live)]
         return jnp.where(pid < num_pages, pid, 0)
 
     def pool_spec(*tail):
         return pl.BlockSpec(
             (1, page_size, *tail),
-            lambda s, p, pt: (page_of(s, p, pt), *([0] * (len(tail) + 1))),
+            lambda s, p, pt, live: (
+                page_of(s, p, pt, live), *([0] * (len(tail) + 1))
+            ),
         )
 
-    q_spec = pl.BlockSpec((1, Hkv, G, hd), lambda s, p, pt: (s, 0, 0, 0))
+    q_spec = pl.BlockSpec(
+        (1, Hkv, G, hd), lambda s, p, pt, live: (s, 0, 0, 0)
+    )
     in_specs = [
         q_spec,
         pool_spec(Hkv, hd),
         pool_spec(Hkv, hd),
-        pl.BlockSpec((1, 1, 1, page_size), lambda s, p, pt: (s, p, 0, 0)),
+        pl.BlockSpec(
+            (1, 1, 1, page_size),
+            lambda s, p, pt, live: (s, entry_of(s, p, live), 0, 0),
+        ),
     ]
     operands = [q4, k_codes, v_codes, bias4]
     if quantized:
@@ -208,7 +244,7 @@ def paged_decode_attention(
         operands += [k_scales, v_scales]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(S, max_pages),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -224,7 +260,7 @@ def paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((S, Hkv, G, hd), q.dtype),
         interpret=pallas_mode.interpret(),
         name="paged_decode_attention",
-    )(page_table.astype(jnp.int32), *operands)
+    )(page_table.astype(jnp.int32), live, *operands)
     return out.reshape(S, H, hd)
 
 

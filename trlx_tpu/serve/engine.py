@@ -102,6 +102,12 @@ class ServeConfig:
         page_size)`` pages, so mixed-length traffic packs more live
         slots into the same HBM (docs/source/serving.rst has the
         pages-per-GB formula).
+    :param window_pages: size of the WINDOW class of the page pool, for
+        a model with window layers (``ModelSpec.layer_pattern``; ``pages``
+        then sizes the full class): 0 (default) gives every slot a whole
+        ring, ``slots * (window / page_size + 2)``. A slot maps only the
+        window-class pages its window still reaches, so real traffic
+        needs fewer (docs/source/serving.rst, "Two classes of page").
     :param request_tracing: per-request lifecycle tracing
         (trlx_tpu.serve.trace): every request carries a
         :class:`RequestTrace` with monotonic timestamps at each edge
@@ -225,6 +231,7 @@ class ServeConfig:
     kv_layout: str = "paged"
     page_size: int = 64
     pages: int = 0
+    window_pages: int = 0
     request_tracing: bool = True
     slo_ttft_ms: float = 500.0
     slo_target: float = 0.99
@@ -326,6 +333,27 @@ def _normalize_buckets(buckets) -> Tuple[Bucket, ...]:
     return tuple(sorted(set(out), key=lambda t: (t[1], t[2], t[0])))
 
 
+def _split_layers(seg, release: bool):
+    """A stacked [n, ...] block segment as n per-layer [1, ...] trees (a
+    segment of one layer is returned as it is). ``release``: delete each
+    stacked leaf once its slices are made."""
+    import jax
+
+    leaves, treedef = jax.tree_util.tree_flatten(seg)
+    n = leaves[0].shape[0] if leaves else 0
+    if n <= 1:
+        return (seg,) * n
+    layers = [[] for _ in range(n)]
+    for x in leaves:
+        parts = [x[i:i + 1] for i in range(n)]
+        if release:
+            jax.block_until_ready(parts)
+            x.delete()
+        for layer, part in zip(layers, parts):
+            layer.append(part)
+    return tuple(treedef.unflatten(layer) for layer in layers)
+
+
 class InferenceEngine:
     """A restored policy + its precompiled decode bucket lattice.
 
@@ -341,7 +369,11 @@ class InferenceEngine:
         checkpoint path is :meth:`from_checkpoint`. ``params`` defaults
         to a fresh policy init (useful only for tests/dev); ``init=False``
         defers weight installation entirely (the checkpoint path installs
-        the restored tree instead of paying a throwaway random init)."""
+        the restored tree instead of paying a throwaway random init).
+        ``params`` may also be a zero-argument maker of the tree: the
+        engine then owns what it makes, as it owns a restored or a fresh
+        tree, and may release it piece by piece while it strips it (a
+        model with routed experts: :meth:`strip_for_serve`)."""
         import jax.numpy as jnp
 
         from trlx_tpu import telemetry
@@ -384,9 +416,10 @@ class InferenceEngine:
             raise ValueError(
                 f"serve.page_size={self.serve.page_size} must be >= 1"
             )
-        if self.serve.pages < 0:
+        if self.serve.pages < 0 or self.serve.window_pages < 0:
             raise ValueError(
-                f"serve.pages={self.serve.pages} must be >= 0 (0 = auto)"
+                f"serve.pages={self.serve.pages} and serve.window_pages="
+                f"{self.serve.window_pages} must be >= 0 (0 = auto)"
             )
         if self.serve.slo_ttft_ms < 0:
             raise ValueError(
@@ -531,6 +564,14 @@ class InferenceEngine:
         self.tokenizer = load_tokenizer(config.model.tokenizer_path)
 
         spec, trunk = self._resolve_spec_and_trunk(config)
+        from trlx_tpu.models.transformer import require_supported
+
+        require_supported(
+            spec, kv_dtype=self.serve.kv_dtype,
+            weights_dtype=self.serve.weights_dtype,
+            speculation=self.serve.speculation, mesh=self.serve.mesh,
+            scheduler=self.serve.scheduler, kv_layout=self.serve.kv_layout,
+        )
         for b, p, g in self.buckets:
             if p + g > spec.n_positions:
                 raise ValueError(
@@ -555,10 +596,12 @@ class InferenceEngine:
         #: into every request at admission (``serve/model_version`` gauge)
         self.model_version = 1
         self.checkpoint_path: Optional[str] = None
-        if params is not None:
+        if callable(params):
+            self._install_params(params(), owned=True)
+        elif params is not None:
             self._install_params(params)
         elif init:
-            self._install_params(self._init_params())
+            self._install_params(self._init_params(), owned=True)
 
         eos = getattr(self.tokenizer, "eos_token_id", -1)
         pad = getattr(self.tokenizer, "pad_token_id", 0) or 0
@@ -691,7 +734,7 @@ class InferenceEngine:
         # streaming partial restore: decode subset only, per-leaf onto
         # the live serve shardings (load_params docstring)
         params, _ = engine.load_params(resolved)
-        engine._install_params(params)
+        engine._install_params(params, owned=True)
         engine.checkpoint_path = resolved
         return engine
 
@@ -709,7 +752,7 @@ class InferenceEngine:
             )
         return self.policy.init(jax.random.PRNGKey(0))
 
-    def _install_params(self, params: Dict) -> None:
+    def _install_params(self, params: Dict, owned: bool = False) -> None:
         """Keep only what decode reads: (trunk, trainable-top) block
         segments, embed (+lm_head), ln_f. The full tree is NOT retained —
         once the caller's reference drops, the reference branch and the
@@ -717,21 +760,19 @@ class InferenceEngine:
         steady-state memory holds one serving policy, not the training
         triple. The views land on the serve mesh under the decode
         partition rules (trlx_tpu.serve.layouts) — on the default
-        single-device mesh that is plain device placement."""
+        single-device mesh that is plain device placement. ``owned``: no
+        caller keeps ``params`` (see :meth:`strip_for_serve`)."""
         from trlx_tpu import telemetry
         from trlx_tpu.serve import layouts
         from trlx_tpu.utils import tree_bytes
 
-        blocks = self.policy.all_blocks(params)
-        embed, ln_f = self.policy.head_params_for_decode(params)
-        if self.serve.weights_dtype == "int8":
-            blocks = quantize_serve_weights(blocks)
+        total = tree_bytes(params)  # before the strip may release leaves
+        blocks, embed, ln_f = self.strip_for_serve(params, owned)
         self.blocks, self.embed, self.ln_f = layouts.shard_decode_views(
             self.mesh, (blocks, embed, ln_f),
             weights=self.serve.mesh_weights,
         )
         kept = tree_bytes((self.blocks, self.embed, self.ln_f))
-        total = tree_bytes(params)
         telemetry.set_gauge("serve/model_gb", kept / 2**30)
         telemetry.set_gauge(
             "serve/stripped_gb", max(total - kept, 0) / 2**30
@@ -762,15 +803,28 @@ class InferenceEngine:
 
     # -- live hot-swap (crash-only serving; docs "Fault tolerance") ------- #
 
-    def strip_for_serve(self, params: Dict):
+    def strip_for_serve(self, params: Dict, owned: bool = False):
         """Reduce a full hydra tree to the decode views — the hot-swap
         analogue of :meth:`_install_params`'s strip, WITHOUT installing:
         the candidate weights must pass :meth:`validate_swap` and a smoke
-        probe before they replace the serving set."""
+        probe before they replace the serving set.
+
+        A model with routed experts is kept as per-layer leaves: a
+        layer's expert stack goes to the grouped product (a custom call)
+        as a whole buffer; sliced out of a stacked [L, ...] tree it would
+        be copied every step. The split is made once, here, and copies
+        every segment of more than one layer. ``owned`` says no caller
+        keeps ``params``: each stacked leaf is then deleted as soon as
+        its per-layer copies exist, so the transient is one leaf and not
+        a second trunk."""
         blocks = self.policy.all_blocks(params)
         embed, ln_f = self.policy.head_params_for_decode(params)
         if self.serve.weights_dtype == "int8":
             blocks = quantize_serve_weights(blocks)
+        if self.spec.n_experts:
+            blocks = tuple(
+                layer for seg in blocks for layer in _split_layers(seg, owned)
+            )
         return blocks, embed, ln_f
 
     def validate_swap(self, views) -> None:
@@ -947,19 +1001,48 @@ class InferenceEngine:
     # -- slot-scheduler lattice (trlx_tpu.serve.slots) -------------------- #
 
     def prompt_classes(self) -> Tuple[Tuple[int, Tuple[int, ...]], ...]:
-        """Distinct prompt lengths with their admission batch extents,
-        smallest prompt first — the slot scheduler's prefill lattice
-        (prefill shape is (batch, prompt_len); the gen extent lives in
-        per-slot ``max_new`` lanes, not in the compiled shape)."""
+        """Distinct ONE-SHOT prompt lengths with their admission batch
+        extents, smallest prompt first — the slot scheduler's prefill
+        lattice, one program each (prefill shape is (batch, prompt_len);
+        the gen extent lives in per-slot ``max_new`` lanes, not in the
+        compiled shape). A class served in chunks (:meth:`chunk_len`) has
+        no program of its own and is not listed."""
         classes = {}
         for b, p, _ in self.buckets:
             classes.setdefault(p, set()).add(b)
+        chunked = self._chunked_from()
         return tuple(
             (p, tuple(sorted(classes[p]))) for p in sorted(classes)
+            if chunked is None or p < chunked
         )
 
+    def _chunked_from(self) -> Optional[int]:
+        """THE CHUNK RULE (docs/source/serving.rst): under the paged
+        layout a prompt class more than four times as long as the next
+        shorter class of the lattice, and every class after it, is
+        prefilled in chunks of that shorter class's length through its
+        prefix-context program. None: every class is one-shot."""
+        if self.serve.kv_layout != "paged" or self.serve.scheduler != "slots":
+            return None
+        lengths = sorted({p for _, p, _ in self.buckets})
+        for shorter, longer in zip(lengths, lengths[1:]):
+            if longer > 4 * shorter:
+                return longer
+        return None
+
+    def chunk_len(self, prompt_len: int) -> int:
+        """The chunk a prompt class is prefilled in: 0 for a one-shot
+        class, else the length of the longest one-shot class."""
+        chunked = self._chunked_from()
+        if chunked is None or prompt_len < chunked:
+            return 0
+        return self.prompt_classes()[-1][0]
+
     def prefill_batch_sizes(self, prompt_len: int) -> Tuple[int, ...]:
-        """Ascending admission batch extents for one prompt class."""
+        """Ascending admission batch extents for one prompt class (a
+        class served in chunks is admitted one request at a time)."""
+        if self.chunk_len(prompt_len):
+            return (1,)
         for p, extents in self.prompt_classes():
             if p == prompt_len:
                 return extents
@@ -996,6 +1079,23 @@ class InferenceEngine:
         """Page-pool size: ``serve.pages``, or slots x pages-per-slot
         (capacity parity with the contiguous layout) when 0."""
         return self.serve.pages or self.slot_count() * self.pages_per_slot()
+
+    # -- the window class (a model with window layers) -------------------- #
+
+    def window_ring_pages(self) -> int:
+        """Width of a slot's window-class ring at decode: the pages a
+        window reaches, the one being written, and one to spare —
+        ``window / page_size + 2``. 0 for a model without window layers."""
+        if "window" not in self.spec.page_classes:
+            return 0
+        return -(-self.spec.window // self.page_size_tokens()) + 2
+
+    def window_page_count(self) -> int:
+        """Size of the window class: ``serve.window_pages``, or a whole
+        ring for every slot when 0."""
+        return self.serve.window_pages or (
+            self.slot_count() * self.window_ring_pages()
+        )
 
     def request_page_need(self, prompt_len: int, max_new_tokens: int) -> int:
         """Worst-case pages one request reserves at admission (prefix

@@ -114,6 +114,14 @@ _SERVE_COUNTERS = (
     # allocation pressure
     "serve/prefix_tokens_saved",
     "serve/evicted_pages",
+    # two classes of page and routed experts (docs "Two classes of
+    # page"): window-class pages released behind a window, chunks of a
+    # prompt prefilled in chunks, (token, expert) pairs computed here and
+    # distinct experts a decode step hit
+    "serve/window_pages_freed",
+    "serve/prefill_chunks",
+    "serve/moe/pairs_here",
+    "serve/moe/experts_hit",
     # crash-only lifecycle family (docs "Fault tolerance"): in-flight
     # requests re-queued after a poisoned step, queued requests shed past
     # their deadline, graceful drains entered, checkpoint hot-swaps

@@ -141,10 +141,23 @@ class SlotPoolRuntime:
         #: default single-device mesh run the SAME code path
         self.mesh = engine.mesh
         self._host_sharding = layouts.replicated(self.mesh)
+        #: a model with window layers keeps a second class of page: every
+        #: program then takes the window-class tables beside the full ones
+        #: and returns the expert layer's routing counts
+        self.two_class = (
+            self.kv_layout == "paged"
+            and "window" in engine.spec.page_classes
+        )
+        self.ring_pages = self.num_window_pages = 0
+        self.moe_stats = []  # device arrays [L, 4] since the last fetch
+        self.moe_stats_host = []  # the same, fetched with the last step
         if self.kv_layout == "paged":
             self.page_size = engine.page_size_tokens()
             self.max_pages = engine.pages_per_slot()
             self.num_pages = engine.page_count()
+            if self.two_class:
+                self.ring_pages = engine.window_ring_pages()
+                self.num_window_pages = engine.window_page_count()
             # logical per-slot extent rounds UP to whole pages
             self.buffer_len = self.max_pages * self.page_size
             # serve.kv_dtype picks the pool tier: int8 swaps each (k, v)
@@ -157,7 +170,9 @@ class SlotPoolRuntime:
             )
             self._init_pool = functools.partial(
                 init_page_pool, engine.spec, self._seg_sizes,
-                self.num_pages, self.page_size, cache_dtype=cache_dtype,
+                {"full": self.num_pages, "window": self.num_window_pages}
+                if self.two_class else self.num_pages,
+                self.page_size, cache_dtype=cache_dtype,
             )
         else:
             self.page_size = self.max_pages = self.num_pages = 0
@@ -228,13 +243,18 @@ class SlotPoolRuntime:
             if self.kv_layout == "paged":
                 ps = self.page_size
 
+                # a model with window layers also hands over its
+                # window-class tables (and is always ``suffix``)
                 def run(blocks, embed, ln_f, pool, state, tokens, mask,
-                        slot_ids, max_new, page_tables, start):
+                        slot_ids, max_new, page_tables, start,
+                        window_tables=None, window_base=None):
                     return prefill_into_slots(
                         spec, blocks, embed, ln_f, pool, state, tokens,
                         mask, slot_ids, max_new, compute_dtype=compute,
                         page_tables=page_tables, page_size=ps,
                         start=start, prefix_context=suffix,
+                        window_tables=window_tables,
+                        window_base=window_base,
                     )
             else:
 
@@ -253,7 +273,8 @@ class SlotPoolRuntime:
             # replicate; pool + state keep their build shardings in AND
             # out — the step loop's signatures are pinned, so
             # compile/recompiles == 0 survives the mesh
-            n_host = 6 if self.kv_layout == "paged" else 4
+            n_host = 8 if self.two_class \
+                else 6 if self.kv_layout == "paged" else 4
             fn = self._prefill_fns[key] = aot_jit(
                 run, donate_argnums=(3, 4) if self._donate else (),
                 in_shardings=(
@@ -262,10 +283,17 @@ class SlotPoolRuntime:
                     *([self._host_sharding] * n_host),
                 ),
                 out_shardings=(
-                    self._pool_shardings, self._state_shardings
+                    self._pool_shardings, self._state_shardings,
+                    *([self._host_sharding] * self.two_class),
                 ),
             )
         return fn
+
+    def window_table_pages(self, prompt_len: int) -> int:
+        """Width of the window-class table of the prefill program of
+        ``prompt_len``: the pages the window reaches before the suffix,
+        the pages the suffix writes, and one for a start inside a page."""
+        return self.ring_pages - 1 + -(-prompt_len // self.page_size)
 
     def _decode_fn(self):
         if self._step_fn is None:
@@ -295,11 +323,14 @@ class SlotPoolRuntime:
                     else self.mesh
                 )
 
-            def run(blocks, embed, ln_f, pool, state, seed):
+            # ``window_table``: a model with window layers alone
+            def run(blocks, embed, ln_f, pool, state, seed,
+                    window_table=None):
                 return decode_step(
                     spec, blocks, embed, ln_f, pool, state, seed, cfg,
                     compute_dtype=compute,
                     paged_decode_fn=paged_decode_fn,
+                    window_table=window_table,
                 )
 
             run.__name__ = "run_decode_step"
@@ -308,12 +339,11 @@ class SlotPoolRuntime:
                 in_shardings=(
                     *self._view_shardings(),
                     self._pool_shardings, self._state_shardings,
-                    self._host_sharding,
+                    *([self._host_sharding] * (1 + self.two_class)),
                 ),
                 out_shardings=(
                     self._pool_shardings, self._state_shardings,
-                    self._host_sharding, self._host_sharding,
-                    self._host_sharding,
+                    *([self._host_sharding] * (3 + self.two_class)),
                 ),
             )
         return self._step_fn
@@ -369,7 +399,8 @@ class SlotPoolRuntime:
 
     def prefill(self, bucket, tokens: np.ndarray, mask: np.ndarray,
                 slot_ids, max_new, page_tables=None, start=None,
-                suffix: bool = False) -> None:
+                suffix: bool = False, window_tables=None,
+                window_base=None) -> None:
         """Admit one prompt bucket into the pool (filler rows carry the
         out-of-bounds sentinel and are dropped on device). Paged layout:
         ``page_tables`` [Bp, max_pages] maps each row's logical pages
@@ -377,6 +408,7 @@ class SlotPoolRuntime:
         ``suffix=True`` selects the prefix-context (``prefill_suffix``)
         executable; tokens/mask are right-padded there."""
         e = self.engine
+        suffix = suffix or self.two_class  # two classes: the one variant
         fn = self._prefill_fn(bucket, suffix)
         args = [
             e.blocks, e.embed, e.ln_f, self.pool, self.state,
@@ -390,15 +422,51 @@ class SlotPoolRuntime:
                 np.ascontiguousarray(page_tables, np.int32),
                 np.asarray(start, np.int32),
             ]
+        if self.two_class:
+            Bp, P = bucket
+            if window_tables is None:  # nothing mapped: every write drops
+                window_tables = np.full(
+                    (Bp, self.window_table_pages(P)),
+                    self.num_window_pages, np.int32,
+                )
+                window_base = np.zeros((Bp,), np.int32)
+            args += [
+                np.ascontiguousarray(window_tables, np.int32),
+                np.asarray(window_base, np.int32),
+            ]
         with telemetry.span(self.prefill_span(bucket, suffix)):
-            self.pool, self.state = fn(*args)
+            if self.two_class:
+                self.pool, self.state, stats = fn(*args)
+                self.moe_stats.append(stats)
+            else:
+                self.pool, self.state = fn(*args)
 
-    def step(self, seed: int):
+    def step(self, seed: int, window_table=None):
         """One decode step for every slot; returns host-side
-        (tokens [S], emitted [S], finished [S]) numpy arrays."""
+        (tokens [S], emitted [S], finished [S]) numpy arrays. A model
+        with window layers takes the slots' window-class ring tables
+        (``window_table`` [S, ring_pages], the scheduler's) and leaves the
+        routing counts of this step and of the prefills since the last
+        one in ``moe_stats_host`` — they ride the token fetch."""
         e = self.engine
         fn = self._decode_fn()
         with telemetry.span(self.STEP_SPAN):
+            if self.two_class:
+                if window_table is None:
+                    window_table = np.full(
+                        (self.num_slots, self.ring_pages),
+                        self.num_window_pages, np.int32,
+                    )
+                self.pool, self.state, tok, emitted, finished, stats = fn(
+                    e.blocks, e.embed, e.ln_f, self.pool, self.state,
+                    np.int32(seed),
+                    np.ascontiguousarray(window_table, np.int32),
+                )
+                pending, self.moe_stats = self.moe_stats, []
+                tok, emitted, finished, stats, self.moe_stats_host = \
+                    self._fetch((tok, emitted, finished, stats, pending))
+                self.moe_stats_host.append(stats)  # the step's comes last
+                return tok, emitted, finished
             self.pool, self.state, tok, emitted, finished = fn(
                 e.blocks, e.embed, e.ln_f, self.pool, self.state,
                 np.int32(seed),
@@ -473,8 +541,11 @@ class SlotPoolRuntime:
         pages, never a pool's worth). A second pool in either number is
         a copy a later change brought back."""
         e = self.engine
+        extra = (np.zeros((self.num_slots, self.ring_pages), np.int32),) \
+            if self.two_class else ()
         stats = self._decode_fn().compiled_for(
             e.blocks, e.embed, e.ln_f, self.pool, self.state, np.int32(0),
+            *extra,
         ).memory_analysis()
         if stats is None:  # a backend without the analysis
             return
@@ -500,7 +571,9 @@ class SlotPoolRuntime:
         pad = self.engine.pad_token_id
         latencies = {}
         paged = self.kv_layout == "paged"
-        variants = (False, True) if paged else (False,)
+        # a model with window layers has the prefix-context variant alone
+        variants = (True,) if self.two_class \
+            else (False, True) if paged else (False,)
         for P, extents in self.engine.prompt_classes():
             for Bp in extents:
                 for suffix in variants:
@@ -562,13 +635,22 @@ class _LiveSlot:
     harvest); ``committed`` the pages this admission inserted into the
     radix tree (the rollback handle for a failed prefill)."""
 
-    __slots__ = ("request", "tokens", "pages", "committed")
+    __slots__ = ("request", "tokens", "pages", "committed", "wmap",
+                 "wquota", "pos0")
 
     def __init__(self, request: Request, pages=None, committed=None):
         self.request = request
         self.tokens: List[int] = []
         self.pages: List[int] = pages or []
         self.committed: List[int] = committed or []
+        # the window class (a model with window layers): the window-class
+        # pages the slot maps now, {logical page: page id}, each holding
+        # one reference; the most it may map at once (reserved at
+        # admission); and the position of its first decoded token less
+        # the tokens journaled before admission
+        self.wmap: Dict[int, int] = {}
+        self.wquota = 0
+        self.pos0 = 0
 
 
 class SlotScheduler:
@@ -583,8 +665,6 @@ class SlotScheduler:
     def __init__(self, engine, max_queue: Optional[int] = None,
                  run_supervisor=None, slots: Optional[int] = None,
                  draft=None):
-        from trlx_tpu.serve.paged import RadixCache
-
         self.engine = engine
         cfg = engine.serve
         self.max_queue = cfg.max_queue if max_queue is None else max_queue
@@ -594,9 +674,19 @@ class SlotScheduler:
         #: under the contiguous layout
         self.cache: Optional[RadixCache] = None
         if self.runtime.kv_layout == "paged":
-            self.cache = RadixCache(
-                self.runtime.num_pages, self.runtime.page_size
-            )
+            self.cache = self._new_cache()
+        #: the slots' window-class rings (a model with window layers):
+        #: host data handed to every decode step, logical page n of slot
+        #: s at entry n % ring_pages
+        self._wtable = np.full(
+            (self.runtime.num_slots, max(self.runtime.ring_pages, 1)),
+            self.runtime.num_window_pages, np.int32,
+        )
+        # the expert layer's counts and the window pages released since
+        # the last flight record
+        self._fr_pairs = self._fr_pairs_step = self._fr_experts_hit = 0
+        self._fr_window_freed = 0
+        self._moe_load = 0.0  # the last step's load_max_over_mean
         self._prompt_tokens_total = 0  # prefix hit-rate denominators
         self._prefix_tokens_saved = 0
         self._queue = deque()  # guarded-by: _cond
@@ -672,6 +762,17 @@ class SlotScheduler:
         )
         self._brownout_recover_s = float(
             getattr(cfg, "brownout_recover_s", 5.0)
+        )
+
+    def _new_cache(self):
+        """A fresh allocator + radix tree over the pool's classes."""
+        from trlx_tpu.serve.paged import RadixCache
+
+        rt = self.runtime
+        return RadixCache(
+            rt.num_pages, rt.page_size,
+            window_pages=rt.num_window_pages,
+            window=self.engine.spec.window if rt.two_class else 0,
         )
 
     # -- lifecycle ------------------------------------------------------- #
@@ -785,6 +886,15 @@ class SlotScheduler:
                     f"pool holds {self.runtime.num_pages}; raise "
                     f"serve.pages (or serve.page_size) — queueing could "
                     f"never admit it"
+                )
+            if self.runtime.two_class and (
+                self._window_quota(need, 0) > self.runtime.num_window_pages
+            ):
+                raise ValueError(
+                    f"request needs up to {self._window_quota(need, 0)} "
+                    f"window-class KV pages at once but the pool holds "
+                    f"{self.runtime.num_window_pages}; raise "
+                    f"serve.window_pages — queueing could never admit it"
                 )
         if trace is None and self._tracing:
             trace = RequestTrace()
@@ -1015,6 +1125,8 @@ class SlotScheduler:
         """Prefill one admission batch; returns False when the paged
         allocator ran dry and part of the batch went back to the queue."""
         if self.cache is not None:
+            if self.runtime.two_class or self.engine.chunk_len(P):
+                return self._prefill_batch_classes(batch, P, extents)
             return self._prefill_batch_paged(batch, P, extents)
         Bp = next(b for b in extents if b >= len(batch))
         slots = [self._free.pop() for _ in batch]
@@ -1172,11 +1284,307 @@ class SlotScheduler:
         self._emit_pool_gauges()
         return not deferred
 
+    # -- two classes of page, prefill in chunks --------------------------- #
+
+    def _window_quota(self, total_blocks: int, first_block: int) -> int:
+        """The most window-class pages one request maps at once: what a
+        window reaches, plus the pages the longest prefill program writes
+        in one call, plus one (a start inside a page) — or everything from
+        ``first_block`` on, if the request is shorter than that."""
+        rt = self.runtime
+        longest = self.engine.prompt_classes()[-1][0]
+        return min(total_blocks - first_block,
+                   rt.ring_pages - 1 + -(-longest // rt.page_size))
+
+    def _prefill_batch_classes(self, batch: List[Request], P: int,
+                               extents) -> bool:
+        """Paged admission of a model with two classes of page, and of any
+        prompt class served in chunks. As :meth:`_prefill_batch_paged`
+        (match, reserve, commit, prefill the unmatched suffix; exhaustion
+        of EITHER class queues), and besides: the match is cut back to
+        where the window-class pages are still kept; a slot maps only the
+        window-class pages its window reaches and reserves the most it
+        will map at once; a suffix longer than the longest one-shot
+        program is prefilled in chunks of that program's length through
+        the prefix-context program, every chunk but the last aimed at the
+        sentinel slot (it leaves its pages and no lanes)."""
+        rt, cache = self.runtime, self.cache
+        ps, two = rt.page_size, rt.two_class
+        chaos.maybe_inject("serve_prefix_match")
+        plans = []  # (request, toks, matched blocks, live slot)
+        deferred: List[Request] = []
+        for i, r in enumerate(batch):
+            toks = (r.tokens + r.committed)[-P:]
+            matched, wmap = (
+                cache.match_classes(toks) if two
+                else (cache.match(toks), {})
+            )
+            total = self.engine.request_page_need(
+                len(toks), r.remaining_new_tokens()
+            )
+            fresh = cache.alloc(total - len(matched))
+            quota = 0
+            if fresh is not None and two:
+                quota = self._window_quota(
+                    total, max(len(matched) - cache.window_blocks, 0)
+                )
+                if cache.alloc_window(
+                    0, reserve=max(quota - len(wmap), 0)
+                ) is None:
+                    cache.release_all(fresh)
+                    fresh = None
+            if fresh is None:
+                cache.release_all(matched)
+                if two:
+                    cache.release_window(list(wmap.values()))
+                deferred = batch[i:]
+                break
+            pages = matched + fresh
+            live = _LiveSlot(r, pages=pages,
+                             committed=cache.commit(toks, pages))
+            live.tokens = list(r.committed)
+            live.wmap, live.wquota = wmap, max(quota, len(wmap))
+            live.pos0 = len(toks) - len(live.tokens)
+            plans.append((r, toks, len(matched), live))
+        if deferred:
+            with self._cond:
+                for r in reversed(deferred):
+                    if r.trace is not None:  # page starvation -> re-queued
+                        r.trace.queue_reentries += 1
+                    self._queue.appendleft(r)
+                telemetry.set_gauge("serve/queue_depth", len(self._queue))
+            # the _admit exception handler must not fail re-queued rows
+            batch[:] = [p[0] for p in plans]
+        if not plans:
+            self._emit_pool_gauges()
+            return False
+
+        slots = [self._free.pop() for _ in plans]
+        admit_at = monotonic()
+        version = self.engine.model_version
+        try:
+            chunk = self.engine.chunk_len(P)
+            if chunk:
+                # a class served in chunks is admitted one request at a
+                # time: every chunk but the last through the chunk program
+                (r, toks, m, live), = plans
+                start = m * ps
+                while len(toks) - start > chunk:
+                    self._prefill_call(
+                        (1, chunk), [(live, toks, start, start + chunk)],
+                        [rt.num_slots], [1], final=False,
+                    )
+                    start += chunk
+                last = next(p for p, _ in self.engine.prompt_classes()
+                            if p >= len(toks) - start)
+                self._stamp_admission(plans, (1, last), admit_at, version)
+                self._prefill_call(
+                    (1, last), [(live, toks, start, len(toks))], slots,
+                    [r.remaining_new_tokens()],
+                )
+            else:
+                Bp = next(b for b in extents if b >= len(plans))
+                self._stamp_admission(plans, (Bp, P), admit_at, version)
+                self._prefill_call(
+                    (Bp, P),
+                    [(live, toks, m * ps, len(toks))
+                     for _, toks, m, live in plans],
+                    slots, [p[0].remaining_new_tokens() for p in plans],
+                )
+        except Exception:
+            self._free.extend(slots)  # nothing was admitted
+            for _, _, _, live in reversed(plans):
+                cache.rollback(live.committed)  # content never landed
+            for _, _, _, live in plans:
+                self._release_slot_pages(live)
+            raise
+        prefill_end = monotonic()
+        saved = 0
+        for (r, toks, m, live), s in zip(plans, slots):
+            if r.trace is not None:
+                r.trace.prefill_end = prefill_end
+            self._live[s] = live
+            self._wtable[s] = rt.num_window_pages
+            for block, wpage in live.wmap.items():
+                self._wtable[s, block % rt.ring_pages] = wpage
+            self.events.append(("admit", s, r))
+            saved += m * ps
+            self._prompt_tokens_total += len(toks)
+            telemetry.observe("serve/pages_per_request", len(live.pages))
+        self._fr_admitted += len(plans)
+        self._prefix_tokens_saved += saved
+        if saved:
+            telemetry.inc("serve/prefix_tokens_saved", saved)
+        telemetry.inc("serve/admissions", len(plans))
+        for p in plans:
+            telemetry.inc("serve/admissions",
+                          labels={"tenant": p[0].tenant})
+        telemetry.set_gauge("serve/slot_occupancy", self._occupancy())
+        self._emit_pool_gauges()
+        return not deferred
+
+    def _stamp_admission(self, plans, bucket, admit_at, version) -> None:
+        ps = self.runtime.page_size
+        for r, toks, m, live in plans:
+            r.model_version = version
+            if r.trace is not None:
+                r.trace.admitted = admit_at
+                r.trace.bucket = bucket
+                r.trace.prefill_start = admit_at
+                r.trace.pages_reserved = len(live.pages)
+                r.trace.prefix_blocks_hit = m
+                r.trace.suffix_len = len(toks) - m * ps
+                r.trace.model_version = version
+
+    def _prefill_call(self, bucket, rows, slot_ids, max_new,
+                      final: bool = True) -> None:
+        """One prefill program over ``rows`` [(live slot, tokens, start,
+        end)]: tokens[start:end] of each row, right-padded into the
+        bucket, written at logical positions from ``start`` on. Before the
+        call each row is given the window-class pages the call writes;
+        after it those pages go to the trie's blocks that own none, and
+        the pages now wholly behind the row's window are released. A call
+        that is not ``final`` is a chunk of a longer prompt (span
+        ``serve/prefill_chunk``, counter ``serve/prefill_chunks``)."""
+        rt, cache = self.runtime, self.cache
+        Bp, P = bucket
+        ps, two = rt.page_size, rt.two_class
+        wb = cache.window_blocks
+        tokens = np.full((Bp, P), self.engine.pad_token_id, np.int32)
+        mask = np.zeros((Bp, P), np.int32)
+        page_tables = np.full((Bp, rt.max_pages), rt.num_pages, np.int32)
+        starts = np.zeros((Bp,), np.int32)
+        new = np.ones((Bp,), np.int32)
+        ids = np.full((Bp,), rt.num_slots, np.int32)
+        wtables = wbase = None
+        if two:
+            wtables = np.full((Bp, rt.window_table_pages(P)),
+                              rt.num_window_pages, np.int32)
+            wbase = np.zeros((Bp,), np.int32)
+        for j, (live, toks, start, end) in enumerate(rows):
+            tokens[j, :end - start] = toks[start:end]
+            mask[j, :end - start] = 1
+            page_tables[j, :len(live.pages)] = live.pages
+            starts[j], new[j], ids[j] = start, max_new[j], slot_ids[j]
+            if two:
+                for block in range(start // ps, -(-end // ps)):
+                    if block not in live.wmap:
+                        live.wmap[block], = cache.alloc_window(
+                            1, reserved=True
+                        )
+                wbase[j] = max(start // ps - wb, 0)
+                for block, wpage in live.wmap.items():
+                    if 0 <= block - wbase[j] < wtables.shape[1]:
+                        wtables[j, block - wbase[j]] = wpage
+        span = supervisor.NULL_CM if final \
+            else telemetry.span("serve/prefill_chunk")
+        with span:
+            rt.prefill(
+                bucket, tokens, mask, ids, new, page_tables=page_tables,
+                start=starts, suffix=bool(starts.any()) or not final,
+                window_tables=wtables, window_base=wbase,
+            )
+        if not final:
+            telemetry.inc("serve/prefill_chunks")
+        if two:
+            for live, toks, start, end in rows:
+                for block in range(start // ps, end // ps):  # whole blocks
+                    cache.attach_window(live.pages[block], live.wmap[block])
+                self._release_behind(live, end)
+
+    def _release_behind(self, live: _LiveSlot, next_pos: int,
+                        slot: Optional[int] = None) -> None:
+        """Release the window-class pages wholly behind the window of the
+        query at ``next_pos`` and of every later one: logical pages before
+        ``(next_pos - window + 1) // page_size``. The places return to
+        the slot's reservation; a page the trie owns stays cached. A
+        decoding ``slot``'s ring entries go back to the sentinel."""
+        rt = self.runtime
+        first = (next_pos - self.engine.spec.window + 1) // rt.page_size
+        behind = [b for b in live.wmap if b < first]
+        if behind:
+            if slot is not None:
+                for b in behind:
+                    self._wtable[slot, b % rt.ring_pages] = \
+                        rt.num_window_pages
+            self.cache.release_window(
+                [live.wmap.pop(b) for b in behind], behind=True,
+                back_to_reserve=True,
+            )
+            telemetry.inc("serve/window_pages_freed", len(behind))
+            self._fr_window_freed += len(behind)
+
+    def _advance_windows(self) -> None:
+        """Before a decode step: every live slot gets the window-class
+        page its next token is written to (out of its reservation, when
+        the token opens a page) and drops the pages that token's window
+        has passed; the rings handed to the step follow."""
+        rt = self.runtime
+        for s, live in self._live.items():
+            pos = live.pos0 + len(live.tokens)  # written by this step
+            block = pos // rt.page_size
+            if block not in live.wmap:
+                live.wmap[block], = self.cache.alloc_window(
+                    1, reserved=True
+                )
+                self._wtable[s, block % rt.ring_pages] = live.wmap[block]
+            self._release_behind(live, pos, s)
+
+    def _release_slot_pages(self, live: _LiveSlot) -> None:
+        """Harvest (or a failed admission): drop every page reference the
+        slot holds, of both classes, and what is left of its window-class
+        reservation."""
+        self.cache.release_all(live.pages)
+        if self.runtime.two_class:
+            self.cache.window_reserved -= live.wquota - len(live.wmap)
+            self.cache.release_window(list(live.wmap.values()))
+            live.wmap = {}
+
+    def _note_moe_stats(self) -> None:
+        """The expert layer's routing counts of the last step and of the
+        prefills since the step before it (they rode the token fetch):
+        [L, 4] each of (pairs_here, experts_hit, load_max, load_mean).
+        ``pairs_here`` counts every pair computed, prefill included;
+        ``experts_hit`` and the load are the decode step's own (a prefill
+        chunk hits every expert held: it says nothing about the step)."""
+        stats = self.runtime.moe_stats_host
+        if not stats:
+            return
+        step = stats[-1]  # the decode step's own
+        pairs = int(sum(a[:, 0].sum() for a in stats))
+        hit = int(step[:, 1].sum())
+        telemetry.inc("serve/moe/pairs_here", pairs)
+        telemetry.inc("serve/moe/experts_hit", hit)
+        # the step's worst layer: the fullest expert over the mean expert
+        self._moe_load = float(
+            np.max(step[:, 2] / np.maximum(step[:, 3], 1e-9))
+        )
+        telemetry.set_gauge("serve/moe/load_max_over_mean", self._moe_load)
+        self._fr_pairs += pairs
+        self._fr_pairs_step += int(step[:, 0].sum())
+        self._fr_experts_hit += hit
+        self.runtime.moe_stats_host = []
+
     def _hit_rate(self) -> float:
         return self._prefix_tokens_saved / max(self._prompt_tokens_total, 1)
 
+    def _pages_in_use(self) -> Dict[str, int]:
+        """Pages of each class that are not on a free list (mapped by a
+        slot or kept by the trie)."""
+        rt = self.runtime
+        out = {"full": rt.num_pages - self.cache.free_pages()}
+        if rt.two_class:
+            out["window"] = (
+                rt.num_window_pages - self.cache.window_free_pages()
+            )
+        return out
+
     def _emit_pool_gauges(self) -> None:
         telemetry.set_gauge("serve/pages_free", self.cache.free_pages())
+        if self.runtime.two_class:
+            for cls, n in self._pages_in_use().items():
+                telemetry.set_gauge("serve/pages_in_use", n,
+                                    labels={"class": cls})
         telemetry.set_gauge("serve/prefix_hit_rate", self._hit_rate())
         tel = telemetry.current()
         if tel is not None:
@@ -1295,7 +1703,14 @@ class SlotScheduler:
                 accepted = int(np.maximum(counts - 1, 0).sum())
                 span = self.runtime.VERIFY_SPAN
             else:
-                tok, emitted, finished = self.runtime.step(seed)
+                if self.runtime.two_class:
+                    self._advance_windows()
+                    tok, emitted, finished = self.runtime.step(
+                        seed, self._wtable
+                    )
+                    self._note_moe_stats()
+                else:
+                    tok, emitted, finished = self.runtime.step(seed)
                 # plain decode is the counts <= 1 degenerate case of the
                 # same harvest shape
                 cand = np.asarray(tok)[:, None]
@@ -1358,7 +1773,7 @@ class SlotScheduler:
                     # committed (trie-owned) pages stay cached at
                     # refcount 0 — hit-able until LRU eviction; the rest
                     # return to the free list
-                    self.cache.release_all(live.pages)
+                    self._release_slot_pages(live)
                     telemetry.set_gauge(
                         "serve/pages_free", self.cache.free_pages()
                     )
@@ -1384,11 +1799,8 @@ class SlotScheduler:
         content can no longer be trusted — poisoned step, or KV computed
         under pre-swap weights) resets with them."""
         if self.cache is not None:
-            from trlx_tpu.serve.paged import RadixCache
-
-            self.cache = RadixCache(
-                self.runtime.num_pages, self.runtime.page_size
-            )
+            self.cache = self._new_cache()
+            self._wtable[:] = self.runtime.num_window_pages
             telemetry.set_gauge(
                 "serve/pages_free", self.cache.free_pages()
             )
@@ -1724,6 +2136,17 @@ class SlotScheduler:
         }
         if self.cache is not None:
             rec["pages_free"] = self.cache.free_pages()
+        if self.runtime.two_class:
+            in_use = self._pages_in_use()
+            rec.update(pages_full=in_use["full"],
+                       pages_window=in_use["window"],
+                       pairs_here=self._fr_pairs,
+                       pairs_step=self._fr_pairs_step,
+                       experts_hit=self._fr_experts_hit,
+                       window_freed=self._fr_window_freed,
+                       moe_load=round(self._moe_load, 3))
+            self._fr_pairs = self._fr_pairs_step = 0
+            self._fr_experts_hit = self._fr_window_freed = 0
         if self.spec_k > 0:
             # a speculation regression (acceptance collapsing to 0) must
             # be visible in a stall dump, not only in the counters

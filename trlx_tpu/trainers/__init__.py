@@ -401,7 +401,11 @@ class BaseRLTrainer:
         successful run. Opt into random init explicitly via
         `model.model_spec`."""
         if config.model.model_spec is not None:
-            return config.model.resolve_spec(), None
+            from trlx_tpu.models.transformer import require_supported
+
+            spec = config.model.resolve_spec()
+            require_supported(spec, trainer=type(self).__name__)
+            return spec, None
         from trlx_tpu.models.hf_import import load_trunk_from_hf
 
         try:
