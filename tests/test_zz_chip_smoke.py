@@ -133,7 +133,25 @@ def test_importing_the_package_does_not_enable_the_cache():
 # -- Mosaic itself, without a chip --------------------------------------- #
 
 
-def test_kernels_compile_under_mosaic_for_a_v5e_from_this_host(monkeypatch):
+@pytest.fixture(scope="module")
+def on_chip():
+    """One chip of a described v5e as a sharding: what a compile-only
+    lowering places its arguments on. Made inside a fixture, never at
+    import: only the worker that runs this file loads libtpu."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topology = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu on this host: nothing to compile with
+        pytest.skip(f"no compile-only TPU topology here: {e!r}")
+    return SingleDeviceSharding(topology.devices[0])
+
+
+def test_kernels_compile_under_mosaic_for_a_v5e_from_this_host(
+        on_chip, monkeypatch):
     """libtpu ships the compiler, and jax can hand it a compile-only v5e
     topology with no TPU attached: the same Mosaic passes the chip run
     goes through, minus the numbers. tests/test_kernel_lowering.py stops
@@ -143,20 +161,11 @@ def test_kernels_compile_under_mosaic_for_a_v5e_from_this_host(monkeypatch):
     breaks."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     from trlx_tpu.ops import pallas_mode
     from trlx_tpu.ops.paged_attention import paged_decode_attention
     from trlx_tpu.ops.pallas_attention import flash_attention
 
-    try:
-        topology = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2"
-        )
-    except Exception as e:  # no libtpu on this host: nothing to compile with
-        pytest.skip(f"no compile-only TPU topology here: {e!r}")
-    on_chip = SingleDeviceSharding(topology.devices[0])
     monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
 
     def sds(shape, dtype):
@@ -195,3 +204,78 @@ def test_kernels_compile_under_mosaic_for_a_v5e_from_this_host(monkeypatch):
         ),
         qkv, qkv, qkv, mask,
     )
+
+
+# -- what XLA:TPU makes of the decode step's projections ------------------ #
+
+
+@pytest.mark.parametrize(
+    "arch, heads, head_dim, rows",
+    [("gptj", 16, 256, 16), ("gpt2", 25, 64, 128)],
+    ids=["16x256-gptj6b", "25x64-gpt2xl"],
+)
+def test_decode_step_streams_its_qkv_weights_as_stored(
+        on_chip, monkeypatch, arch, heads, head_dim, rows):
+    """The paged decode step at gpt-j-6B's and gpt2-xl's attention widths,
+    compiled for a v5e: no ``copy`` / ``transpose`` /
+    ``slice_bitcast_fusion`` as large as a q, k or v matrix (on the chip
+    they were a third of gpt-j-6B's step: three matrices a layer written
+    out again in the order a folded dot wants). The control is the
+    projection as it stood (test_decode_qkv.qkv_folded): it must still
+    show them, or this probe has stopped seeing what it guards."""
+    import jax
+    import jax.numpy as jnp
+
+    from test_decode_qkv import qkv_folded
+    from trlx_tpu.data.configs import ModelSpec
+    from trlx_tpu.models import generation as G
+    from trlx_tpu.models import transformer as T
+    from trlx_tpu.models.policy import HydraPolicy
+    from trlx_tpu.utils.hlo_text import large_moves
+
+    d = heads * head_dim
+    spec = ModelSpec(arch=arch, vocab_size=512, n_layer=3, n_head=heads,
+                     d_model=d, d_ff=d, n_positions=256,
+                     rotary_dim=64 if arch == "gptj" else 0)
+    policy = HydraPolicy(spec=spec, num_layers_unfrozen=1,
+                         compute_dtype=jnp.bfloat16)
+    page_size, max_pages = 8, 1  # a pool leaf stays under one matrix
+    config = G.GenerationConfig(
+        gen_size=1, sampling=G.SamplingParams(do_sample=False),
+        eos_token_id=256, pad_token_id=0, min_new_tokens=0,
+    )
+
+    def arguments():
+        params = jax.tree_util.tree_map(  # kept in bfloat16, as served
+            lambda x: x.astype(jnp.bfloat16),
+            policy.init(jax.random.PRNGKey(0)),
+        )
+        blocks = policy.all_blocks(params)  # stacked [2, ..] + top [1, ..]
+        embed, ln_f = policy.head_params_for_decode(params)
+        _, seg_sizes = G._segments_of(blocks)
+        pool = G.init_page_pool(spec, seg_sizes, rows * max_pages,
+                                page_size)
+        state = G.init_slot_state(rows, max_pages * page_size,
+                                  spec.vocab_size, max_pages=max_pages)
+        return blocks, embed, ln_f, pool, state, jnp.int32(0)
+
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip),
+        jax.eval_shape(arguments),
+    )
+
+    def moves():
+        def run_decode_step(blocks, embed, ln_f, pool, state, seed):
+            return G.decode_step(spec, blocks, embed, ln_f, pool, state,
+                                 seed, config, compute_dtype=jnp.bfloat16)
+
+        with jax.default_matmul_precision("default"):  # as on the chip
+            text = jax.jit(run_decode_step, donate_argnums=(3, 4)).lower(
+                *args).compile().as_text()
+        return large_moves(text, d * d * 2)
+
+    assert moves() == []
+    monkeypatch.setattr(T, "_qkv", qkv_folded)
+    folded = moves()
+    assert len(folded) >= 3, folded  # q, k and v, in every layer
+    assert all("attn" in m.op_name or not m.op_name for m in folded), folded

@@ -351,9 +351,22 @@ def _qkv(spec: ModelSpec, flags: ArchFlags, p: Params, h, positions,
     H, hd, Hkv = spec.n_head, spec.head_dim, spec.kv_heads
     x = layer_norm(p["ln_1"], h, spec.layer_norm_epsilon)
     attn = p["attn"]
-    q = _project(x, attn["wq"], attn.get("bq")).reshape(B, T, H, hd)
-    k = _project(x, attn["wk"], attn.get("bk")).reshape(B, T, Hkv, hd)
-    v = _project(x, attn["wv"], attn.get("bv")).reshape(B, T, Hkv, hd)
+    q = _project(x, attn["wq"], attn.get("bq"))
+    k = _project(x, attn["wk"], attn.get("bk"))
+    v = _project(x, attn["wv"], attn.get("bv"))
+    if T == 1:
+        # a decode step: XLA:TPU otherwise folds the head split below into
+        # the dot, and the folded dot wants each weight in another order:
+        # every step then writes all three matrices out again before it
+        # reads them (a third of a gpt-j-6B step on v5e). The barrier
+        # keeps the dot 2-D, so the weights stream as they are stored
+        # (``serve/decode_weight_copy_bytes`` reads 0). Programs of
+        # T > 1 keep the folded form: prefill, verify, and the trained
+        # forward, where a barrier would also stand in the backward pass.
+        q, k, v = jax.lax.optimization_barrier((q, k, v))
+    q = q.reshape(B, T, H, hd)
+    k = k.reshape(B, T, Hkv, hd)
+    v = v.reshape(B, T, Hkv, hd)
     if use_rope:
         q = apply_rotary(q, positions, spec.rotary_dim,
                          flags.rotary_interleaved, spec.rope_theta)

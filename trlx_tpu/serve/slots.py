@@ -532,35 +532,58 @@ class SlotPoolRuntime:
     # -- warmup ------------------------------------------------------------ #
 
     def _report_decode_memory(self) -> None:
-        """What the compiler made of the decode step's pool writes, as
-        two gauges and one log line: ``serve/decode_alias_bytes`` (bytes
-        of arguments aliased to outputs: the whole pool + the lanes when
-        every layer's scatter lands in place; 0 on the CPU, which has no
-        donation) and ``serve/decode_temp_bytes`` (the program's
-        temporaries: prefetched weight slices and a few layers' gathered
-        pages, never a pool's worth). A second pool in either number is
-        a copy a later change brought back."""
+        """What the compiler made of the decode step, as three gauges and
+        one log line. ``serve/decode_alias_bytes``: bytes of arguments
+        aliased to outputs (the whole pool + the lanes when every layer's
+        scatter lands in place; 0 on the CPU, which has no donation).
+        ``serve/decode_temp_bytes``: the program's temporaries (prefetched
+        weight slices and a few layers' gathered pages, never a pool's
+        worth); a second pool in either number is a copy a later change
+        brought back. ``serve/decode_weight_copy_bytes``: bytes of the ops
+        that only move data and write at least a layer's q, k or v matrix
+        (utils/hlo_text.large_moves): 0 while every weight reaches its
+        dot in the layout it is stored in; a weight written out again in
+        another layout, every step, shows here before a trace is read."""
         e = self.engine
         extra = (np.zeros((self.num_slots, self.ring_pages), np.int32),) \
             if self.two_class else ()
-        stats = self._decode_fn().compiled_for(
+        import jax
+
+        from trlx_tpu.serve import layouts
+        from trlx_tpu.utils.hlo_text import large_moves
+
+        compiled = self._decode_fn().compiled_for(
             e.blocks, e.embed, e.ln_f, self.pool, self.state, np.int32(0),
             *extra,
-        ).memory_analysis()
-        if stats is None:  # a backend without the analysis
-            return
-        alias, temp = stats.alias_size_in_bytes, stats.temp_size_in_bytes
-        telemetry.set_gauge("serve/decode_alias_bytes", alias)
-        telemetry.set_gauge("serve/decode_temp_bytes", temp)
-        from trlx_tpu.serve import layouts
-
-        pool = layouts.tree_bytes_per_device(self.pool)
-        print(
-            f"[trlx_tpu.serve] decode step: {alias / 2**30:.3f} GiB of "
-            f"arguments aliased to outputs (pool {pool / 2**30:.3f} GiB "
-            f"per device), {temp / 2**30:.3f} GiB of temporaries",
-            file=sys.stderr, flush=True,
         )
+        said = []
+        stats = compiled.memory_analysis()
+        if stats is not None:  # None: a backend without the analysis
+            alias, temp = stats.alias_size_in_bytes, stats.temp_size_in_bytes
+            telemetry.set_gauge("serve/decode_alias_bytes", alias)
+            telemetry.set_gauge("serve/decode_temp_bytes", temp)
+            pool = layouts.tree_bytes_per_device(self.pool)
+            said += [
+                f"{alias / 2**30:.3f} GiB of arguments aliased to outputs "
+                f"(pool {pool / 2**30:.3f} GiB per device)",
+                f"{temp / 2**30:.3f} GiB of temporaries",
+            ]
+        seg, layers = next(  # a hydra's frozen segment may hold no layer
+            (s, n) for s, n in zip(self._segments, self._seg_sizes) if n
+        )
+        one_matrix = min(  # the int8 tier's (codes, scale): the codes
+            layouts.tree_bytes_per_device(
+                jax.tree_util.tree_leaves(seg["attn"][name])[0]
+            ) // layers
+            for name in ("wq", "wk", "wv")
+        )
+        moved = sum(
+            m.nbytes for m in large_moves(compiled.as_text(), one_matrix)
+        )
+        telemetry.set_gauge("serve/decode_weight_copy_bytes", moved)
+        said.append(f"{moved / 2**30:.3f} GiB of weight-sized copies")
+        print(f"[trlx_tpu.serve] decode step: {', '.join(said)}",
+              file=sys.stderr, flush=True)
 
     def warmup(self) -> Dict[str, float]:
         """Compile every admission bucket + the decode step up front.
