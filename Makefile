@@ -17,7 +17,7 @@ lint:
 
 check: lint kernels defense obs overload spec
 	@command -v ruff >/dev/null 2>&1 \
-		&& ruff check trlx_tpu tests examples bench.py __graft_entry__.py \
+		&& ruff check trlx_tpu tests examples __graft_entry__.py \
 		|| true
 
 # Pallas kernel tier (trlx_tpu/ops): the fused-attention train kernels
@@ -34,7 +34,7 @@ kernels:
 
 style:
 	@command -v ruff >/dev/null 2>&1 \
-		&& ruff check --fix trlx_tpu tests examples bench.py __graft_entry__.py \
+		&& ruff check --fix trlx_tpu tests examples __graft_entry__.py \
 		|| python -m trlx_tpu.analysis
 
 # the tier-1 contract (ROADMAP.md): CPU-pinned so a dev-box run never
@@ -68,16 +68,15 @@ chaos:
 
 # inference-serving tier (trlx_tpu/serve, docs "Serving"): bucketed AOT
 # decode engine (checkpoint restore + strip, zero steady-state
-# recompiles), the static micro-batcher (deadline flush, bucket
-# rounding, queue-overflow admission control), the continuous-batching
-# slot scheduler (test_slots.py: prefill/decode-step parity vs one-shot
-# generate(), step-level harvest + slot reuse mid-decode, occupancy
+# recompiles), the continuous-batching slot scheduler (test_slots.py:
+# parity vs one-shot generate(), queue-overflow admission control,
+# step-level harvest + slot reuse mid-decode, occupancy
 # metrics, and the chaos drill on the serve_admit seam — hang = watchdog
 # stall, exc = contained batch failure), the paged KV pool + radix
 # prefix cache (test_paged.py: allocator/radix semantics, greedy-parity
 # sweep across page sizes, prefix-hit prefill skipping, exhaustion
 # queue-not-crash, serve_prefix_match chaos drill, pool health on
-# /healthz, contiguous fallback), HTTP endpoint parity e2e, the
+# /healthz), HTTP endpoint parity e2e, the
 # serve_decode/serve_request containment paths, and the
 # request-lifecycle observability layer (test_request_trace.py:
 # RequestTrace/TTFT/ITL semantics, Perfetto span export validity,

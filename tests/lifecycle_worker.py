@@ -18,7 +18,7 @@ def main() -> None:
     telemetry.start()
     serve = ServeConfig(
         buckets=[[2, 8, 8]], max_queue=16, request_timeout=30.0,
-        scheduler="slots", slots=2, kv_layout="paged", page_size=4,
+        slots=2, page_size=4,
         drain_timeout=20.0,
     )
     engine = InferenceEngine(TRLConfig.from_dict(tiny_config_dict()),

@@ -40,7 +40,7 @@ SPEC = {
     "window": 16, "rope_kinds": ["window"], "n_experts": 16, "experts_per_token": 2, "n_shared_experts": 2,
     "expert_width": 32, "experts_held": 4, "expert_offset": 4,
 }
-SERVE = {"scheduler": "slots", "kv_layout": "paged", "page_size": 8, "slots": 3, "pages": 64, "window_pages": 24,
+SERVE = {"page_size": 8, "slots": 3, "pages": 64, "window_pages": 24,
          "buckets": [[1, 16, 16], [2, 16, 16], [1, 128, 16]], "flight_recorder_steps": 64}
 # the tests' pool is float32 (the served one is bfloat16, which alone moves a logit of standard deviation 1.3 by
 # 0.02): what is left is the order of float32 sums, and any fault of position, page or window is orders above it
@@ -252,8 +252,7 @@ def test_dense_specs_and_parameter_trees_are_unchanged(arch):
 # ------------------------------------------------------------ what the arch cannot run under yet
 @pytest.mark.parametrize("setting, value", [
     ("kv_dtype", "int8"), ("weights_dtype", "int8"), ("speculation", "lookup"), ("mesh", {"tp": 2}),
-    ("scheduler", "static"), ("kv_layout", "contiguous"), ("trainer", "JaxPPOTrainer"), ("trainer", "JaxILQLTrainer"),
-    ("hf_import", "cohere2_moe"),
+    ("trainer", "JaxPPOTrainer"), ("trainer", "JaxILQLTrainer"), ("hf_import", "cohere2_moe"),
 ])
 def test_one_refusal_names_the_setting_and_the_arch(setting, value):
     with pytest.raises(NotImplementedError, match=f"{setting}=.*cohere2_moe") as refused:

@@ -111,8 +111,8 @@ def serve_greedy():
         TRLConfig.from_dict(tiny_config_dict()),
         serve=ServeConfig(
             buckets=[[2, 8, 8], [4, 8, 8]], max_queue=64,
-            request_timeout=30.0, scheduler="slots", slots=4,
-            kv_layout="paged", page_size=4,
+            request_timeout=30.0, slots=4,
+            page_size=4,
         ),
     )
     s = SlotScheduler(engine)

@@ -29,7 +29,7 @@ import pytest
 from trlx_tpu import telemetry
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.serve import InferenceEngine, InferenceServer, ServeConfig
-from trlx_tpu.serve.batcher import DeadlineExceeded
+from trlx_tpu.serve.admission import DeadlineExceeded
 from trlx_tpu.serve.slots import SlotScheduler
 from trlx_tpu.supervisor import chaos
 from test_serve import tiny_config_dict
@@ -40,8 +40,8 @@ def build_engine(page_size=4, buckets=None, **overrides):
     telemetry.start()
     serve = ServeConfig(**{
         "buckets": buckets or [[2, 8, 8]], "max_queue": 64,
-        "request_timeout": 30.0, "scheduler": "slots", "slots": 4,
-        "kv_layout": "paged", "page_size": page_size, **overrides,
+        "request_timeout": 30.0, "slots": 4,
+        "page_size": page_size, **overrides,
     })
     return InferenceEngine(TRLConfig.from_dict(tiny_config_dict()),
                            serve=serve)
@@ -251,7 +251,7 @@ def test_hot_swap_chaos_reload_fault_rolls_back_then_recovers():
 
 SERVE_HTTP = ServeConfig(
     buckets=[[2, 8, 8], [4, 8, 8]], max_queue=8, request_timeout=60.0,
-    scheduler="slots", slots=4, kv_layout="paged", page_size=4,
+    slots=4, page_size=4,
     drain_timeout=15.0,
 )
 
@@ -295,20 +295,20 @@ def test_drain_under_load_e2e(http_engine):
         # hold the decode steps while the drill looks at the draining
         # server: a toy engine otherwise finishes the burst, drains and
         # closes its socket under the drill's own requests
-        step = srv.batcher.runtime.step
+        step = srv.scheduler.runtime.step
 
         def held_step(seed):
             release.wait(timeout=60.0)
             return step(seed)
 
-        srv.batcher.runtime.step = held_step
+        srv.scheduler.runtime.step = held_step
         rows = [[1, 2, 3], [4, 5], [6, 7], [8, 9, 1], [2, 2], [3, 1, 4]]
         out, threads = _burst(srv.port, rows)
         # wait until the engine actually holds live work
         deadline = time.monotonic() + 30.0
-        while not srv.batcher._live and time.monotonic() < deadline:
+        while not srv.scheduler._live and time.monotonic() < deadline:
             time.sleep(0.01)
-        assert srv.batcher._live, "burst never reached the slots"
+        assert srv.scheduler._live, "burst never reached the slots"
         # ...and has accepted the whole burst: a request that arrives
         # once the drain has begun is refused (429), by design
         while (registry.counters.get("serve/requests", 0.0) < len(rows)
@@ -362,7 +362,7 @@ def test_retry_after_paces_the_backlog(http_engine):
         chaos.configure("serve_decode:hang=60@1")
         out, threads = _burst(srv.port, [[1, 2]], max_new=2)
         deadline = time.monotonic() + 30.0
-        while not srv.batcher._live and time.monotonic() < deadline:
+        while not srv.scheduler._live and time.monotonic() < deadline:
             time.sleep(0.01)
         # fill the queue behind the wedged step...
         more, more_threads = _burst(
@@ -370,7 +370,7 @@ def test_retry_after_paces_the_backlog(http_engine):
             max_new=2,
         )
         deadline = time.monotonic() + 30.0
-        while (srv.batcher.queue_depth() < SERVE_HTTP.max_queue
+        while (srv.scheduler.queue_depth() < SERVE_HTTP.max_queue
                and time.monotonic() < deadline):
             time.sleep(0.01)
         # ...and the next arrival is paced, not just bounced
@@ -517,7 +517,7 @@ def test_watch_checkpoints_auto_swaps(tmp_path):
     telemetry.start()
     serve = ServeConfig(
         buckets=[[2, 8, 8]], max_queue=8, request_timeout=30.0,
-        scheduler="slots", slots=2, kv_layout="paged", page_size=4,
+        slots=2, page_size=4,
         watch_checkpoints=0.2,
     )
     engine = InferenceEngine.from_checkpoint(run, serve=serve)

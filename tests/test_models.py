@@ -275,8 +275,8 @@ def test_debug_nans_no_cross_trainer_leak():
 def test_memory_fit_counts_optimizer_choice(monkeypatch):
     """The precheck's optimizer-state term follows train.optimizer: the
     bf16-frozen single-chip 6B hydra that FAILS under fp32 AdamW (~19 GB)
-    PASSES under adafactor (~15 GB) — the lever bench.py's 6B train leg
-    exercises on the real chip."""
+    PASSES under adafactor (~15 GB) — the lever a 6B train run on one
+    chip needs."""
     import jax
 
     from tests.test_ppo_e2e import make_config

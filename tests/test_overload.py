@@ -31,9 +31,8 @@ from trlx_tpu import telemetry
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.router import FleetRouter, RouterConfig
 from trlx_tpu.serve import InferenceEngine, InferenceServer, ServeConfig
-from trlx_tpu.serve.batcher import (
+from trlx_tpu.serve.admission import (
     DEFAULT_TENANT,
-    MicroBatcher,
     QueueFull,
     QuotaExceeded,
     TenantPolicy,
@@ -46,9 +45,8 @@ SERVE_OVERLOAD = ServeConfig(
     buckets=[[2, 8, 8], [4, 8, 8]],  # (B, P, G): one prompt class P=8
     max_queue=32,
     request_timeout=30.0,
-    scheduler="slots",
     slots=2,
-    kv_layout="contiguous",
+    page_size=4,  # divides the bucket's prompt and prompt + gen
 )
 
 
@@ -240,18 +238,6 @@ def test_over_share_tenant_never_sees_global_queue_full(
         assert ok in sched._queue
 
 
-def test_micro_batcher_enforces_the_same_quota(engine, fresh_registry):
-    with serve_overrides(engine, tenants={"free": {"rps": 0.01,
-                                                   "burst": 1}}):
-        mb = MicroBatcher(engine)  # not started: admission-path only
-        mb.submit([1, 2], max_new_tokens=4, tenant="free")
-        with pytest.raises(QuotaExceeded) as exc:
-            mb.submit([1, 2], max_new_tokens=4, tenant="free")
-        assert exc.value.tenant == "free"
-        assert fresh_registry.counters[
-            "serve/shed_quota{tenant=free}"] == 1.0
-
-
 def test_priority_aging_prevents_starvation(engine, fresh_registry):
     """Satellite regression: a queued best-effort request gains one
     effective priority level every ``priority_aging_rounds`` admission
@@ -437,13 +423,13 @@ def test_http_quota_429_degraded_flag_and_readyz_pressure(engine):
                               {"tokens": [1, 2], "max_new_tokens": 2})
             assert status == 200
             # browned-out best-effort answers carry "degraded": true
-            srv.batcher._brownout = True
+            srv.scheduler._brownout = True
             status, body = _http(srv.port, "POST", "/generate",
                                  {"tokens": [1, 2], "max_new_tokens": 6,
                                   "tenant": "guest"})
             assert status == 200
             assert body.get("degraded") is True
-            srv.batcher._brownout = False
+            srv.scheduler._brownout = False
             # /readyz publishes the pressure block the prober ingests
             status, ready = _http(srv.port, "GET", "/readyz")
             assert status == 200

@@ -6,8 +6,7 @@ leaves), device-level paged prefill/decode parity against one-shot
 ``generate()``, the greedy-parity sweep across page sizes and staggered
 shared-prefix admission, prefix hits skipping prefill tokens, the
 ``serve_prefix_match`` chaos drill, pool health on /healthz + /metrics,
-the buffer-reusing ``reset_lanes``, and the ``serve.kv_layout:
-contiguous`` A/B fallback.
+and the buffer-reusing ``reset_lanes``.
 """
 
 import json
@@ -26,7 +25,6 @@ from trlx_tpu.models.generation import (
     _segments_of,
     decode_step,
     init_page_pool,
-    init_slot_pool,
     init_slot_state,
     prefill_into_slots,
     verify_step,
@@ -42,9 +40,7 @@ SERVE_PAGED = ServeConfig(
     buckets=[[2, 8, 8], [4, 8, 8], [4, 16, 8]],
     max_queue=64,
     request_timeout=30.0,
-    scheduler="slots",
     slots=4,
-    kv_layout="paged",
     page_size=4,
 )
 
@@ -53,7 +49,7 @@ def build_engine(**overrides):
     telemetry.start()
     serve = ServeConfig(**{
         "buckets": [[2, 8, 8]], "max_queue": 64, "request_timeout": 30.0,
-        "scheduler": "slots", "slots": 4, "kv_layout": "paged",
+        "slots": 4,
         "page_size": 4, **overrides,
     })
     return InferenceEngine(TRLConfig.from_dict(tiny_config_dict()),
@@ -275,23 +271,21 @@ def _flat_eqns(jaxpr, env):
             yield eqn.primitive.name, ins, list(eqn.outvars)
 
 
-@pytest.mark.parametrize("layout,kv_dtype,program", [
-    ("paged", "bfloat16", "decode_step"),
-    ("paged", "bfloat16", "verify_step"),
-    ("paged", "bfloat16", "prefill_suffix"),
-    ("paged", "bfloat16", "prefill"),
-    ("paged", "int8", "decode_step"),
-    ("paged", "int8", "verify_step"),
-    ("paged", "int8", "prefill_suffix"),
-    ("paged", "int8", "prefill"),
-    ("contiguous", "bfloat16", "decode_step"),
-    ("contiguous", "bfloat16", "prefill"),
+@pytest.mark.parametrize("kv_dtype,program", [
+    ("bfloat16", "decode_step"),
+    ("bfloat16", "verify_step"),
+    ("bfloat16", "prefill_suffix"),
+    ("bfloat16", "prefill"),
+    ("int8", "decode_step"),
+    ("int8", "verify_step"),
+    ("int8", "prefill_suffix"),
+    ("int8", "prefill"),
 ])
-def test_pool_leaves_are_written_in_place(engine, layout, kv_dtype, program):
+def test_pool_leaves_are_written_in_place(engine, kv_dtype, program):
     """Every pool leaf enters a serve program, is consumed by exactly ONE
     scatter (block_apply's write of the fresh rows, or the local
     prefill's block-scatter), and that scatter's output — read only by
-    gathers under the paged layout — is the leaf the program returns.
+    gathers — is the leaf the program returns.
     No slice / dynamic_update_slice / concatenate touches anything as
     large as a leaf. That is what lets XLA alias each donated leaf to
     its output; a stacked [L, ...] pool (sliced per layer, written back
@@ -307,23 +301,12 @@ def test_pool_leaves_are_written_in_place(engine, layout, kv_dtype, program):
     # a pool whose SMALLEST leaf (an int8 scale plane) outgrows every
     # other array of the program, so "as large as a leaf" names the pool
     rows = max(x.size for x in weights) // (ps * spec.kv_heads) + 1
-    paged = layout == "paged"
-    if paged:
-        make_pool = lambda: init_page_pool(
-            spec, seg_sizes, rows, ps,
-            cache_dtype=jnp.int8 if kv_dtype == "int8" else jnp.bfloat16,
-        )
-    else:
-        make_pool = lambda: init_slot_pool(
-            spec, seg_sizes, max(S, rows // (max_pages * ps) + 1),
-            max_pages * ps,
-        )
-    pool = jax.eval_shape(make_pool)
-    if not paged:
-        S = jax.tree_util.tree_leaves(pool)[0].shape[0]
+    pool = jax.eval_shape(lambda: init_page_pool(
+        spec, seg_sizes, rows, ps,
+        cache_dtype=jnp.int8 if kv_dtype == "int8" else jnp.bfloat16,
+    ))
     state = jax.eval_shape(lambda: init_slot_state(
-        S, max_pages * ps, spec.vocab_size,
-        max_pages=max_pages if paged else None,
+        S, max_pages * ps, spec.vocab_size, max_pages=max_pages,
     ))
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     model = (spec, engine.blocks, engine.embed, engine.ln_f)
@@ -341,9 +324,8 @@ def test_pool_leaves_are_written_in_place(engine, layout, kv_dtype, program):
         args = (i32(S, K), i32(S))
     else:
         fn = lambda pool, st, t, m, sid, mn, pt, start: prefill_into_slots(
-            *model, pool, st, t, m, sid, mn, compute_dtype=jnp.float32,
-            page_tables=pt if paged else None,
-            page_size=ps if paged else None, start=start,
+            *model, pool, st, t, m, sid, mn, pt, ps,
+            compute_dtype=jnp.float32, start=start,
             prefix_context=program == "prefill_suffix",
         )[0]
         args = (i32(B, P), i32(B, P), i32(B), i32(B), i32(B, max_pages),
@@ -365,8 +347,7 @@ def test_pool_leaves_are_written_in_place(engine, layout, kv_dtype, program):
             f"{program}: a returned pool leaf is not its scatter's output"
         )
         after = [e[0] for e in eqns if any(v is written for v in e[1])]
-        if paged:
-            assert set(after) <= {"gather"}, after
+        assert set(after) <= {"gather"}, after
         if program in ("decode_step", "verify_step", "prefill_suffix"):
             assert after, "attention never reads the written leaf"
     big = lambda v: _is_var(v) and v.aval.size >= leaf_size
@@ -377,6 +358,39 @@ def test_pool_leaves_are_written_in_place(engine, layout, kv_dtype, program):
         and any(big(v) for v in ins + outs)
     ]
     assert not moved, f"{program}: {moved} move something of a leaf's size"
+
+
+def test_block_apply_refuses_per_row_writes_without_a_page_table(engine):
+    """Per-row cache writes exist only through a page table: a contiguous
+    cache (generate()'s own) is written at the one ``cache_offset``. The
+    combination that used to be the contiguous slot pool is refused, not
+    taken for a cache_offset of None."""
+    from trlx_tpu.models.transformer import (
+        ArchFlags,
+        block_apply,
+        init_kv_cache,
+    )
+
+    spec = engine.spec
+    B, T = 2, 8
+    layer = jax.tree_util.tree_map(
+        lambda x: x[0], _segments_of(engine.blocks)[0][-1]
+    )
+    cache = jax.tree_util.tree_map(
+        lambda x: x[0], init_kv_cache(spec, 1, B, T, jnp.float32)
+    )
+    args = dict(
+        h=jnp.zeros((B, 1, spec.d_model), jnp.float32),
+        mask_bias=jnp.zeros((B, 1, 1, T), jnp.float32),
+        positions=jnp.zeros((B, 1), jnp.int32), kv_cache=cache,
+    )
+    with pytest.raises(ValueError, match="cache_row_offsets.*page_table"):
+        block_apply(spec, ArchFlags.for_spec(spec), layer,
+                    cache_row_offsets=jnp.zeros((B,), jnp.int32), **args)
+    out, new_cache = block_apply(spec, ArchFlags.for_spec(spec), layer,
+                                 cache_offset=jnp.int32(3), **args)
+    assert out.shape == (B, 1, spec.d_model)
+    assert new_cache[0].shape == cache[0].shape
 
 
 # --------------------------------------------------------------------- #
@@ -582,7 +596,7 @@ def test_poisoned_step_resets_prefix_cache_and_replays(engine,
 
 
 # --------------------------------------------------------------------- #
-# surfaces: /healthz + /metrics, contiguous fallback
+# surfaces: /healthz + /metrics
 # --------------------------------------------------------------------- #
 
 
@@ -610,7 +624,6 @@ def test_healthz_and_metrics_report_pool_health(engine, fresh_registry):
         status, health = _get(server.port, "/healthz")
         assert status == 200 and health["status"] == "ok"
         kv = health["kv"]
-        assert kv["kv_layout"] == "paged"
         assert kv["page_size"] == 4
         assert kv["pages_total"] == kv["pages_free"] == 24
         assert kv["prefix_hit_rate"] == 0.0
@@ -674,34 +687,6 @@ def test_soak_paged_no_recompiles_no_page_leaks(fresh_registry):
         assert registry.counters["serve/admissions"] == 300.0
         assert registry.counters["serve/prefix_tokens_saved"] > 0.0
         assert registry.counters.get("serve/request_errors", 0.0) == 0.0
-    finally:
-        s.stop()
-
-
-def test_contiguous_fallback_still_serves(fresh_registry):
-    """serve.kv_layout: contiguous stays a working A/B fallback: same
-    scheduler surface, parity with generate(), no paged structures."""
-    engine = build_engine(kv_layout="contiguous")
-    registry = telemetry.current().registry
-    s = SlotScheduler(engine)
-    assert s.cache is None
-    stats = s.pool_stats()
-    assert stats["kv_layout"] == "contiguous"
-    assert stats["slots"] == 4
-    # per-device footprint reports for both layouts; no paged keys here
-    assert stats["pool_gb_per_device"] > 0
-    assert "pages_total" not in stats
-    s.warmup()
-    s.start()
-    try:
-        rows = [[3, 1, 4], [1, 5, 9, 2, 6]]
-        reqs = [s.submit(r, max_new_tokens=8) for r in rows]
-        for r in reqs:
-            r.wait(timeout=60.0)
-        oracle = direct_generate(engine, rows, (2, 8, 8))
-        for i, r in enumerate(reqs):
-            assert r.result == engine.depad_row(oracle, i, 8)
-        assert registry.counters.get("compile/recompiles", 0.0) == 0.0
     finally:
         s.stop()
 

@@ -4,7 +4,7 @@ derivation + goodput, Perfetto span export validity (every line parses,
 children nest inside the parent on the request's own track), Prometheus
 text exposition (schema + predeclared-zero series + content negotiation
 on /metrics), /debug/state, flight-recorder ring/dump behavior on
-poisoned steps and watchdog stalls, and the static-path trace.
+poisoned steps and watchdog stalls.
 """
 
 import json
@@ -28,9 +28,7 @@ SERVE_TRACED = ServeConfig(
     buckets=[[2, 8, 8], [4, 8, 8]],
     max_queue=64,
     request_timeout=30.0,
-    scheduler="slots",
     slots=4,
-    kv_layout="paged",
     page_size=4,
     slo_ttft_ms=0.0,  # every completed request counts good
     flight_recorder_steps=32,
@@ -230,20 +228,6 @@ def test_trace_goodput_slo_gating(fresh_registry):
     assert fresh_registry.gauges["serve/goodput"] == 0.5
     assert fresh_registry.counters["serve/slo_total"] == 2.0
     assert fresh_registry.counters["serve/slo_good"] == 1.0
-
-
-def test_trace_static_decode_approximation(fresh_registry):
-    tr = RequestTrace(received=0.0)
-    tr.enqueued = 0.0
-    tr.note_static_decode(1.0, 2.0, n_tokens=5)
-    tr.harvested = 2.0
-    # batch-to-completion: first token materializes at decode END; ITL is
-    # the uniform decode_time/tokens approximation
-    assert tr.ttft() == pytest.approx(2.0)
-    assert tr.itl_count == 4
-    assert tr.itl_mean() == pytest.approx(0.2)
-    assert tr.itl_min == tr.itl_max == pytest.approx(0.2)
-    assert fresh_registry.hists["serve/itl"].count == 1
 
 
 def test_trace_perfetto_export_parses_and_nests(fresh_registry, tmp_path):
@@ -551,7 +535,6 @@ def test_debug_state_endpoint(server):
     assert body["queue_depth"] == 0
     assert body["free_slots"] == 4  # everything harvested
     assert body["slots"] == {}
-    assert body["kv"]["kv_layout"] == "paged"
     assert body["kv"]["pages_total"] >= 1
     assert isinstance(body["flight_recorder"], list)
     assert body["flight_recorder"], "flight ring empty after a decode"

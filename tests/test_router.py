@@ -35,7 +35,7 @@ ROWS = [PREFIX + t for t in TAILS]
 
 SERVE = dict(
     buckets=[[4, 8, 8]], max_queue=64, request_timeout=60.0,
-    scheduler="slots", slots=4, kv_layout="paged", page_size=4,
+    slots=4, page_size=4,
 )
 BUCKET = (4, 8, 8)
 

@@ -1,12 +1,14 @@
-"""Inference-serving tests (trlx_tpu/serve): bucket lattice + AOT decode
-engine, dynamic micro-batcher semantics (deadline flush, bucket rounding,
-admission control), HTTP endpoint routes, chaos-driven containment, and
-the checkpoint->endpoint parity e2e the subsystem exists for.
+"""Inference-serving tests (trlx_tpu/serve): bucket lattice + the
+one-shot ``decode`` oracle, submit/wait validation, the ``serve:``
+config surface (retired settings refused by name, files written before
+they were retired still loading), HTTP endpoint routes, and the
+checkpoint->endpoint parity e2e the subsystem exists for. The slot
+scheduler's own tier is test_slots.py.
 """
 
 import json
+import pathlib
 import threading
-import time
 import urllib.error
 import urllib.request
 
@@ -20,11 +22,12 @@ from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.serve import (
     InferenceEngine,
     InferenceServer,
-    MicroBatcher,
-    QueueFull,
     ServeConfig,
+    SlotScheduler,
 )
-from trlx_tpu.supervisor import RunSupervisor, chaos
+from trlx_tpu.supervisor import chaos
+
+REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
 def tiny_config_dict(do_sample=False):
@@ -80,15 +83,11 @@ def tiny_config_dict(do_sample=False):
     }
 
 
-# scheduler pinned to the batch-to-completion path: this module is the
-# static driver's tier (and the slots A/B baseline); the
-# continuous-batching slot scheduler has its own tier in test_slots.py
 SERVE = ServeConfig(
     buckets=[[2, 8, 8], [4, 8, 8], [4, 16, 8]],
-    max_wait_ms=40.0,
     max_queue=64,
     request_timeout=30.0,
-    scheduler="static",
+    page_size=4,
 )
 
 
@@ -109,10 +108,11 @@ def fresh_registry():
 
 
 @pytest.fixture()
-def batcher(engine):
-    b = MicroBatcher(engine).start()
-    yield b
-    b.stop()
+def scheduler(engine):
+    """Not started: nothing drains, so submit's own checks are what runs."""
+    s = SlotScheduler(engine)
+    yield s
+    s.stop()
 
 
 # --------------------------------------------------------------------- #
@@ -128,13 +128,6 @@ def test_pick_shape_rounds_up_to_smallest_fit(engine):
         engine.pick_shape(17, 8)
     with pytest.raises(ValueError, match="fits no serve bucket"):
         engine.pick_shape(4, 9)
-
-
-def test_batch_sizes_ascend_per_shape_class(engine):
-    assert engine.batch_sizes_for((8, 8)) == (2, 4)
-    assert engine.batch_sizes_for((16, 8)) == (4,)
-    assert engine.max_new_tokens_cap() == 8
-    assert engine.default_max_new_tokens() == 8
 
 
 def test_pad_batch_left_pads_and_fills(engine):
@@ -168,180 +161,50 @@ def test_engine_rejects_non_ppo_method():
         InferenceEngine(cfg, serve=SERVE, init=False)
 
 
-def test_warmup_compiles_each_bucket_once(engine, fresh_registry):
+def test_decode_compiles_each_bucket_once(engine, fresh_registry):
+    """The one-shot oracle: a bucket's first ``decode`` is a first
+    compile in ITS OWN cache, never a steady-state miss, and a second
+    call of the same bucket compiles nothing."""
     engine._decode_fns = {}
-    engine.warmed = False
-    latencies = engine.warmup()
-    assert engine.warmed
-    assert set(latencies) == {
-        engine.span_name(b) for b in engine.buckets
-    }
-    # warming bucket N+1 is a first compile in ITS OWN cache, never a
-    # steady-state miss — the serving invariant
+    for b in engine.buckets:
+        tokens, mask = engine.pad_batch([[1, 2]], b)
+        engine.decode(b, tokens, mask, seed=0)
     assert fresh_registry.counters.get("compile/recompiles", 0.0) == 0.0
-    # and a steady-state call after warmup does not recompile either
+    assert set(engine._decode_fns) == set(engine.buckets)
     b = engine.buckets[0]
     tokens, mask = engine.pad_batch([[1, 2]], b)
     engine.decode(b, tokens, mask, seed=3)
     assert fresh_registry.counters.get("compile/recompiles", 0.0) == 0.0
+    assert len(engine._decode_fn(b)._cache) == 1
     # per-bucket first-call (compile) latency recorded apart by the tracer
     assert f"compile/{engine.span_name(b)}_first_s" in fresh_registry.gauges
+    assert engine.max_new_tokens_cap() == 8
+    assert engine.default_max_new_tokens() == 8
 
 
 # --------------------------------------------------------------------- #
-# micro-batcher semantics
+# submit / wait
 # --------------------------------------------------------------------- #
 
 
-def test_deadline_flush_partial_batch(engine, fresh_registry, batcher):
-    t0 = time.monotonic()
-    req = batcher.submit([1, 2, 3], max_new_tokens=4)
-    req.wait(timeout=30.0)
-    assert req.result is not None and len(req.result) <= 4
-    assert time.monotonic() - t0 < 25.0
-    # one request in a batch-2 bucket: fill ratio 0.5
-    assert fresh_registry.gauges["serve/batch_fill_ratio"] == 0.5
-    assert fresh_registry.counters["serve/batches"] == 1.0
-    assert fresh_registry.counters["serve/responses"] == 1.0
-    assert "serve/request_latency{path=static}" in fresh_registry.hists
-
-
-def test_static_path_populates_request_trace(engine, fresh_registry,
-                                             batcher):
-    """The batch-to-completion path fills the same RequestTrace the slot
-    scheduler does: first token materializes at decode END (the whole
-    decode is one program) and ITL is the uniform decode_time/tokens
-    approximation (trlx_tpu/serve/trace.py note_static_decode)."""
-    req = batcher.submit([1, 2, 3], max_new_tokens=4)
-    req.wait(timeout=30.0)
-    tr = req.trace
-    assert tr is not None
-    assert tr.received <= tr.enqueued <= tr.admitted
-    assert tr.admitted <= tr.prefill_end <= tr.first_token
-    assert tr.first_token == tr.last_token  # batch-to-completion
-    assert tr.harvested >= tr.first_token
-    assert tr.bucket is not None
-    assert tr.ttft() > 0.0
-    if len(req.result) > 1:
-        assert tr.itl_count == len(req.result) - 1
-        assert tr.itl_min == tr.itl_max  # uniform approximation
-    # complete("static", ...) derived the SLO family + per-path latency
-    assert fresh_registry.hists["serve/ttft"].count == 1
-    assert fresh_registry.hists[
-        "serve/request_latency{path=static}"].count == 1
-    assert "serve/goodput" in fresh_registry.gauges
-
-
-def test_full_bucket_flushes_before_deadline(engine, fresh_registry):
-    b = MicroBatcher(engine, max_wait_ms=30_000.0).start()
-    try:
-        t0 = time.monotonic()
-        reqs = [b.submit([i + 1], max_new_tokens=2) for i in range(4)]
-        for r in reqs:
-            r.wait(timeout=30.0)
-        # the largest (8, 8) extent is 4: filling it must flush without
-        # waiting out the 30s deadline
-        assert time.monotonic() - t0 < 20.0
-        assert fresh_registry.gauges["serve/batch_fill_ratio"] == 1.0
-    finally:
-        b.stop()
-
-
-def test_bucket_rounding_groups_same_shape_only(engine, batcher):
-    short = batcher.submit([1, 2], max_new_tokens=8)  # (8, 8) class
-    long = batcher.submit(list(range(1, 13)), max_new_tokens=8)  # (16, 8)
-    short.wait(timeout=30.0)
-    long.wait(timeout=30.0)
-    assert short.shape == (8, 8)
-    assert long.shape == (16, 8)
-
-
-def test_queue_overflow_rejected(engine, fresh_registry):
-    b = MicroBatcher(engine, max_queue=3)  # not started: nothing drains
-    for i in range(3):
-        b.submit([1, 2], max_new_tokens=2)
-    with pytest.raises(QueueFull, match="retry with backoff"):
-        b.submit([1, 2], max_new_tokens=2)
-    assert fresh_registry.counters["serve/rejected"] == 1.0
-    b.stop()  # pending requests are failed, not stranded
-
-
-def test_submit_validation(engine, batcher):
+def test_submit_validation(engine, scheduler):
     with pytest.raises(ValueError, match="empty prompt"):
-        batcher.submit([], max_new_tokens=2)
+        scheduler.submit([], max_new_tokens=2)
     with pytest.raises(ValueError, match="max_new_tokens"):
-        batcher.submit([1], max_new_tokens=0)
+        scheduler.submit([1], max_new_tokens=0)
     with pytest.raises(ValueError, match="fits no serve bucket"):
-        batcher.submit([1], max_new_tokens=99)
+        scheduler.submit([1], max_new_tokens=99)
+    # bucket rounding: each request carries the shape class it rounds to
+    assert scheduler.submit([1, 2], max_new_tokens=8).shape == (8, 8)
+    assert scheduler.submit(
+        list(range(1, 13)), max_new_tokens=8
+    ).shape == (16, 8)
 
 
-def test_wait_timeout_raises(engine):
-    b = MicroBatcher(engine)  # not started
-    req = b.submit([1, 2], max_new_tokens=2)
+def test_wait_timeout_raises(engine, scheduler):
+    req = scheduler.submit([1, 2], max_new_tokens=2)
     with pytest.raises(TimeoutError, match="not decoded within"):
         req.wait(timeout=0.05)
-    b.stop()
-
-
-def test_stopped_batcher_fails_pending(engine):
-    b = MicroBatcher(engine)  # not started
-    req = b.submit([1, 2], max_new_tokens=2)
-    b.stop()
-    with pytest.raises(RuntimeError, match="batcher stopped"):
-        req.wait(timeout=1.0)
-
-
-# --------------------------------------------------------------------- #
-# chaos-driven stall containment
-# --------------------------------------------------------------------- #
-
-
-def test_chaos_hang_surfaces_as_watchdog_stall(engine, fresh_registry):
-    """serve_decode:hang wedges the decode phase; the serve supervisor
-    (owned by the batcher worker) must detect the stall — stack dump,
-    fault/stalls — and releasing the hang fails only that batch while
-    the loop keeps serving."""
-    exit_codes = []
-    sup = RunSupervisor(
-        stall_timeout=0.3,
-        stall_first_timeout=0.3,
-        stall_grace=10_000.0,
-        exit_fn=exit_codes.append,
-    )
-    chaos.configure("serve_decode:hang=60@1")
-    b = MicroBatcher(engine, max_wait_ms=5.0, run_supervisor=sup)
-    b.start()
-    try:
-        req = b.submit([1, 2, 3], max_new_tokens=2)
-        deadline = time.monotonic() + 15.0
-        while sup.stalls == 0 and time.monotonic() < deadline:
-            time.sleep(0.05)
-        assert sup.stalls >= 1, "watchdog never flagged the hung decode"
-        assert sup.stalled_phase == "serve_decode"
-        assert fresh_registry.counters["fault/stalls"] >= 1.0
-        chaos.reset()  # releases the hang as ChaosHang in the worker
-        with pytest.raises(chaos.ChaosHang):
-            req.wait(timeout=15.0)
-        assert fresh_registry.counters["serve/request_errors"] >= 1.0
-        # the loop survived: a fresh request decodes normally
-        ok = b.submit([4, 5], max_new_tokens=2)
-        assert ok.wait(timeout=30.0).result is not None
-        assert not exit_codes  # grace was huge: no escalation
-    finally:
-        chaos.reset()
-        b.stop()
-
-
-def test_chaos_exc_fails_batch_not_loop(engine, fresh_registry, batcher):
-    chaos.configure("serve_decode:exc@1")
-    try:
-        req = batcher.submit([1, 2], max_new_tokens=2)
-        with pytest.raises(chaos.ChaosError):
-            req.wait(timeout=30.0)
-        ok = batcher.submit([3, 4], max_new_tokens=2)
-        assert ok.wait(timeout=30.0).result is not None
-    finally:
-        chaos.reset()
 
 
 # --------------------------------------------------------------------- #
@@ -440,15 +303,15 @@ def test_chaos_request_exc_maps_to_500(server):
 
 
 def test_queue_full_maps_to_429(server):
-    batcher = server.batcher
-    old = batcher.max_queue
-    batcher.max_queue = 0
+    scheduler = server.scheduler
+    old = scheduler.max_queue
+    scheduler.max_queue = 0
     try:
         with pytest.raises(urllib.error.HTTPError) as e:
             _post(server.port, {"prompt": "x", "max_new_tokens": 2})
         assert e.value.code == 429
     finally:
-        batcher.max_queue = old
+        scheduler.max_queue = old
 
 
 def test_metrics_dump_has_serve_family(server):
@@ -457,15 +320,37 @@ def test_metrics_dump_has_serve_family(server):
     assert status == 200
     counters, gauges = body["counters"], body["gauges"]
     assert counters["serve/requests"] >= 1
-    assert counters["serve/batches"] >= 1
+    assert counters["serve/admissions"] >= 1
     assert "serve/rejected" in counters  # predeclared even before firing
     assert "serve/queue_depth" in gauges
-    assert "serve/batch_fill_ratio" in gauges
+    assert "serve/slot_occupancy" in gauges
     assert "serve/tokens_per_sec" in gauges
-    assert any(k.startswith("time/serve/decode_") for k in body["timings"])
-    assert "serve/request_latency{path=static}" in body["timings"]
-    hist = body["timings"]["serve/request_latency{path=static}"]
+    assert "time/serve/slot_step" in body["timings"]
+    assert any(k.startswith("time/serve/prefill_") for k in body["timings"])
+    assert "serve/request_latency{path=slots}" in body["timings"]
+    hist = body["timings"]["serve/request_latency{path=slots}"]
     assert "p50_s" in hist and "p95_s" in hist
+
+
+def test_metrics_carry_nothing_of_the_static_path(server):
+    """A warmed server's /metrics, JSON and Prometheus text: no series
+    labelled with the retired path, none of the three names only the
+    batch-to-completion scheduler set."""
+    _post(server.port, {"prompt": "warm", "max_new_tokens": 2})
+    _, body = _get(server.port, "/metrics")
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{server.port}/metrics",
+        headers={"Accept": "text/plain"},
+    )
+    with urllib.request.urlopen(req, timeout=60) as resp:
+        text = resp.read().decode()
+    assert 'path="slots"' in text
+    names = [k for section in ("counters", "gauges", "timings")
+             for k in body[section]]
+    for gone in ("static", "serve/batches", "serve/batch_fill_ratio",
+                 "serve/buckets_warmed"):
+        assert not [k for k in names if gone in k], gone
+        assert gone.replace("/", "_") not in text, gone
 
 
 # --------------------------------------------------------------------- #
@@ -489,8 +374,8 @@ def test_checkpoint_to_endpoint_parity_e2e(tmp_path, seed):
 
     registry = telemetry.start().registry
     serve_cfg = ServeConfig(
-        buckets=[[8, 8, 8]], max_wait_ms=250.0, max_queue=64,
-        request_timeout=60.0, scheduler="static",
+        buckets=[[8, 8, 8]], max_queue=64, request_timeout=60.0,
+        page_size=4,
     )
     # config=None: the architecture comes from the checkpoint's own
     # embedded meta.json config — the self-describing-checkpoint path
@@ -543,13 +428,14 @@ def test_checkpoint_to_endpoint_parity_e2e(tmp_path, seed):
                 f"generate(): {results[i]['tokens']} vs {expect}"
             )
 
-        # serving invariant: exactly one compile per warmed bucket and
+        # serving invariant: exactly one compile per warmed program and
         # ZERO steady-state recompiles across all live traffic
         _, metrics = _get(server.port, "/metrics")
         assert metrics["counters"]["compile/recompiles"] == 0
         assert registry.counters["compile/recompiles"] == 0.0
-        span = engine.span_name(bucket)
-        assert f"compile/{span}_first_s" in metrics["gauges"]
+        assert "compile/serve/prefill_b8p8_first_s" in metrics["gauges"]
+        assert "compile/serve/slot_step_first_s" in metrics["gauges"]
+        assert "serve/request_latency{path=slots}" in metrics["timings"]
         assert metrics["counters"]["serve/requests"] >= 8
         assert metrics["counters"]["serve/generated_tokens"] > 0
         assert metrics["gauges"].get("serve/model_gb", 0) > 0
@@ -584,28 +470,127 @@ def test_cli_bucket_parsing():
         parse_buckets("8x32")
     args = build_parser().parse_args(
         ["--checkpoint", "c", "--buckets", "2x8x8", "--port", "0",
-         "--max-wait-ms", "5", "--max-queue", "7",
-         "--scheduler", "static", "--slots", "3"]
+         "--max-queue", "7", "--slots", "3", "--page-size", "4"]
     )
     from trlx_tpu.serve.__main__ import serve_config_from_args
 
     cfg = serve_config_from_args(args)
     assert cfg.buckets == [[2, 8, 8]]
-    assert cfg.port == 0 and cfg.max_wait_ms == 5 and cfg.max_queue == 7
-    assert cfg.scheduler == "static" and cfg.slots == 3
-    # flags unset: the ServeConfig defaults survive (slots is the default
-    # driver)
+    assert cfg.port == 0 and cfg.max_queue == 7
+    assert cfg.slots == 3 and cfg.page_size == 4
+    # flags unset: the ServeConfig defaults survive
     bare = serve_config_from_args(
         build_parser().parse_args(["--checkpoint", "c"])
     )
-    assert bare.scheduler == "slots" and bare.slots == 0
+    assert bare.slots == 0 and bare.page_size == 64
+
+
+@pytest.mark.parametrize("flag, value", [
+    ("--scheduler", "slots"), ("--kv-layout", "paged"),
+])
+def test_cli_refuses_the_retired_flags(flag, value, capsys):
+    """The flags went with the paths they chose between: argparse
+    refuses them (exit 2) even at the value that names what is left."""
+    from trlx_tpu.serve.__main__ import build_parser
+
+    with pytest.raises(SystemExit) as refused:
+        build_parser().parse_args(["--checkpoint", "c", flag, value])
+    assert refused.value.code == 2
+    assert flag in capsys.readouterr().err
 
 
 def test_serve_config_roundtrip():
     cfg = ServeConfig.from_dict(
-        {"buckets": [[2, 8, 8]], "max_wait_ms": 7, "unknown_key": 1}
+        {"buckets": [[2, 8, 8]], "max_queue": 7, "unknown_key": 1}
     )
-    assert cfg.buckets == [[2, 8, 8]] and cfg.max_wait_ms == 7
+    assert cfg.buckets == [[2, 8, 8]] and cfg.max_queue == 7
+
+
+# --------------------------------------------------------------------- #
+# settings retired in PR 31: refused by name, or loaded and dropped
+# --------------------------------------------------------------------- #
+
+RETIRED = [("scheduler", "static", "slot scheduler"),
+           ("kv_layout", "contiguous", "pages")]
+#: what every config file and checkpoint written before PR 31 carries
+LEGACY_SERVE = {"scheduler": "slots", "kv_layout": "paged",
+                "max_wait_ms": 20.0}
+
+
+@pytest.mark.parametrize("key, value, survivor", RETIRED)
+def test_from_dict_refuses_a_retired_path_by_name(key, value, survivor):
+    with pytest.raises(ValueError, match=f"serve.{key}.*{value}") as e:
+        ServeConfig.from_dict({"buckets": [[2, 8, 8]], key: value})
+    assert "PR 31" in str(e.value) and survivor in str(e.value)
+
+
+@pytest.mark.parametrize("key", sorted(k for k, _, _ in RETIRED))
+def test_from_dict_drops_the_surviving_value(key):
+    cfg = ServeConfig.from_dict({"slots": 3, key: LEGACY_SERVE[key]})
+    assert cfg.slots == 3 and not hasattr(cfg, key)
+
+
+def test_trl_config_refuses_a_retired_serve_block_at_load(tmp_path):
+    import yaml
+
+    body = {**tiny_config_dict(), "serve": {"kv_layout": "contiguous"}}
+    path = tmp_path / "old.yml"
+    path.write_text(yaml.safe_dump(body))
+    with pytest.raises(ValueError, match="serve.kv_layout.*PR 31"):
+        TRLConfig.load_yaml(str(path))
+    body["serve"] = dict(LEGACY_SERVE)  # the surviving values: loads
+    path.write_text(yaml.safe_dump(body))
+    assert TRLConfig.load_yaml(str(path)).train.gen_size == 8
+
+
+def test_checkpoint_written_before_pr31_still_loads(tmp_path):
+    """Checkpoints are self-describing, and the ones users hold embed a
+    config written when the three keys existed."""
+    from trlx_tpu.utils.checkpoint import META_NAME
+    from trlx_tpu.utils.loading import get_model
+
+    cfg = TRLConfig.from_dict(tiny_config_dict())
+    ckpt = tmp_path / "ckpt"
+    get_model(cfg.model.model_type)(cfg).save(str(ckpt))
+    meta = json.loads((ckpt / META_NAME).read_text())
+    meta["config"]["serve"] = dict(LEGACY_SERVE)
+    (ckpt / META_NAME).write_text(json.dumps(meta))
+    engine = InferenceEngine.from_checkpoint(
+        str(ckpt), serve=ServeConfig.from_dict(
+            {**LEGACY_SERVE, "buckets": [[2, 8, 8]], "page_size": 4}
+        ), )
+    assert engine.checkpoint_path == str(ckpt)
+    assert engine.page_count() == 2 * 4  # slots x pages-per-slot
+    telemetry.start()
+
+
+SERVE_CELLS = sorted(
+    p.name for p in (REPO / "benchmarks" / "workloads").glob("*.json")
+    if "serve" in json.loads(p.read_text())
+)
+
+
+@pytest.mark.parametrize("block", ["serve", "rehearse.serve"])
+@pytest.mark.parametrize("cell", SERVE_CELLS)
+def test_accepted_serve_cells_load_as_they_are(cell, block):
+    """The benchmark's workload files may not change, and name the two
+    retired keys at their surviving values: each ``serve`` block still
+    loads, and every other key lands on a field (none is dropped in
+    silence, which would run the cell some other way than it says)."""
+    import dataclasses
+
+    data = json.loads((REPO / "benchmarks" / "workloads" / cell).read_text())
+    section = data["serve"] if block == "serve" else data["rehearse"]["serve"]
+    if block == "serve":
+        assert section["scheduler"] == "slots"
+        assert section["kv_layout"] == "paged"
+    cfg = ServeConfig.from_dict(section)
+    fields = {f.name for f in dataclasses.fields(ServeConfig)}
+    for key, value in section.items():
+        if key in ("scheduler", "kv_layout"):
+            continue
+        assert key in fields, f"{cell} {block}.{key} is dropped"
+        assert getattr(cfg, key) == value
 
 
 def test_config_embeds_and_roundtrips():

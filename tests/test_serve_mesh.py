@@ -48,8 +48,8 @@ ROWS = [
 def mesh_engine(mesh=None, page_size=4, weights="fsdp", **overrides):
     serve = ServeConfig(**{
         "buckets": BUCKETS, "max_queue": 64, "request_timeout": 30.0,
-        "scheduler": "slots", "slots": 4, "kv_layout": "paged",
-        "page_size": page_size, "mesh": mesh, "mesh_weights": weights,
+        "slots": 4, "page_size": page_size, "mesh": mesh,
+        "mesh_weights": weights,
         **overrides,
     })
     return InferenceEngine(TRLConfig.from_dict(tiny_config_dict()),
@@ -267,8 +267,8 @@ def test_hot_swap_under_load_mesh(serve_mesh_devices, tmp_path):
 
     registry = telemetry.start().registry
     serve = ServeConfig(buckets=BUCKETS, max_queue=64,
-                        request_timeout=30.0, scheduler="slots", slots=4,
-                        kv_layout="paged", page_size=4, mesh={"tp": 2})
+                        request_timeout=30.0, slots=4,
+                        page_size=4, mesh={"tp": 2})
     engine = InferenceEngine.from_checkpoint(
         os.path.join(run, "step_1"), serve=serve
     )
@@ -292,8 +292,7 @@ def test_hot_swap_under_load_mesh(serve_mesh_devices, tmp_path):
         # cross-version parity bar: a SINGLE-DEVICE engine from step_2
         oracle = InferenceEngine.from_checkpoint(
             os.path.join(run, "step_2"),
-            serve=ServeConfig(buckets=BUCKETS, scheduler="slots",
-                              slots=4, kv_layout="paged", page_size=4),
+            serve=ServeConfig(buckets=BUCKETS, slots=4, page_size=4),
         )
         out = direct_generate(oracle, ROWS[:2], (2, 8, 8),
                               gen_size=MAX_NEW)
@@ -327,8 +326,8 @@ def test_streaming_reload_is_partial_and_sharded(serve_mesh_devices,
     get_model(cfg.model.model_type)(cfg).save(os.path.join(run, "step_1"))
 
     telemetry.start()
-    serve = ServeConfig(buckets=BUCKETS, scheduler="slots", slots=4,
-                        kv_layout="paged", page_size=4, mesh={"tp": 2})
+    serve = ServeConfig(buckets=BUCKETS, slots=4,
+                        page_size=4, mesh={"tp": 2})
     engine = InferenceEngine.from_checkpoint(
         os.path.join(run, "step_1"), serve=serve
     )

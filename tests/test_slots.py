@@ -1,10 +1,10 @@
-"""Continuous-batching slot-scheduler tests (trlx_tpu/serve/slots +
-models/generation slot primitives): device-level prefill/decode-step
-parity against one-shot ``generate()``, step-level harvest + immediate
-slot reuse mid-decode (the acceptance e2e), zero steady-state
-recompiles, the ``serve_admit`` chaos containment paths, the HTTP
-surface under ``serve.scheduler: slots``, and the slow-marked
-mixed-length soak (zero recompiles, zero slot leaks).
+"""Continuous-batching slot-scheduler tests (trlx_tpu/serve/slots):
+step-level harvest + immediate slot reuse mid-decode (the acceptance
+e2e) with parity against one-shot ``generate()``, zero steady-state
+recompiles, the ``serve_admit`` chaos containment paths, sampling, the
+HTTP surface, and the slow-marked mixed-length soak (zero recompiles,
+zero slot leaks). The device primitives' parity and the prefix cache
+get their own pass in test_paged.py.
 """
 
 import json
@@ -21,9 +21,8 @@ from trlx_tpu import telemetry
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.models.generation import (
     _segments_of,
-    decode_step,
     generate,
-    init_slot_pool,
+    init_page_pool,
     init_slot_state,
     prefill_into_slots,
 )
@@ -32,16 +31,12 @@ from trlx_tpu.serve.slots import SlotScheduler
 from trlx_tpu.supervisor import RunSupervisor, chaos
 from test_serve import tiny_config_dict
 
-# pinned to the CONTIGUOUS layout: this module is the PR-5 pool's
-# coverage (the serve.kv_layout: contiguous A/B fallback); the paged
-# pool + radix prefix cache get their own full pass in test_paged.py
 SERVE_SLOTS = ServeConfig(
     buckets=[[2, 8, 8], [4, 8, 8], [4, 16, 8]],
     max_queue=64,
     request_timeout=30.0,
-    scheduler="slots",
     slots=4,
-    kv_layout="contiguous",
+    page_size=4,  # divides every bucket's prompt and prompt + gen
 )
 
 
@@ -86,83 +81,28 @@ def direct_generate(engine, rows, bucket, gen_size=8):
 # --------------------------------------------------------------------- #
 
 
-def test_slot_primitives_parity_with_staggered_admission(engine):
-    """Greedy slot decode must emit tokens bit-identical to one-shot
-    generate() per row — including a row ADMITTED MID-DECODE into a
-    freshly built pool (the scheduling move the pool exists for) and a
-    left-padded prompt."""
-    spec = engine.spec
-    cfg = engine._gen_base._replace(gen_size=8)
-    _, seg_sizes = _segments_of(engine.blocks)
-    S, T = 3, 16
-    pool = init_slot_pool(spec, seg_sizes, S, T)
-    state = init_slot_state(S, T, spec.vocab_size)
-
-    pf = jax.jit(
-        lambda pool, st, t, m, sid, mn: prefill_into_slots(
-            spec, engine.blocks, engine.embed, engine.ln_f, pool, st,
-            t, m, sid, mn, compute_dtype=jnp.float32,
-        )
-    )
-    sf = jax.jit(
-        lambda pool, st, seed: decode_step(
-            spec, engine.blocks, engine.embed, engine.ln_f, pool, st,
-            seed, cfg, compute_dtype=jnp.float32,
-        )
-    )
-
-    rows = [[3, 1, 4, 1, 5], [9, 2, 6], [5, 3, 5, 8, 9, 7, 9, 3]]
-    tokens, mask = engine.pad_batch(rows[:2], (2, 8, 0))
-    # slots out of order + one filler at the drop sentinel
-    pool, state = pf(
-        pool, state, np.vstack([tokens, tokens[:1]]),
-        np.vstack([mask, mask[:1]]),
-        np.array([2, 0, S], np.int32), np.array([8, 8, 1], np.int32),
-    )
-    got = {0: [], 1: [], 2: []}
-    for step in range(3):
-        pool, state, tok, em, _ = sf(pool, state, np.int32(step))
-        tok, em = np.asarray(tok), np.asarray(em)
-        for s in (2, 0):
-            if em[s]:
-                got[s].append(int(tok[s]))
-    # admit row 3 into slot 1 while the others are mid-decode
-    t3, m3 = engine.pad_batch(rows[2:], (2, 8, 0))
-    pool, state = pf(
-        pool, state, t3, m3, np.array([1, S], np.int32),
-        np.array([8, 1], np.int32),
-    )
-    for step in range(3, 14):
-        pool, state, tok, em, _ = sf(pool, state, np.int32(step))
-        tok, em = np.asarray(tok), np.asarray(em)
-        for s in (2, 0, 1):
-            if em[s]:
-                got[s].append(int(tok[s]))
-
-    oracle = direct_generate(engine, rows, (4, 8, 8))
-    for i, slot in enumerate((2, 0, 1)):
-        assert got[slot] == engine.depad_row(oracle, i, 8), (
-            f"slot {slot} (row {i}) diverged from one-shot generate()"
-        )
-
-
 def test_prefill_drop_sentinel_touches_nothing(engine):
-    """An all-sentinel prefill (what warmup runs) must leave pool and
-    lanes byte-identical — the mode='drop' contract."""
+    """An all-sentinel prefill (what warmup runs: sentinel slot ids AND
+    sentinel page tables) must leave pages and lanes byte-identical —
+    the mode='drop' contract. The pages start non-zero, so a write that
+    landed anywhere would show."""
     spec = engine.spec
     _, seg_sizes = _segments_of(engine.blocks)
-    S, T = 2, 16
-    pool = init_slot_pool(spec, seg_sizes, S, T)
-    state = init_slot_state(S, T, spec.vocab_size)
+    S, ps, max_pages, num_pages = 2, 4, 4, 8
+    pool = jax.tree_util.tree_map(
+        lambda x: x + 1, init_page_pool(spec, seg_sizes, num_pages, ps)
+    )
+    state = init_slot_state(S, max_pages * ps, spec.vocab_size, max_pages)
     tokens = np.zeros((2, 8), np.int32)
     mask = np.ones((2, 8), np.int32)
     new_pool, new_state = jax.jit(
-        lambda pool, st, t, m, sid, mn: prefill_into_slots(
+        lambda pool, st, t, m, sid, mn, pt: prefill_into_slots(
             spec, engine.blocks, engine.embed, engine.ln_f, pool, st,
-            t, m, sid, mn, compute_dtype=jnp.float32,
+            t, m, sid, mn, pt, ps, compute_dtype=jnp.float32,
         )
     )(pool, state, tokens, mask, np.full((2,), S, np.int32),
-      np.ones((2,), np.int32))
+      np.ones((2,), np.int32),
+      np.full((2, max_pages), num_pages, np.int32))
     for a, b in zip(jax.tree_util.tree_leaves((pool, state)),
                     jax.tree_util.tree_leaves((new_pool, new_state))):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -382,7 +322,7 @@ def test_replay_budget_exhaustion_is_typed_503(engine, fresh_registry,
     full ``serve.max_replays`` budget and completes with the typed
     ReplayExhausted (HTTP 503 + reason), not a raw ChaosError — and the
     engine still serves once the fault clears."""
-    from trlx_tpu.serve.batcher import ReplayExhausted
+    from trlx_tpu.serve.admission import ReplayExhausted
 
     chaos.configure("serve_decode:exc@*")
     try:
@@ -415,7 +355,7 @@ def test_replay_double_fault_falls_back_to_fail(engine, fresh_registry,
 
 
 # --------------------------------------------------------------------- #
-# HTTP surface under serve.scheduler: slots
+# HTTP surface
 # --------------------------------------------------------------------- #
 
 
@@ -482,6 +422,65 @@ def test_http_endpoint_on_slots_scheduler(engine, fresh_registry):
         }
     finally:
         server.stop()
+
+
+# --------------------------------------------------------------------- #
+# sampling (do_sample: true): the per-step stream
+# --------------------------------------------------------------------- #
+
+SAMPLED = [([3, 1, 4, 1, 5], 8), ([9, 2], 1), ([5, 3, 5, 8, 9, 7], 3),
+           ([2, 7, 1, 8], 8), ([6], 5), ([1, 6, 1, 8, 0, 3], 2)]
+
+
+@pytest.fixture(scope="module")
+def sampling_engine():
+    telemetry.start()
+    cfg = TRLConfig.from_dict(tiny_config_dict(do_sample=True))
+    return InferenceEngine(cfg, serve=SERVE_SLOTS)
+
+
+def sample_all(engine):
+    """Every SAMPLED request through a fresh 2-slot scheduler, queued
+    BEFORE the worker starts: admission order and the step each request
+    rides are then the queue's alone."""
+    s = SlotScheduler(engine, slots=2)
+    s.warmup()
+    reqs = [s.submit(toks, max_new_tokens=n) for toks, n in SAMPLED]
+    s.start()
+    try:
+        return [r.wait(timeout=60.0).result for r in reqs]
+    finally:
+        s.stop()
+
+
+def test_sampling_honours_vocab_budget_and_compiles_nothing(
+    sampling_engine, fresh_registry
+):
+    eos = sampling_engine.tokenizer.eos_token_id
+    results = sample_all(sampling_engine)
+    for (toks, max_new), out in zip(SAMPLED, results):
+        assert out and all(
+            0 <= t < sampling_engine.spec.vocab_size for t in out
+        )
+        assert len(out) == max_new or (len(out) < max_new
+                                       and out[-1] == eos)
+        assert eos not in out[:-1]
+    # sampled, not the greedy stream under another name
+    oracle = direct_generate(
+        sampling_engine, [t for t, _ in SAMPLED[:4]], (4, 8, 8)
+    )
+    greedy = [sampling_engine.depad_row(oracle, i, n)
+              for i, (_, n) in enumerate(SAMPLED[:4])]
+    assert results[:4] != greedy
+    assert fresh_registry.counters.get("compile/recompiles", 0.0) == 0.0
+    assert fresh_registry.counters["serve/responses"] == len(SAMPLED)
+
+
+def test_sampling_is_reproducible_for_one_arrival_order(sampling_engine):
+    """The stream is per STEP (``serve.seed`` + the step's counter): the
+    same requests in the same order through a fresh scheduler ride the
+    same steps and draw the same tokens."""
+    assert sample_all(sampling_engine) == sample_all(sampling_engine)
 
 
 # --------------------------------------------------------------------- #

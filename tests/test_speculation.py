@@ -32,8 +32,8 @@ def build_engine(**overrides):
     telemetry.start()
     serve = ServeConfig(**{
         "buckets": [[2, 8, 8], [4, 8, 8]], "max_queue": 64,
-        "request_timeout": 30.0, "scheduler": "slots", "slots": 4,
-        "kv_layout": "paged", "page_size": 4,
+        "request_timeout": 30.0, "slots": 4,
+        "page_size": 4,
         "speculation": "lookup", "spec_k": 4, **overrides,
     })
     return InferenceEngine(TRLConfig.from_dict(tiny_config_dict()),
@@ -386,11 +386,6 @@ def test_draft_tier_parity_and_full_acceptance(fresh_registry):
 # --------------------------------------------------------------------- #
 
 
-def test_speculation_requires_paged_layout():
-    with pytest.raises(ValueError, match="speculation"):
-        build_engine(kv_layout="contiguous", page_size=64)
-
-
 def test_draft_requires_checkpoint():
     with pytest.raises(ValueError, match="spec_draft_checkpoint"):
         build_engine(speculation="draft")
@@ -398,8 +393,8 @@ def test_draft_requires_checkpoint():
 
 def test_speculation_requires_greedy():
     telemetry.start()
-    serve = ServeConfig(buckets=[[2, 8, 8]], scheduler="slots", slots=4,
-                        kv_layout="paged", page_size=4,
+    serve = ServeConfig(buckets=[[2, 8, 8]], slots=4,
+                        page_size=4,
                         speculation="lookup")
     with pytest.raises(ValueError, match="greedy"):
         InferenceEngine(

@@ -47,7 +47,6 @@ def test_lint_covers_whole_repo():
     the checked set."""
     prefixes = {t.split("/")[0] for t in TARGETS if "/" in t}
     assert {"trlx_tpu", "tests", "examples"} <= prefixes
-    assert "bench.py" in TARGETS
     assert "__graft_entry__.py" in TARGETS
     assert set(_BY_FILE) <= set(TARGETS)
 
@@ -101,4 +100,40 @@ def test_the_gone_rig_and_the_version_shims_stay_out():
             if rig.search(line) or (rel.suffix == ".py"
                                     and shims.search(line)):
                 hits.append(f"{rel}:{n}: {line.strip()[:120]}")
+    assert not hits, "\n" + "\n".join(hits)
+
+
+# --------------------------------------------------------------------- #
+# one serve path: what PR 31 took out stays out
+# --------------------------------------------------------------------- #
+
+#: the builders' record and the driver's may name what went (that is
+#: their job); nothing a reader would build on may
+_RECORDS = {"CHANGES.md", "PERF.md", "ROADMAP.md", "ISSUE.md",
+            "PERF_LEDGER.jsonl"}
+
+
+# spelled in pieces so this file passes its own scan
+@pytest.mark.parametrize("gone", [
+    "Micro" + "Batcher", "init_slot" + "_pool", "bench" + ".py",
+])
+def test_what_the_one_serve_path_replaced_is_named_nowhere(gone):
+    """The static scheduler, the contiguous pool and the old one-main()
+    benchmark are gone; a doc, comment or config that still names one
+    sends its reader looking for it."""
+    import re
+
+    # whole names: "benchmarks/run.py" and "microbench.py" pass
+    named = re.compile(r"(?<![\w/])" + re.escape(gone) + r"\b")
+    hits = []
+    for rel, path in _committable_files():
+        if str(rel) in _RECORDS:
+            continue
+        try:
+            text = path.read_text()
+        except UnicodeDecodeError:
+            continue
+        hits += [f"{rel}:{n}: {line.strip()[:120]}"
+                 for n, line in enumerate(text.split("\n"), 1)
+                 if named.search(line)]
     assert not hits, "\n" + "\n".join(hits)

@@ -208,7 +208,7 @@ def test_token_times_gaps_are_the_itl_observations(session):
 
 SERVE_PAGED = ServeConfig(
     buckets=[[2, 8, 8], [4, 8, 8]], max_queue=16, request_timeout=30.0,
-    scheduler="slots", slots=4, kv_layout="paged", page_size=4,
+    slots=4, page_size=4,
 )
 
 

@@ -77,7 +77,7 @@ _TIMED_BLOCKERS = ("join", "wait", "acquire")
 _ALWAYS_BLOCKERS = ("bounded_call", "urlopen")
 
 #: call-graph BFS depth bound — deep enough for any real chain here
-#: (handler -> server -> batcher -> runtime is 4), bounded so a cycle
+#: (handler -> server -> scheduler -> runtime is 4), bounded so a cycle
 #: in the (approximate) graph cannot spin
 _MAX_DEPTH = 24
 

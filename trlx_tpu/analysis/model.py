@@ -27,7 +27,7 @@ from typing import Dict, Iterable, List, Optional, Set, Tuple
 #: TARGETS); fixture snippets under tests/lint_fixtures/ are planted-bad
 #: by design and excluded everywhere
 TARGET_ROOTS = ("trlx_tpu", "tests", "examples")
-TARGET_FILES = ("bench.py", "__graft_entry__.py")
+TARGET_FILES = ("__graft_entry__.py",)
 EXCLUDE_PARTS = ("lint_fixtures", "__pycache__", "_scratch")
 
 #: the metric catalog the contract-sync rules check names against

@@ -104,8 +104,8 @@ class LazyLockRule(ClassRule):
         "threads hitting the None check together each construct a "
         "Lock and serialise against DIFFERENT objects — the exact bug "
         "serve/engine.py shipped (lock built lazily in decode() while "
-        "batcher.request_swap raced the same check from the reload "
-        "thread)"
+        "the scheduler's request_swap raced the same check from the "
+        "reload thread)"
     )
     hint = "construct the lock eagerly in __init__"
 

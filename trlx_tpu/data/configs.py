@@ -427,6 +427,12 @@ class TRLConfig:
 
     @classmethod
     def from_dict(cls, config: Dict[str, Any]) -> "TRLConfig":
+        if config.get("serve"):
+            # the serve: section is ``python -m trlx_tpu.serve``'s to
+            # read; a retired value in it is refused at load all the same
+            from trlx_tpu.serve.engine import refuse_retired
+
+            refuse_retired(config["serve"])
         return cls(
             ModelConfig.from_dict(config["model"]),
             TrainConfig.from_dict(config["train"]),

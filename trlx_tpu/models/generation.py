@@ -302,7 +302,7 @@ def generate(
         raise NotImplementedError(
             f"generate() runs every layer over one contiguous cache; arch "
             f"'{spec.arch}' mixes {spec.layer_pattern} layers and is served "
-            f"through the paged slot pool (serve.scheduler: slots)"
+            f"through the paged slot pool (trlx_tpu.serve.slots)"
         )
     if S > spec.n_positions:
         raise ValueError(
@@ -552,13 +552,14 @@ def generate(
 # from prefill through all gen_size steps, so a batch admits nothing until
 # every row is done and a finished row keeps paying full steps. The two
 # primitives below split that monolith for iteration-level scheduling
-# (Orca, Yu et al., OSDI '22) over a PERSISTENT device-resident slot pool
-# (the static-shape analogue of vLLM's block pool, Kwon et al., SOSP '23):
+# (Orca, Yu et al., OSDI '22) over a PERSISTENT device-resident page pool
+# (the static-shape rebuild of vLLM's PagedAttention allocator, Kwon et
+# al., SOSP '23):
 #
 # - ``prefill_into_slots``: one prompt-bucket forward writing each row's
-#   prompt KV into a named pool slot (scatter, ``mode="drop"`` so filler
-#   rows aimed at the out-of-bounds sentinel vanish) plus its first-step
-#   logits and per-slot lanes;
+#   prompt KV into the pages its table names (scatter, ``mode="drop"`` so
+#   filler rows aimed at the out-of-bounds sentinel vanish) plus its
+#   first-step logits and per-slot lanes;
 # - ``decode_step``: ONE token for all S slots — per-slot cache offsets,
 #   logical positions, finished/active lanes, per-request max_new caps —
 #   returning the emitted tokens to the host scheduler
@@ -571,19 +572,17 @@ def generate(
 # and zero recompiles. Numerics match generate() exactly for a row decoded
 # in isolation: masked (invalid) pool positions contribute exact zeros to
 # the attention softmax, so emitted tokens are bit-identical under greedy
-# decode — the parity contract tests/test_slots.py pins.
+# decode — the parity contract tests/test_paged.py pins, over a sweep of
+# page sizes and prefix splits.
 #
-# Both primitives also run against a PAGED pool (init_page_pool +
-# SlotState.pages page tables, block_apply's paged mode — the
-# static-shape rebuild of vLLM's PagedAttention allocator): KV lives in
-# fixed-size pages shared across slots, a slot's logical position p maps
-# through its table to (page, offset), and prefill can start at a
-# nonzero page-aligned offset with the committed prefix gathered as
-# attention context (prefix_context=True — the radix-prefix-cache path,
-# trlx_tpu.serve.paged). Page tables are DATA, not shape, so the
-# executable count and the zero-recompile contract are unchanged; the
-# parity contract extends to any page size / prefix split
-# (tests/test_paged.py pins the sweep).
+# The pool is PAGED (init_page_pool + SlotState.pages page tables,
+# block_apply's paged mode): KV lives in fixed-size pages shared across
+# slots, a slot's logical position p maps through its table to (page,
+# offset), and prefill can start at a nonzero page-aligned offset with
+# the committed prefix gathered as attention context
+# (prefix_context=True — the radix-prefix-cache path,
+# trlx_tpu.serve.paged). Page tables are DATA, not shape, so they cost no
+# executable and no recompile.
 
 
 class SlotState(NamedTuple):
@@ -598,12 +597,10 @@ class SlotState(NamedTuple):
     ``logits`` [S, V] carries each slot's next-token distribution between
     programs (written by prefill, advanced by every step).
 
-    ``pages`` [S, max_pages] int32 is the per-slot page table under the
-    PAGED pool layout (``serve.kv_layout: paged``): entry j names the
-    physical pool page holding the slot's logical positions
+    ``pages`` [S, max_pages] int32 is the per-slot page table: entry j
+    names the physical pool page holding the slot's logical positions
     [j * page_size, (j+1) * page_size); unallocated entries carry the
     out-of-bounds :data:`PAGE_SENTINEL` so device scatters drop them.
-    ``None`` selects the contiguous per-slot layout (the PR-5 pool).
     """
 
     valid: jnp.ndarray  # [S, T] int32
@@ -614,7 +611,7 @@ class SlotState(NamedTuple):
     active: jnp.ndarray  # [S] bool
     finished: jnp.ndarray  # [S] bool
     logits: jnp.ndarray  # [S, V] float32
-    pages: Optional[jnp.ndarray] = None  # [S, max_pages] int32 | None
+    pages: jnp.ndarray  # [S, max_pages] int32
 
 
 #: page-table entry meaning "no page here": comfortably past any real
@@ -624,10 +621,10 @@ PAGE_SENTINEL = 2**30
 
 
 def init_slot_state(num_slots: int, buffer_len: int, vocab_size: int,
-                    max_pages: Optional[int] = None) -> SlotState:
+                    max_pages: int) -> SlotState:
     """An all-free pool state: nothing active, everything finished (so a
-    decode step over an empty pool emits nothing). ``max_pages`` builds
-    the paged variant (all page-table entries at the drop sentinel)."""
+    decode step over an empty pool emits nothing), every page-table
+    entry at the drop sentinel."""
     S = num_slots
     return SlotState(
         valid=jnp.zeros((S, buffer_len), jnp.int32),
@@ -638,9 +635,7 @@ def init_slot_state(num_slots: int, buffer_len: int, vocab_size: int,
         active=jnp.zeros((S,), bool),
         finished=jnp.ones((S,), bool),
         logits=jnp.zeros((S, vocab_size), jnp.float32),
-        pages=None if max_pages is None else jnp.full(
-            (S, max_pages), PAGE_SENTINEL, jnp.int32
-        ),
+        pages=jnp.full((S, max_pages), PAGE_SENTINEL, jnp.int32),
     )
 
 
@@ -655,26 +650,11 @@ def init_slot_state(num_slots: int, buffer_len: int, vocab_size: int,
 # gpt-j-6B decode step). tests/test_paged.py pins the structure.
 
 
-def init_slot_pool(spec: ModelSpec, seg_sizes, num_slots: int,
-                   buffer_len: int, cache_dtype=jnp.bfloat16):
-    """Contiguous pool: per segment, per layer, (k, v) buffers
-    [S, T, Hkv, hd] — one region per slot."""
-    shape = (num_slots, buffer_len, spec.kv_heads, spec.head_dim)
-    return tuple(
-        tuple(
-            (jnp.zeros(shape, cache_dtype), jnp.zeros(shape, cache_dtype))
-            for _ in range(size)
-        )
-        for size in seg_sizes
-    )
-
-
 def init_page_pool(spec: ModelSpec, seg_sizes, num_pages,
                    page_size: int, cache_dtype=jnp.bfloat16):
     """PAGE pool: per segment, per layer, (k, v) pages [num_pages,
-    page_size, Hkv, hd] — the block-granular replacement for
-    init_slot_pool: HBM is sized in pages shared by all slots, not slots
-    x worst-case length. The int8 tier makes each of k/v a ``(codes,
+    page_size, Hkv, hd]: HBM is sized in pages shared by all slots, not
+    slots x worst-case length. The int8 tier makes each of k/v a ``(codes,
     scales)`` pair (transformer.init_paged_kv_cache).
 
     ``num_pages`` is one count, or ``{class: count}`` for a model whose
@@ -831,37 +811,37 @@ def prefill_into_slots(
     ln_f: Params,
     pool,
     state: SlotState,
-    prompt_tokens: jnp.ndarray,  # [Bp, P] left-padded
+    prompt_tokens: jnp.ndarray,  # [Bp, P] RIGHT-padded
     prompt_mask: jnp.ndarray,  # [Bp, P]
     slot_ids: jnp.ndarray,  # [Bp] int32; == num_slots -> dropped filler
     max_new: jnp.ndarray,  # [Bp] int32 per-request cap
+    page_tables: jnp.ndarray,  # [Bp, max_pages] int32
+    page_size: int,
     compute_dtype=jnp.bfloat16,
     attention_fn=attention_scores,
-    page_tables: Optional[jnp.ndarray] = None,  # [Bp, max_pages] int32
-    page_size: Optional[int] = None,
     start: Optional[jnp.ndarray] = None,  # [Bp] int32 page-aligned prefix
     prefix_context: bool = False,
     window_tables: Optional[jnp.ndarray] = None,  # [Bp, Rp] int32
     window_base: Optional[jnp.ndarray] = None,  # [Bp] int32 logical page
 ):
-    """Write a prompt bucket's KV + first-step logits into pool slots.
+    """Write a prompt bucket's KV + first-step logits into the page pool:
+    suffix forward + block-scatter through per-row ``page_tables``; state
+    rows (valid/offset/pos/pages/logits) scattered to ``slot_ids``.
 
-    Runs the exact prefill generate() runs (same ops, local [Bp, P] cache
-    buffer at offset 0), then scatters cache/state rows to ``slot_ids``.
-    Filler rows carry ``slot_ids == num_slots`` (one past the end):
-    every scatter here uses ``mode="drop"``, so they compile the bucket
-    shape without touching any real slot — which is also how warmup
-    compiles each bucket against the live pool for free.
+    Filler rows carry ``slot_ids == num_slots`` (one past the end) and
+    sentinel page tables: every scatter here uses ``mode="drop"``, so
+    they compile the bucket shape without touching any real slot or page
+    — which is also how warmup compiles each bucket against the live
+    pool for free.
 
-    ``page_tables`` switches to the PAGED pool layout: ``pool`` is then
-    the global page pool (init_page_pool) and ``prompt_tokens`` /
-    ``prompt_mask`` must be RIGHT-padded — under right padding a slot's
-    buffer position equals its logical token position, so two requests
-    sharing a token prefix share identical page CONTENT, which is what
-    makes radix prefix caching content-addressable (KV of a causal model
-    depends only on the tokens before it, not on pad placement; masked
-    pad positions contribute exactly zero either way, so greedy outputs
-    stay bit-identical to one-shot left-padded ``generate()``).
+    ``prompt_tokens`` / ``prompt_mask`` are RIGHT-padded — under right
+    padding a slot's buffer position equals its logical token position,
+    so two requests sharing a token prefix share identical page CONTENT,
+    which is what makes radix prefix caching content-addressable (KV of
+    a causal model depends only on the tokens before it, not on pad
+    placement; masked pad positions contribute exactly zero either way,
+    so greedy outputs stay bit-identical to one-shot left-padded
+    ``generate()``).
 
     ``start`` ([Bp] int32, page-aligned, default zeros) is each row's
     already-committed prefix length: the tokens passed in are only the
@@ -870,8 +850,9 @@ def prefill_into_slots(
     ``prefix_context=True`` the suffix attends to the committed prefix
     pages gathered from the pool (the ``prefill_suffix`` executable — a
     prefix hit skips the matched tokens' forward entirely); with
-    ``False`` (all-zero ``start``) attention stays local to the prompt,
-    which is cheaper and exactly mirrors the contiguous prefill.
+    ``False`` (all-zero ``start``) attention stays local to the prompt
+    (the exact prefill generate() runs, in a local [Bp, P] cache buffer
+    at offset 0), which is cheaper.
 
     A model with window layers keeps two classes of page and is always
     prefilled with ``prefix_context=True``: ``window_tables`` [Bp, Rp]
@@ -891,73 +872,8 @@ def prefill_into_slots(
         )
     segments, seg_sizes = _segments_of(blocks)
     prompt_mask = prompt_mask.astype(jnp.int32)
-    if page_tables is not None:
-        return _prefill_into_pages(
-            spec, segments, seg_sizes, embed, ln_f, pool, state,
-            prompt_tokens, prompt_mask, slot_ids, max_new, compute_dtype,
-            attention_fn, page_tables, page_size, start, prefix_context,
-            window_tables, window_base,
-        )
-    real_len = prompt_mask.sum(axis=-1)
-
-    cache_dtype = jax.tree_util.tree_leaves(pool)[0].dtype
-    cache_segs = [
-        init_kv_cache(spec, size, B, P, cache_dtype) for size in seg_sizes
-    ]
-    positions = positions_from_mask(prompt_mask)
-    h = embed_tokens(embed, spec, prompt_tokens, positions, compute_dtype)
-    bias = causal_mask_bias(prompt_mask)
-    for i, seg in enumerate(segments):
-        h, cache_segs[i] = apply_blocks_with_cache(
-            seg, cache_segs[i], spec, h, bias, positions,
-            cache_offset=jnp.int32(0), attention_fn=attention_fn,
-        )
-    with jax.named_scope("head"):
-        h_last = layer_norm(ln_f, h[:, -1:], spec.layer_norm_epsilon)
-        logits0 = project_logits(embed, spec, h_last)[:, 0]  # [Bp, V]
-
-    rows = slot_ids.astype(jnp.int32)
-    with jax.named_scope("kv_write"):
-        new_pool = tuple(
-            tuple(
-                (k.at[rows, :P].set(k_new[i], mode="drop"),
-                 v.at[rows, :P].set(v_new[i], mode="drop"))
-                for i, (k, v) in enumerate(seg_pool)
-            )
-            for seg_pool, (k_new, v_new) in zip(pool, cache_segs)
-        )
-
-    valid_rows = jnp.concatenate(
-        [prompt_mask, jnp.zeros((B, T - P), jnp.int32)], axis=1
-    )
-    new_state = SlotState(
-        valid=state.valid.at[rows].set(valid_rows, mode="drop"),
-        offset=state.offset.at[rows].set(P, mode="drop"),
-        pos=state.pos.at[rows].set(real_len, mode="drop"),
-        generated=state.generated.at[rows].set(0, mode="drop"),
-        max_new=state.max_new.at[rows].set(
-            jnp.clip(max_new.astype(jnp.int32), 0, T - P), mode="drop"
-        ),
-        active=state.active.at[rows].set(True, mode="drop"),
-        finished=state.finished.at[rows].set(False, mode="drop"),
-        logits=state.logits.at[rows].set(logits0, mode="drop"),
-    )
-    return new_pool, new_state
-
-
-def _prefill_into_pages(
-    spec, segments, seg_sizes, embed, ln_f, pool, state,
-    prompt_tokens, prompt_mask, slot_ids, max_new, compute_dtype,
-    attention_fn, page_tables, page_size, start, prefix_context,
-    window_tables=None, window_base=None,
-):
-    """Paged half of prefill_into_slots (see its docstring): suffix
-    forward + block-scatter through per-row page tables; state rows
-    (valid/offset/pos/pages/logits) scattered to ``slot_ids``."""
-    B, P = prompt_tokens.shape
-    T = state.valid.shape[1]
-    if page_size is None or page_size <= 0:
-        raise ValueError(f"paged prefill needs page_size, got {page_size}")
+    if page_size <= 0:
+        raise ValueError(f"prefill needs a page_size >= 1, got {page_size}")
     max_pages = page_tables.shape[1]
     if max_pages * page_size != T:
         raise ValueError(
@@ -1011,8 +927,8 @@ def _prefill_into_pages(
             token_mask=prompt_mask > 0, moe_stats=moe_stats,
         )
     elif not prefix_context:
-        # no committed prefix: local causal prefill (the exact ops the
-        # contiguous path runs), then one block-scatter into the pages.
+        # no committed prefix: local causal prefill (the exact ops
+        # generate() runs), then one block-scatter into the pages.
         # int8 tier: the LOCAL buffer stays full-precision in the compute
         # dtype and quantization happens once at the scatter — the same
         # source dtype block_apply's decode-time quantize sees, so page
@@ -1144,10 +1060,10 @@ def verify_step(
     (``serve.spec_k``) and this is ONE executable next to
     ``decode_step``, so ``compile/recompiles == 0`` survives.
 
-    Paged layout only (``state.pages`` required): the candidate window
-    may run past the slot buffer for rows near their budget end, so the
-    write path runs through a sentinel-extended page table — overflow
-    positions drop instead of clamping into the last real page. Greedy
+    The candidate window may run past the slot buffer for rows near
+    their budget end, so the write path runs through a sentinel-extended
+    page table — overflow positions drop instead of clamping into the
+    last real page. Greedy
     sampling only (the host gates speculation on ``do_sample=False``);
     the jnp attention path only (the pallas decode kernel is T==1).
 
@@ -1155,11 +1071,6 @@ def verify_step(
     the host appends ``cand[s, :counts[s]]`` per live slot; plain steps
     are the ``counts <= 1`` degenerate case of the same harvest shape.
     """
-    if state.pages is None:
-        raise ValueError(
-            "verify_step requires the paged pool layout (state.pages); "
-            "serve.speculation is gated on serve.kv_layout: paged"
-        )
     S, K = proposals.shape
     Tc = K + 1  # candidates forwarded: the free token + K proposals
     T = state.valid.shape[1]
@@ -1377,23 +1288,20 @@ def decode_step(
     )
     pos = state.pos[:, None]  # [S, 1] logical position of this token
     h = embed_tokens(embed, spec, tok[:, None], pos, compute_dtype)
-    paged = state.pages is not None
-    if paged:
-        # gate writes through the page table: non-emitting slots (free,
-        # finished, or harvested-awaiting-reuse) aim at the sentinel so
-        # their scatter drops — a harvested slot's pages may already
-        # belong to ANOTHER slot, so the old "write into your own row"
-        # harmlessness argument no longer holds
-        num_pages, page_size = _pool_page_geometry(pool)
-        pt_step = jnp.where(
-            emitted[:, None], state.pages, jnp.int32(num_pages)
-        )
+    # gate writes through the page table: non-emitting slots (free,
+    # finished, or harvested-awaiting-reuse) aim at the sentinel so their
+    # scatter drops — a harvested slot's pages may already belong to
+    # ANOTHER slot
+    num_pages, page_size = _pool_page_geometry(pool)
+    pt_step = jnp.where(
+        emitted[:, None], state.pages, jnp.int32(num_pages)
+    )
     moe_stats = []
     if _mixed_layers(spec):
-        if not paged or window_table is None:
+        if window_table is None:
             raise ValueError(
-                "a model with window layers decodes over the paged pool "
-                "with its window-class ring table"
+                "a model with window layers decodes with its "
+                "window-class ring table"
             )
         R = window_table.shape[1]
         # ring entry r, offset o holds the newest position p <= cur of
@@ -1428,10 +1336,8 @@ def decode_step(
         new_pool, h = _apply_layers_with_pool(
             spec, segments, seg_sizes, pool, h,
             mask_bias=bias, positions=pos, cache_row_offsets=state.offset,
-            page_table=pt_step if paged else None,
-            page_size=page_size if paged else None,
-            attention_fn=attention_fn,
-            paged_decode_fn=paged_decode_fn if paged else None,
+            page_table=pt_step, page_size=page_size,
+            attention_fn=attention_fn, paged_decode_fn=paged_decode_fn,
         )
     with jax.named_scope("head"):
         h_normed = layer_norm(ln_f, h, spec.layer_norm_epsilon)

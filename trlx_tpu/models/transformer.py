@@ -449,33 +449,18 @@ def _paged_read(new_cache, page_table, page_size, dtype):
                 v_ctx.reshape(B, max_pages * page_size, *tail))
 
 
-def _slot_cache(kv_cache, k, v, cache_offset, cache_row_offsets):
-    """Cache writer, contiguous: one buffer slot for every row
-    (``cache_offset``) or a slot per row (``cache_row_offsets``, T == 1).
-    The reader is the whole buffer."""
-    B, T = k.shape[:2]
+def _slot_cache(kv_cache, k, v, cache_offset):
+    """Cache writer, contiguous (``generate()``'s own buffer): the same
+    buffer slot ``cache_offset`` for every row. The reader is the whole
+    buffer."""
     k_cache, v_cache = kv_cache
     with jax.named_scope("kv_write"):
-        if cache_row_offsets is not None:
-            if T != 1:
-                raise ValueError(
-                    f"cache_row_offsets (per-row cache writes) "
-                    f"requires a single fresh token per row, got T={T}"
-                )
-            rows = jnp.arange(B)
-            k_full = k_cache.at[rows, cache_row_offsets].set(
-                k[:, 0].astype(k_cache.dtype), mode="drop"
-            )
-            v_full = v_cache.at[rows, cache_row_offsets].set(
-                v[:, 0].astype(v_cache.dtype), mode="drop"
-            )
-        else:
-            k_full = jax.lax.dynamic_update_slice_in_dim(
-                k_cache, k.astype(k_cache.dtype), cache_offset, axis=1
-            )
-            v_full = jax.lax.dynamic_update_slice_in_dim(
-                v_cache, v.astype(v_cache.dtype), cache_offset, axis=1
-            )
+        k_full = jax.lax.dynamic_update_slice_in_dim(
+            k_cache, k.astype(k_cache.dtype), cache_offset, axis=1
+        )
+        v_full = jax.lax.dynamic_update_slice_in_dim(
+            v_cache, v.astype(v_cache.dtype), cache_offset, axis=1
+        )
     return k_full, v_full
 
 
@@ -585,21 +570,17 @@ def block_apply(
     and attention runs q against the full buffer (decode mode: T is the
     fresh suffix, typically 1).
 
-    `cache_row_offsets` ([B] int32) switches the write to PER-ROW buffer
-    positions — the slot-pool decode mode (trlx_tpu.models.generation
-    `decode_step`), where each slot advances at its own pace. Requires
-    T == 1 (one fresh token per row); rows whose offset is out of bounds
-    are dropped (``mode="drop"``), which is how free/finished slots
-    no-op. `cache_offset` is ignored in this mode.
-
     `page_table` ([B, max_pages] int32) switches to the PAGED pool
-    layout: `kv_cache` is then the global page pool (k_pages, v_pages)
-    [num_pages, page_size, Hkv, hd] shared by all rows, and each row's
-    logical buffer position p lives at physical
+    layout — the serve programs' (trlx_tpu.models.generation
+    `prefill_into_slots` / `decode_step` / `verify_step`), where each
+    slot advances at its own pace: `kv_cache` is then the global page
+    pool (k_pages, v_pages) [num_pages, page_size, Hkv, hd] shared by
+    all rows, and each row's logical buffer position p lives at physical
     ``(page_table[b, p // page_size], p % page_size)``. Fresh K/V for
     token j of row b is scattered to logical position
-    ``cache_row_offsets[b] + j`` (T >= 1 is allowed here — the
-    prefix-suffix prefill path writes many tokens per row); entries whose
+    ``cache_row_offsets[b] + j`` ([B] int32, per-row; T >= 1 — the
+    prefix-suffix prefill path writes many tokens per row;
+    `cache_offset` is ignored in this mode); entries whose
     page id is out of bounds (the host allocator's sentinel) or whose
     logical position exceeds the table extent are dropped, which is both
     the filler-row warmup trick and the finished-slot write gate.
@@ -695,9 +676,13 @@ def block_apply(
                                        q.dtype)
             k_ctx, v_ctx = expand_kv(k_ctx), expand_kv(v_ctx)
     elif kv_cache is not None:
-        k_full, v_full = _slot_cache(
-            kv_cache, k, v, cache_offset, cache_row_offsets
-        )
+        if cache_row_offsets is not None:
+            raise ValueError(
+                "cache_row_offsets (per-row cache writes) go through a "
+                "page_table; a contiguous cache is written at the one "
+                "cache_offset"
+            )
+        k_full, v_full = _slot_cache(kv_cache, k, v, cache_offset)
         new_cache = (k_full, v_full)
         with jax.named_scope("kv_read"):
             k_ctx = expand_kv(k_full.astype(q.dtype))
@@ -783,10 +768,6 @@ _NEW_ARCH_RUNS_UNDER = {
                               "table; use speculation: off"),
     "mesh": ((None,), "there is no expert axis in serve/layouts.py; "
                       "serve one chip's share on the default mesh"),
-    "scheduler": (("slots",), "the static scheduler decodes over one "
-                              "contiguous cache; use scheduler: slots"),
-    "kv_layout": (("paged",), "window layers keep a second class of "
-                              "page; use kv_layout: paged"),
 }
 
 

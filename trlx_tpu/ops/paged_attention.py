@@ -4,9 +4,9 @@ The jnp paged decode path (transformer.block_apply's paged mode) is a
 memory-bound three-step: gather every slot's K/V pages back into logical
 order ([S, max_pages * page_size, Hkv, hd] materialized in HBM), score
 the single fresh query row against it, throw the gathered copy away.
-At decode batch sizes that gather dominates the step — BENCH_r04/r05
-put decode MFU at ~0.20 against 0.60+ for training. This module removes
-it, following the PagedAttention (vLLM) design on the TPU grid model:
+At decode batch sizes that gather is a large share of the step's
+attention (PERF.md section 5 has the chip's breakdown). This module
+removes it, following the PagedAttention (vLLM) design on the TPU grid model:
 
 - Grid ``(slot, page)``; the per-slot page table rides in as a
   **scalar-prefetch** operand (host int32 — data, never shape), so each

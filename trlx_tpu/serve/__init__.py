@@ -11,27 +11,22 @@ local HTTP endpoint in one command::
 Three layers (docs/source/serving.rst):
 
 - :class:`InferenceEngine` (serve.engine) — restores the policy (params
-  only; ref branch / value head / optimizer state stripped), precompiles
-  the jitted KV-cache ``generate()`` over a static (batch, prompt_len,
-  gen_len) **bucket lattice** through ``utils.aotjit`` so steady-state
-  requests never recompile (``compile/recompiles == 0`` is the serving
-  invariant);
-- :class:`SlotScheduler` (serve.slots, ``serve.scheduler: slots`` — the
-  default) — continuous batching: step-level scheduling over a
-  persistent device-resident KV **slot pool**; at every decode step
-  finished rows (EOS / per-request ``max_new_tokens``) are harvested,
-  their slots freed immediately, and queued requests admitted via
-  bucketed prefill — short requests never wait for long ones. Under
-  ``serve.kv_layout: paged`` (default) the pool is block-granular
-  (fixed-size KV pages + per-slot page tables, host free-list
-  allocator) with radix-tree **prefix caching** (serve.paged):
-  admission reserves pages for each request's own length instead of
-  the worst case, and prompts sharing a committed prefix skip
-  re-prefilling it;
-- :class:`MicroBatcher` (serve.batcher, ``serve.scheduler: static``) —
-  the PR-4 batch-to-completion micro-batcher kept for A/B: requests
-  round up to a compiled shape class and coalesce until the bucket
-  fills or ``max_wait_ms`` passes, with ``max_queue`` admission control;
+  only; ref branch / value head / optimizer state stripped) and holds
+  the static (batch, prompt_len, gen_len) **bucket lattice** every
+  compiled shape comes from, so steady-state requests never recompile
+  (``compile/recompiles == 0`` is the serving invariant);
+- :class:`SlotScheduler` (serve.slots) — continuous batching:
+  step-level scheduling over a persistent device-resident KV **slot
+  pool**; at every decode step finished rows (EOS / per-request
+  ``max_new_tokens``) are harvested, their slots freed immediately, and
+  queued requests admitted via bucketed prefill — short requests never
+  wait for long ones. The pool is block-granular (fixed-size KV pages +
+  per-slot page tables, host free-list allocator) with radix-tree
+  **prefix caching** (serve.paged): admission reserves pages for each
+  request's own length instead of the worst case, and prompts sharing a
+  committed prefix skip re-prefilling it. What a request is and the
+  typed ways one is refused (``max_queue`` admission control, tenant
+  quotas) live in serve.admission;
 - :class:`InferenceServer` (serve.server) — stdlib ThreadingHTTPServer
   JSON API (``POST /generate``, ``GET /healthz``, ``GET /metrics``)
   wired into the telemetry registry, the supervisor watchdog
@@ -40,7 +35,7 @@ Three layers (docs/source/serving.rst):
   ``serve_request`` chaos seams.
 """
 
-from trlx_tpu.serve.batcher import MicroBatcher, QueueFull, Request  # noqa: F401
+from trlx_tpu.serve.admission import QueueFull, Request  # noqa: F401
 from trlx_tpu.serve.engine import InferenceEngine, ServeConfig  # noqa: F401
 from trlx_tpu.serve.server import InferenceServer  # noqa: F401
 from trlx_tpu.serve.slots import SlotScheduler  # noqa: F401
@@ -48,7 +43,6 @@ from trlx_tpu.serve.slots import SlotScheduler  # noqa: F401
 __all__ = [
     "InferenceEngine",
     "InferenceServer",
-    "MicroBatcher",
     "QueueFull",
     "Request",
     "ServeConfig",

@@ -4,7 +4,7 @@ The config (architecture, tokenizer, sampling) defaults to the one the
 trainer embedded in the checkpoint's meta.json, so the minimal launch is
 just ``--checkpoint``; ``--config`` overrides it, and the ``serve:``
 section of that YAML (or the flags below, which win) sizes the bucket
-lattice and the batcher. See docs/source/serving.rst.
+lattice and the scheduler. See docs/source/serving.rst.
 """
 
 import argparse
@@ -64,44 +64,30 @@ def build_parser() -> argparse.ArgumentParser:
                         "'8x32x16,16x64x32' (overrides the serve: section)")
     p.add_argument("--host", default=None)
     p.add_argument("--port", type=int, default=None)
-    p.add_argument("--max-wait-ms", type=float, default=None,
-                   help="micro-batch coalescing deadline")
     p.add_argument("--max-queue", type=int, default=None,
                    help="admission-control queue bound (429 past it)")
     p.add_argument("--request-timeout", type=float, default=None,
                    help="per-request walltime bound (503 past it)")
     p.add_argument("--stall-timeout", type=float, default=None,
-                   help="watchdog budget per decoded batch/step (0 = off)")
-    p.add_argument("--scheduler", choices=("static", "slots"), default=None,
-                   help="decode driver: 'slots' = continuous batching "
-                        "over the persistent KV slot pool (default), "
-                        "'static' = PR-4 batch-to-completion A/B path")
+                   help="watchdog budget per admission/decode step "
+                        "(0 = off)")
     p.add_argument("--slots", type=int, default=None,
-                   help="slot-pool size for --scheduler slots "
-                        "(0 = largest compiled batch extent)")
-    p.add_argument("--kv-layout", choices=("paged", "contiguous"),
-                   default=None,
-                   help="slot-pool KV layout: 'paged' (default) = "
-                        "block-granular page pool + radix-tree prefix "
-                        "caching; 'contiguous' = one worst-case region "
-                        "per slot (the A/B fallback)")
+                   help="slot-pool size (0 = largest compiled batch "
+                        "extent)")
     p.add_argument("--page-size", type=int, default=None,
-                   help="tokens per KV page under --kv-layout paged "
-                        "(also the prefix-cache sharing granularity)")
+                   help="tokens per KV page (also the prefix-cache "
+                        "sharing granularity)")
     p.add_argument("--pages", type=int, default=None,
-                   help="page-pool size under --kv-layout paged "
-                        "(0 = slots x pages-per-slot capacity parity)")
+                   help="page-pool size (0 = slots x pages-per-slot)")
     p.add_argument("--attention", choices=("jnp", "pallas"), default=None,
-                   help="decode attention path under --kv-layout paged: "
-                        "'jnp' (default) = HBM gather + dense attention, "
-                        "the parity oracle; 'pallas' = the fused "
+                   help="decode attention path: 'jnp' (default) = HBM "
+                        "gather + dense attention, the parity oracle; 'pallas' = the fused "
                         "paged-attention kernel (page table scalar-"
                         "prefetched, online softmax in VMEM, greedy "
                         "bit-identical at bf16)")
     p.add_argument("--kv-dtype", choices=("bf16", "int8"), default=None,
-                   help="KV-page storage tier under --kv-layout paged: "
-                        "'int8' stores codes + per-(token, kv-head) f32 "
-                        "scales, ~1.9x pages per GB (lossy — greedy "
+                   help="KV-page storage tier: 'int8' stores codes + "
+                        "per-(token, kv-head) f32 scales, ~1.9x pages per GB (lossy — greedy "
                         "parity on tested traces, not exact logits)")
     p.add_argument("--weights-dtype", choices=("bf16", "int8"),
                    default=None,
@@ -151,7 +137,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "'draft' from a small draft model "
                         "(--spec-draft-checkpoint); greedy verification "
                         "keeps output bit-identical to 'off'. Requires "
-                        "--kv-layout paged and greedy decode")
+                        "greedy decode")
     p.add_argument("--spec-k", type=int, default=None,
                    help="proposed tokens verified per slot per "
                         "speculative step (static shape; 3-8 fits most "
@@ -184,13 +170,10 @@ def serve_config_from_args(args) -> ServeConfig:
     if args.mesh_weights is not None:
         cfg.mesh_weights = args.mesh_weights
     for flag, attr in (("host", "host"), ("port", "port"),
-                       ("max_wait_ms", "max_wait_ms"),
                        ("max_queue", "max_queue"),
                        ("request_timeout", "request_timeout"),
                        ("stall_timeout", "stall_timeout"),
-                       ("scheduler", "scheduler"),
                        ("slots", "slots"),
-                       ("kv_layout", "kv_layout"),
                        ("page_size", "page_size"),
                        ("pages", "pages"),
                        ("attention", "attention"),

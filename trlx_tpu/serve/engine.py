@@ -16,19 +16,22 @@ until this module the only consumer of either was the learn loop itself.
   ln_f) via the policy's own decode helpers; the reference branch and the
   value head are dropped, so steady-state HBM holds one policy, not the
   training triple.
-- **bucket lattice**: decode shapes are static under XLA, so the engine
-  precompiles ``generate()`` over a small lattice of
-  ``(batch, prompt_len, gen_len)`` buckets — each bucket gets its OWN
-  ``aot_jit`` wrapper (its own executable cache), so warming bucket N+1
-  is a first compile, not a steady-state miss, and ``compile/recompiles``
-  staying 0 is the serving invariant it already is for training.
-  :meth:`warmup` compiles every bucket up front; per-bucket first-call
-  latencies land apart from steady-state timings through the telemetry
-  tracer's existing first-call separation
-  (``compile/serve/decode_bBpPgG_first_s`` vs ``time/serve/decode_*``).
+- **bucket lattice**: decode shapes are static under XLA, so every
+  compiled shape comes from a small lattice of
+  ``(batch, prompt_len, gen_len)`` buckets. The slot scheduler
+  (trlx_tpu.serve.slots) compiles one prefill program per
+  ``(batch, prompt_len)`` class of it and one decode step for the whole
+  pool; each program gets its OWN ``aot_jit`` wrapper (its own
+  executable cache), so warming program N+1 is a first compile, not a
+  steady-state miss, and ``compile/recompiles`` staying 0 is the serving
+  invariant it already is for training.
+- **the oracle**: :meth:`InferenceEngine.decode` runs ``generate()``
+  over one bucket, batch to completion. It serves no traffic: the tests
+  compare the scheduler's tokens against it.
 
-Requests are shaped into buckets by :class:`trlx_tpu.serve.batcher`;
-the HTTP surface lives in :class:`trlx_tpu.serve.server`.
+What a request is lives in :mod:`trlx_tpu.serve.admission`; the
+scheduler in :mod:`trlx_tpu.serve.slots`; the HTTP surface in
+:class:`trlx_tpu.serve.server`.
 """
 
 from dataclasses import dataclass, field
@@ -53,51 +56,37 @@ class ServeConfig:
 
     :param buckets: the (batch, prompt_len, gen_len) lattice to
         precompile. Requests round UP to the smallest (prompt_len,
-        gen_len) shape class that fits; the batch extent is chosen at
-        flush time from the same-shape queue population.
-    :param max_wait_ms: micro-batcher deadline — a batch is flushed when
-        the bucket's batch size fills OR the oldest queued request has
-        waited this long, whichever comes first.
+        gen_len) shape class that fits; the admission batch extent is
+        chosen at admission from the same-class queue population.
     :param max_queue: admission control — ``submit`` rejects once this
         many requests are queued (the client sees HTTP 429).
     :param request_timeout: bound on one request's queue+decode walltime;
         a breach raises SeamTimeout (HTTP 503) instead of holding the
         connection forever.
-    :param stall_timeout: serve-side watchdog budget for one decoded
-        batch (trlx_tpu.supervisor); a hung decode dumps all-thread
+    :param stall_timeout: serve-side watchdog budget for one admission
+        or decode step (trlx_tpu.supervisor); a hung one dumps all-thread
         stacks and counts ``fault/stalls`` instead of leaving a silently
         dead port. 0 disables.
     :param host / port: bind address for the HTTP endpoint.
-    :param seed: base PRNG seed for sampling batches (each decoded batch
-        folds in a counter; greedy decode ignores it).
-    :param scheduler: ``"slots"`` (default) drives the continuous-batching
-        slot scheduler (trlx_tpu.serve.slots): step-level harvesting +
-        admission over a persistent KV slot pool, per-request
-        ``max_new_tokens`` termination. ``"static"`` keeps the PR-4
-        batch-to-completion micro-batcher (the A/B baseline bench.py's
-        mixed-length trace replays against).
-    :param slots: slot-pool size for the ``slots`` scheduler; 0 (default)
-        sizes it to the largest compiled batch extent — capacity parity
-        with the static path. Pool HBM is
-        ``2 * n_layer * slots * max(prompt+gen) * kv_heads * head_dim``
-        cache-dtype elements under the contiguous layout, or
-        ``2 * n_layer * pages * page_size * kv_heads * head_dim`` paged.
-    :param kv_layout: ``"paged"`` (default) backs the slot pool with a
-        block-granular page pool + per-slot page tables and radix-tree
-        prefix caching (requests sharing a committed prompt prefix skip
-        re-prefilling it); ``"contiguous"`` keeps the PR-5 one-region-
-        per-slot layout (the A/B fallback — no prefix sharing, HBM
-        bounded by slots x worst-case length).
-    :param page_size: tokens per KV page under ``kv_layout: paged``
-        (clamped to the slot buffer length). Smaller pages waste less on
-        the last partial page and match shorter shared prefixes; larger
+    :param seed: base PRNG seed for sampling (each decode step adds
+        its counter; greedy decode ignores it).
+    :param slots: slot-pool size of the continuous-batching scheduler
+        (trlx_tpu.serve.slots); 0 (default) sizes it to the largest
+        compiled batch extent. Pool HBM is
+        ``2 * n_layer * pages * page_size * kv_heads * head_dim``
+        cache-dtype elements: the pool is fixed-size KV pages addressed
+        through per-slot page tables, with radix-tree prefix caching
+        (requests sharing a committed prompt prefix skip re-prefilling
+        it).
+    :param page_size: tokens per KV page (clamped to the slot buffer
+        length). Smaller pages waste less on the last partial page and match shorter shared prefixes; larger
         pages mean fewer table entries and bigger contiguous reads. Also
         the prefix-cache granularity: only whole committed pages are
         shared.
-    :param pages: page-pool size under ``kv_layout: paged``; 0 (default)
-        sizes it to ``slots * ceil(buffer_len / page_size)`` — capacity
-        parity with the contiguous pool. Size it DOWN (or slots UP) to
-        bank on real traffic being shorter than worst case: admission
+    :param pages: page-pool size; 0 (default) sizes it to
+        ``slots * ceil(buffer_len / page_size)`` — every slot can hold
+        the longest request the lattice admits. Size it DOWN (or slots
+        UP) to bank on real traffic being shorter than worst case: admission
         reserves only each request's own ``ceil((prompt + max_new) /
         page_size)`` pages, so mixed-length traffic packs more live
         slots into the same HBM (docs/source/serving.rst has the
@@ -114,8 +103,7 @@ class ServeConfig:
         (received/enqueued/admitted/prefill/first-token/harvested),
         feeding the ``serve/ttft`` / ``serve/itl`` / ``serve/goodput``
         SLO family, Perfetto per-request tracks, and the opt-in
-        ``"trace": true`` response payload. Host-side only; disable for
-        the A/B baseline (bench_serving measures the overhead).
+        ``"trace": true`` response payload. Host-side only.
     :param slo_ttft_ms: the TTFT service-level objective in ms —
         ``serve/goodput`` is the fraction of completed requests whose
         time-to-first-token beat it. 0 counts every request as good.
@@ -165,9 +153,8 @@ class ServeConfig:
         small slice; ``"replicated"`` keeps each weight whole per chip —
         no all-gathers on the decode matvec path when HBM affords it
         (docs/source/serving.rst has the sizing formula).
-    :param attention: decode attention implementation under
-        ``kv_layout: paged``: ``"jnp"`` (default) gathers each slot's
-        pages back into logical order in HBM before scoring — the A/B
+    :param attention: decode attention implementation: ``"jnp"``
+        (default) gathers each slot's pages back into logical order in HBM before scoring — the A/B
         oracle and CPU fallback; ``"pallas"`` runs the fused
         paged-attention decode kernel (trlx_tpu.ops.paged_attention):
         page-table walk, gather, and online softmax in one pallas_call,
@@ -182,7 +169,7 @@ class ServeConfig:
         ``2 * head_dim`` to ``head_dim + 4`` bytes per head, so the
         same pool HBM holds ~2x the pages; greedy outputs stay
         parity-tested against one-shot generate() within a logit
-        tolerance rather than bit-identical. Paged layout only.
+        tolerance rather than bit-identical.
     :param weights_dtype: serve-only weight tier applied at the
         strip-at-load seam: ``"bf16"`` (default) installs the
         checkpoint's dtype; ``"int8"`` quantizes the block matmul
@@ -199,8 +186,8 @@ class ServeConfig:
         batched ``verify_step`` pass; ``"draft"`` proposes with a small
         draft model (``spec_draft_checkpoint``) instead. Greedy
         verification keeps output BIT-IDENTICAL to ``off`` — the knob
-        trades nothing but the verify pass's FLOPs. Requires
-        ``kv_layout: paged`` and greedy decode (``do_sample: false``).
+        trades nothing but the verify pass's FLOPs. Requires greedy
+        decode (``do_sample: false``).
     :param spec_k: proposed tokens verified per slot per speculative
         step (static — one more compiled executable, zero steady-state
         recompiles). 3-8 fits most traces; past the typical acceptance
@@ -219,16 +206,13 @@ class ServeConfig:
     buckets: List[List[int]] = field(
         default_factory=lambda: [list(b) for b in _DEFAULT_BUCKETS]
     )
-    max_wait_ms: float = 20.0
     max_queue: int = 256
     request_timeout: float = 120.0
     stall_timeout: float = 0.0
     host: str = "127.0.0.1"
     port: int = 8080
     seed: int = 0
-    scheduler: str = "slots"
     slots: int = 0
-    kv_layout: str = "paged"
     page_size: int = 64
     pages: int = 0
     window_pages: int = 0
@@ -272,7 +256,38 @@ class ServeConfig:
 
     @classmethod
     def from_dict(cls, config: Optional[Dict[str, Any]]) -> "ServeConfig":
-        return cls(**filter_known_fields(cls, config or {}))
+        """Unknown keys are dropped, as every config section drops them
+        (a file may be older or newer than the code) — except a retired
+        setting that asks for a path that is gone
+        (:func:`refuse_retired`)."""
+        config = config or {}
+        refuse_retired(config)
+        return cls(**filter_known_fields(cls, config))
+
+
+#: settings that once chose between serve paths: the value that named
+#: the surviving path, and what that path is. Config files and
+#: checkpoints written before PR 31 carry these keys; the surviving
+#: value loads (and is dropped like any legacy key).
+RETIRED_SETTINGS = {
+    "scheduler": ("slots", "every request is served by the continuous-"
+                  "batching slot scheduler (trlx_tpu.serve.slots)"),
+    "kv_layout": ("paged", "the KV pool is always fixed-size pages "
+                  "behind per-slot page tables (serve.page_size, "
+                  "serve.pages)"),
+}
+
+
+def refuse_retired(section: Dict[str, Any]) -> None:
+    """Refuse, by name, a ``serve:`` section whose retired setting asks
+    for a removed path: dropped in silence, the file's traffic would be
+    served another way than the file says."""
+    for key, (kept, now) in RETIRED_SETTINGS.items():
+        if key in section and section[key] != kept:
+            raise ValueError(
+                f"serve.{key}: {section[key]!r} was removed in PR 31; "
+                f"{now}. Drop the key (or leave it at {kept!r})."
+            )
 
 
 #: block matmul leaves serve.weights_dtype: int8 quantizes — the stacked
@@ -358,9 +373,8 @@ class InferenceEngine:
     """A restored policy + its precompiled decode bucket lattice.
 
     Thread-safety: :meth:`decode` serializes dispatches under a lock —
-    one device program runs at a time (the micro-batcher is the intended
-    single caller; the lock makes direct multi-threaded use safe rather
-    than fast).
+    one device program runs at a time (the lock makes direct
+    multi-threaded use safe rather than fast).
     """
 
     def __init__(self, config: TRLConfig, serve: Optional[ServeConfig] = None,
@@ -398,19 +412,9 @@ class InferenceEngine:
             telemetry.start()
         self.config = config
         self.serve = serve or ServeConfig()
-        if self.serve.scheduler not in ("static", "slots"):
-            raise ValueError(
-                f"serve.scheduler '{self.serve.scheduler}' is not one of: "
-                f"static, slots"
-            )
         if self.serve.slots < 0:
             raise ValueError(
                 f"serve.slots={self.serve.slots} must be >= 0 (0 = auto)"
-            )
-        if self.serve.kv_layout not in ("paged", "contiguous"):
-            raise ValueError(
-                f"serve.kv_layout '{self.serve.kv_layout}' is not one of: "
-                f"paged, contiguous"
             )
         if self.serve.page_size < 1:
             raise ValueError(
@@ -482,7 +486,7 @@ class InferenceEngine:
         if self.serve.tenants is not None:
             # parse eagerly so a bad tenants block fails at boot with a
             # config-shaped error, not at first admission
-            from trlx_tpu.serve.batcher import TenantTable
+            from trlx_tpu.serve.admission import TenantTable
 
             TenantTable(self.serve.tenants, self.serve.max_queue)
         if self.serve.mesh_weights not in ("fsdp", "replicated"):
@@ -533,27 +537,6 @@ class InferenceEngine:
                 "serve.spec_draft_checkpoint (the draft model to "
                 "propose with) — or use speculation: lookup"
             )
-        if self.serve.kv_layout != "paged":
-            if self.serve.attention == "pallas":
-                raise ValueError(
-                    "serve.attention 'pallas' is the PAGED decode "
-                    "kernel; kv_layout "
-                    f"'{self.serve.kv_layout}' has no paged pool to "
-                    "walk — use kv_layout: paged or attention: jnp"
-                )
-            if self.serve.kv_dtype != "bf16":
-                raise ValueError(
-                    "serve.kv_dtype 'int8' quantizes PAGED pool pages; "
-                    f"kv_layout '{self.serve.kv_layout}' supports bf16 "
-                    "only"
-                )
-            if self.serve.speculation != "off":
-                raise ValueError(
-                    "serve.speculation verifies candidates through the "
-                    "PAGED pool's per-slot page tables; kv_layout "
-                    f"'{self.serve.kv_layout}' cannot re-claim rejected "
-                    "writes — use kv_layout: paged or speculation: off"
-                )
         from trlx_tpu.serve.layouts import build_serve_mesh
 
         #: the serve mesh every executable compiles against — a
@@ -570,7 +553,6 @@ class InferenceEngine:
             spec, kv_dtype=self.serve.kv_dtype,
             weights_dtype=self.serve.weights_dtype,
             speculation=self.serve.speculation, mesh=self.serve.mesh,
-            scheduler=self.serve.scheduler, kv_layout=self.serve.kv_layout,
         )
         for b, p, g in self.buckets:
             if p + g > spec.n_positions:
@@ -636,7 +618,6 @@ class InferenceEngine:
         # a race — two first-callers each build a Lock and hold
         # different ones (graftlint: lazy-lock)
         self._lock = threading.Lock()
-        self.warmed = False
 
     # -- construction --------------------------------------------------- #
 
@@ -785,7 +766,6 @@ class InferenceEngine:
             ) / 2**30,
         )
         self._decode_fns = {}  # shapes unchanged but weights swapped
-        self.warmed = False
 
     def mesh_info(self) -> Dict[str, Any]:
         """The serve-mesh block /healthz and /debug/state report: axis
@@ -986,12 +966,6 @@ class InferenceEngine:
             f"classes (prompt, gen): {list(self.shape_classes())}"
         )
 
-    def batch_sizes_for(self, shape: Tuple[int, int]) -> Tuple[int, ...]:
-        """Ascending batch extents compiled for one shape class."""
-        return tuple(sorted(
-            b for b, p, g in self.buckets if (p, g) == shape
-        ))
-
     def max_new_tokens_cap(self) -> int:
         return max(g for _, _, g in self.buckets)
 
@@ -1017,13 +991,11 @@ class InferenceEngine:
         )
 
     def _chunked_from(self) -> Optional[int]:
-        """THE CHUNK RULE (docs/source/serving.rst): under the paged
-        layout a prompt class more than four times as long as the next
-        shorter class of the lattice, and every class after it, is
-        prefilled in chunks of that shorter class's length through its
-        prefix-context program. None: every class is one-shot."""
-        if self.serve.kv_layout != "paged" or self.serve.scheduler != "slots":
-            return None
+        """THE CHUNK RULE (docs/source/serving.rst): a prompt class more
+        than four times as long as the next shorter class of the
+        lattice, and every class after it, is prefilled in chunks of
+        that shorter class's length through its prefix-context program.
+        None: every class is one-shot."""
         lengths = sorted({p for _, p, _ in self.buckets})
         for shorter, longer in zip(lengths, lengths[1:]):
             if longer > 4 * shorter:
@@ -1053,7 +1025,8 @@ class InferenceEngine:
 
     def slot_count(self) -> int:
         """Slot-pool size: ``serve.slots``, or the largest compiled batch
-        extent (capacity parity with the static path) when 0."""
+        extent when 0 (one admission batch of the widest bucket fills
+        the pool)."""
         return self.serve.slots or max(b for b, _, _ in self.buckets)
 
     def slot_buffer_len(self) -> int:
@@ -1062,12 +1035,12 @@ class InferenceEngine:
         n_positions)."""
         return max(p + g for _, p, g in self.buckets)
 
-    # -- paged-pool lattice (serve.kv_layout: paged) ---------------------- #
+    # -- page-pool lattice ------------------------------------------------ #
 
     def page_size_tokens(self) -> int:
         """Effective KV page size: ``serve.page_size`` clamped to the
-        slot buffer length (a page larger than the longest request is
-        just the contiguous layout with extra steps)."""
+        slot buffer length (a page longer than the longest request
+        holds nothing a shorter one would not)."""
         return min(self.serve.page_size, self.slot_buffer_len())
 
     def pages_per_slot(self) -> int:
@@ -1076,8 +1049,8 @@ class InferenceEngine:
         return -(-self.slot_buffer_len() // ps)
 
     def page_count(self) -> int:
-        """Page-pool size: ``serve.pages``, or slots x pages-per-slot
-        (capacity parity with the contiguous layout) when 0."""
+        """Page-pool size: ``serve.pages``, or slots x pages-per-slot when
+        0 (every slot can hold the longest request the lattice admits)."""
         return self.serve.pages or self.slot_count() * self.pages_per_slot()
 
     # -- the window class (a model with window layers) -------------------- #
@@ -1138,10 +1111,12 @@ class InferenceEngine:
 
     def decode(self, bucket: Bucket, tokens: np.ndarray, mask: np.ndarray,
                seed: int = 0):
-        """Run one bucket-shaped batch: tokens/mask are left-padded
-        ``[B, P]`` int32; returns the GenerationOutput as host numpy
-        (blocking — the micro-batcher's flush IS the dispatch boundary).
-        """
+        """THE ONE-SHOT ORACLE: ``generate()`` over one bucket-shaped
+        batch, decoded to completion. It serves no traffic — the slot
+        scheduler does, through its own prefill/step programs — and is
+        kept because the tests compare the scheduler's tokens against
+        it. tokens/mask are left-padded ``[B, P]`` int32; returns the
+        GenerationOutput as host numpy (blocking)."""
         import jax
 
         from trlx_tpu import telemetry
@@ -1162,32 +1137,6 @@ class InferenceEngine:
             )
             out = jax.device_get(out)
         return out
-
-    def warmup(self) -> Dict[str, float]:
-        """Compile every lattice bucket up front so no live request pays
-        tracing + XLA compilation. Returns {bucket span name: first-call
-        seconds} (also in telemetry as ``compile/<span>_first_s`` gauges
-        via the tracer's first-call separation)."""
-        from trlx_tpu import telemetry
-
-        latencies = {}
-        for bucket in self.buckets:
-            B, P, G = bucket
-            tokens = np.full((B, P), self.pad_token_id, np.int32)
-            tokens[:, -1] = 0
-            mask = np.zeros((B, P), np.int32)
-            mask[:, -1] = 1
-            self.decode(bucket, tokens, mask, seed=0)
-            tel = telemetry.current()
-            if tel is not None:
-                hist = tel.registry.hists.get(
-                    f"time/{self.span_name(bucket)}"
-                )
-                if hist is not None and hist.first is not None:
-                    latencies[self.span_name(bucket)] = hist.first
-        self.warmed = True
-        telemetry.set_gauge("serve/buckets_warmed", len(self.buckets))
-        return latencies
 
     # -- request shaping -------------------------------------------------- #
 

@@ -47,9 +47,8 @@ from trlx_tpu.parallel.sharding import _fit_spec_to_shape, _path_names
 SERVE_AXES = ("tp", "fsdp")
 
 #: KV pool leaf specs by rank — the pool is per-layer leaves
-#: (generation.init_page_pool / init_slot_pool), every one with heads on
-#: axis 2: paged pages [pages, page_size, Hkv, hd], contiguous regions
-#: [slots, buffer_len, Hkv, hd], int8 scale planes [pages, page_size, Hkv]
+#: (generation.init_page_pool), every one with heads on axis 2: pages
+#: [pages, page_size, Hkv, hd], int8 scale planes [pages, page_size, Hkv]
 KV_POOL_SPECS = {4: P(None, None, "tp", None), 3: P(None, None, "tp")}
 
 
@@ -148,7 +147,7 @@ def decode_param_shardings(mesh: Mesh, views: Any,
 
 
 def kv_pool_shardings(mesh: Mesh, pool: Any) -> Any:
-    """NamedSharding pytree for a KV pool (paged or contiguous): heads
+    """NamedSharding pytree for a KV pool: heads
     (axis 2 of every per-layer leaf) over tp, everything else
     replicated. Works on arrays or ShapeDtypeStructs; an Hkv that tp
     doesn't divide replicates."""

@@ -1,4 +1,4 @@
-"""Stdlib HTTP endpoint over the inference engine + micro-batcher.
+"""Stdlib HTTP endpoint over the inference engine + slot scheduler.
 
 ``ThreadingHTTPServer`` + JSON — no new dependencies, matching the rest
 of the codebase's stdlib-only host layer. Four routes:
@@ -17,7 +17,7 @@ of the codebase's stdlib-only host layer. Four routes:
   (decode/chaos failure).
 - ``GET /healthz`` — liveness + lattice + queue depth. A process whose
   decode thread is wedged still answers (HTTP is a different thread) —
-  which is exactly why the batcher runs under the supervisor watchdog:
+  which is exactly why the scheduler runs under the supervisor watchdog:
   the hang surfaces as a stack-dumping stall (``fault/stalls``) rather
   than a green health check over a dead port.
 - ``GET /metrics`` — content-negotiated: the default is the full
@@ -76,14 +76,14 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
 from trlx_tpu import telemetry
-from trlx_tpu.serve.batcher import (
+from trlx_tpu.serve.admission import (
     DeadlineExceeded,
     DrainTimeout,
-    MicroBatcher,
     QueueFull,
     QuotaExceeded,
     ReplayExhausted,
 )
+from trlx_tpu.serve.slots import SlotScheduler
 from trlx_tpu.serve.trace import SLO_COUNTERS, RequestTrace
 from trlx_tpu.utils.checkpoint import CheckpointCorrupt
 from trlx_tpu.supervisor import (
@@ -99,7 +99,6 @@ from trlx_tpu.supervisor import (
 _SERVE_COUNTERS = (
     "serve/requests",
     "serve/responses",
-    "serve/batches",
     "serve/rejected",
     "serve/request_errors",
     "serve/generated_tokens",
@@ -197,22 +196,17 @@ class _Handler(BaseHTTPRequestHandler):
     def do_GET(self) -> None:  # noqa: N802 (stdlib casing)
         srv = self.server_ref
         if self.path == "/healthz":
-            body = {
+            self._json(200, {
                 "status": "ok",
                 "warmed": srv.warmed,
-                "scheduler": srv.engine.serve.scheduler,
+                "scheduler": "slots",  # the one there is; clients key on it
                 "buckets": [list(b) for b in srv.engine.buckets],
-                "queue_depth": srv.batcher.queue_depth(),
-            }
-            free = getattr(srv.batcher, "free_slots", None)
-            if free is not None:
-                body["slots"] = srv.batcher.runtime.num_slots
-                body["free_slots"] = free()
-            pool_stats = getattr(srv.batcher, "pool_stats", None)
-            if pool_stats is not None:
-                body["kv"] = pool_stats()
-            body["mesh"] = srv.engine.mesh_info()
-            self._json(200, body)
+                "queue_depth": srv.scheduler.queue_depth(),
+                "slots": srv.scheduler.runtime.num_slots,
+                "free_slots": srv.scheduler.free_slots(),
+                "kv": srv.scheduler.pool_stats(),
+                "mesh": srv.engine.mesh_info(),
+            })
         elif self.path == "/metrics":
             accept = self.headers.get("Accept", "") or ""
             wants_text = any(
@@ -232,30 +226,18 @@ class _Handler(BaseHTTPRequestHandler):
             # replica answers 503 here while /healthz stays 200, so the
             # orchestrator rotates it without killing in-flight work
             ready = srv.warmed and not srv.draining
-            body = {
+            self._json(200 if ready else 503, {
                 "ready": ready,
                 "warmed": srv.warmed,
                 "draining": srv.draining,
                 "model_version": srv.engine.model_version,
-            }
-            # backpressure block (overload containment): the router's
-            # prober reads this to shed best-effort tenants BEFORE
-            # forwarding into a page-starved/browned-out replica
-            pressure_fn = getattr(srv.batcher, "pressure", None)
-            if pressure_fn is not None:
-                body["pressure"] = pressure_fn()
-            self._json(200 if ready else 503, body)
+                # backpressure block (overload containment): the router's
+                # prober reads this to shed best-effort tenants BEFORE
+                # forwarding into a page-starved/browned-out replica
+                "pressure": srv.scheduler.pressure(),
+            })
         elif self.path == "/debug/state":
-            state_fn = getattr(srv.batcher, "debug_state", None)
-            if state_fn is not None:
-                self._json(200, state_fn())
-            else:  # static micro-batcher: no slot map / flight recorder
-                self._json(200, {
-                    "scheduler": srv.engine.serve.scheduler,
-                    "queue_depth": srv.batcher.queue_depth(),
-                    "slots": {},
-                    "flight_recorder": [],
-                })
+            self._json(200, srv.scheduler.debug_state())
         elif self.path == "/debug/slo":
             # live windowed goodput/burn-rate per label set (serve.trace
             # SloEngine); an empty body when telemetry is off or nothing
@@ -359,7 +341,7 @@ class _Handler(BaseHTTPRequestHandler):
             # admission control (queue full OR draining): tell the
             # client WHEN to come back — queue depth x recent step p50
             self._json(429, {"error": str(e)}, headers={
-                "Retry-After": str(srv.batcher.retry_after_s()),
+                "Retry-After": str(srv.scheduler.retry_after_s()),
             })
             return
         except (ValueError, TypeError) as e:
@@ -387,17 +369,14 @@ class _Handler(BaseHTTPRequestHandler):
 
 
 class InferenceServer:
-    """Engine + decode driver + supervisor + HTTP listener, one object.
+    """Engine + slot scheduler + supervisor + HTTP listener, one object.
 
-    The decode driver is picked by ``serve.scheduler``: ``"slots"``
-    (default) runs the continuous-batching :class:`SlotScheduler`
+    The decode driver is the continuous-batching :class:`SlotScheduler`
     (trlx_tpu.serve.slots — step-level harvest/admission over the
-    persistent KV slot pool); ``"static"`` runs the PR-4
-    batch-to-completion :class:`MicroBatcher`. Both expose the same
-    submit/wait surface, so the HTTP layer is scheduler-agnostic.
+    persistent KV slot pool).
 
     ``start()`` warms the decode programs (unless ``warmup=False``),
-    starts the driver worker (which enters the serve supervisor when
+    starts the scheduler's worker (which enters the serve supervisor when
     ``serve.stall_timeout`` > 0), and binds the HTTP thread; ``stop()``
     tears all three down. Usable in-process (tests pass port=0 and read
     ``server.port``) or via ``python -m trlx_tpu.serve``.
@@ -418,17 +397,11 @@ class InferenceServer:
                 stall_timeout=cfg.stall_timeout, stall_action="abort"
             )
         self.supervisor = sup
-        if cfg.scheduler == "slots":
-            from trlx_tpu.serve.slots import SlotScheduler
-
-            self.batcher = SlotScheduler(engine, run_supervisor=sup)
-        else:
-            self.batcher = MicroBatcher(engine, run_supervisor=sup)
-        dump_fn = getattr(self.batcher, "dump_flight_recorder", None)
-        if sup is not None and dump_fn is not None:
+        self.scheduler = SlotScheduler(engine, run_supervisor=sup)
+        if sup is not None:
             # a watchdog stall dumps the engine-step ring next to the
             # all-thread stack dump (trlx_tpu.serve.trace.FlightRecorder)
-            sup.add_dump_fn(dump_fn)
+            sup.add_dump_fn(self.scheduler.dump_flight_recorder)
         self._httpd: Optional[ThreadingHTTPServer] = None  # guarded-by: _stop_lock
         self._http_thread: Optional[threading.Thread] = None  # guarded-by: _stop_lock
         self._stop_lock = threading.Lock()
@@ -457,15 +430,12 @@ class InferenceServer:
             return True
         with self._lifecycle_lock:
             started = self._drain_thread is not None
-        return started or bool(getattr(self.batcher, "_draining", False))
+        return started or self.scheduler._draining
 
     @property
     def warmed(self) -> bool:
-        """Whether this server's decode programs are compiled: the slot
-        scheduler's prefill/step executables, or the static lattice."""
-        if self.engine.serve.scheduler == "slots":
-            return self.batcher.warmed
-        return self.engine.warmed
+        """Whether the scheduler's prefill/step executables are compiled."""
+        return self.scheduler.warmed
 
     # -- request semantics ---------------------------------------------- #
 
@@ -499,7 +469,7 @@ class InferenceServer:
         if self.engine.serve.request_tracing:
             trace = RequestTrace(trace_id=trace_id, received=received_at)
         priority = body.get("priority")
-        req = self.batcher.submit(
+        req = self.scheduler.submit(
             tokens, max_new_tokens=max_new,
             seed=None if seed is None else int(seed),
             trace=trace,
@@ -515,7 +485,7 @@ class InferenceServer:
             ),
             "bucket": list(req.shape),
             "latency_ms": round(req.latency_s * 1000.0, 3),
-            "queue_depth": self.batcher.queue_depth(),
+            "queue_depth": self.scheduler.queue_depth(),
             "model_version": req.model_version,
         }
         if req.degraded:
@@ -552,7 +522,7 @@ class InferenceServer:
             # scheduler-level drain: rejects new work, finishes (or
             # deadline-sheds) everything in flight, dumps the flight
             # recorder, stops the worker
-            self._drain_clean = self.batcher.drain()
+            self._drain_clean = self.scheduler.drain()
         finally:
             self._watch_stop.set()
             try:
@@ -595,7 +565,7 @@ class InferenceServer:
                 )
             checkpoint = os.path.dirname(self.engine.checkpoint_path)
         params, resolved = self.engine.load_params(checkpoint)
-        result = self.batcher.request_swap(params, label=resolved)
+        result = self.scheduler.request_swap(params, label=resolved)
         result["checkpoint"] = resolved
         if result.get("reloaded"):
             print(f"[trlx_tpu.serve] hot-swapped to {resolved} "
@@ -648,31 +618,29 @@ class InferenceServer:
             from trlx_tpu.serve.trace import slo_engine
 
             slo_engine(target=self.engine.serve.slo_target)
-        if self.engine.serve.scheduler == "slots":
-            telemetry.set_gauge("serve/slot_occupancy", 0.0)
-            # quantization tier, visible per scrape: bytes one committed
-            # token holds resident, and the KV element width in bits
-            # (16 = bf16, 8 = int8) — the numeric twin of /healthz's
-            # ``kv.kv_dtype`` string
-            from trlx_tpu.telemetry.flops import kv_bytes_per_token
+        telemetry.set_gauge("serve/slot_occupancy", 0.0)
+        # quantization tier, visible per scrape: bytes one committed
+        # token holds resident, and the KV element width in bits
+        # (16 = bf16, 8 = int8) — the numeric twin of /healthz's
+        # ``kv.kv_dtype`` string
+        from trlx_tpu.telemetry.flops import kv_bytes_per_token
 
-            kv_dtype = self.engine.serve.kv_dtype
-            telemetry.set_gauge(
-                "serve/kv_bytes_per_token",
-                kv_bytes_per_token(self.engine.spec, kv_dtype),
-            )
-            telemetry.set_gauge(
-                "serve/kv_dtype", 8 if kv_dtype == "int8" else 16
-            )
-            cache = getattr(self.batcher, "cache", None)
-            if cache is not None:  # paged pool health, scraped from 0
-                telemetry.set_gauge(
-                    "serve/pages_free", cache.free_pages()
-                )
-                telemetry.set_gauge("serve/prefix_hit_rate", 0.0)
-                telemetry.set_gauge("serve/pages_per_request_p95", 0.0)
-            if self.engine.serve.speculation != "off":
-                telemetry.set_gauge("serve/spec_acceptance_rate", 0.0)
+        kv_dtype = self.engine.serve.kv_dtype
+        telemetry.set_gauge(
+            "serve/kv_bytes_per_token",
+            kv_bytes_per_token(self.engine.spec, kv_dtype),
+        )
+        telemetry.set_gauge(
+            "serve/kv_dtype", 8 if kv_dtype == "int8" else 16
+        )
+        # page pool health, scraped from 0
+        telemetry.set_gauge(
+            "serve/pages_free", self.scheduler.cache.free_pages()
+        )
+        telemetry.set_gauge("serve/prefix_hit_rate", 0.0)
+        telemetry.set_gauge("serve/pages_per_request_p95", 0.0)
+        if self.engine.serve.speculation != "off":
+            telemetry.set_gauge("serve/spec_acceptance_rate", 0.0)
         telemetry.set_gauge(
             "serve/model_version", self.engine.model_version
         )
@@ -691,14 +659,10 @@ class InferenceServer:
                 ) / 2**30,
             )
         if warmup and not self.warmed:
-            if self.engine.serve.scheduler == "slots":
-                latencies = self.batcher.warmup()
-            else:
-                latencies = self.engine.warmup()
-            for name, secs in latencies.items():
+            for name, secs in self.scheduler.warmup().items():
                 print(f"[trlx_tpu.serve] warmed {name}: {secs:.3f}s "
                       f"first call (compile)", file=sys.stderr, flush=True)
-        self.batcher.start()
+        self.scheduler.start()
         if self.engine.serve.watch_checkpoints > 0 \
                 and self._watch_thread is None:
             if self.engine.checkpoint_path is None:
@@ -748,7 +712,7 @@ class InferenceServer:
             httpd.server_close()
         if http_thread is not None:
             http_thread.join(timeout=5.0)
-        self.batcher.stop()
+        self.scheduler.stop()
 
     def _on_sigterm(self, signum, frame) -> None:
         # runs between bytecodes on whatever frame the signal interrupts:

@@ -1,18 +1,18 @@
 """Continuous-batching slot scheduler: iteration-level serving decode.
 
-The PR-4 micro-batcher (trlx_tpu.serve.batcher) batches
-*request-to-completion*: a flushed bucket decodes all ``gen_size`` steps
-before the next batch starts, short requests wait behind long ones, and
-filler rows decode at full cost. This module schedules at the *step*
-level instead (Orca, Yu et al., OSDI '22), over a persistent
+Batching *request-to-completion* (``InferenceEngine.decode``, the
+oracle) decodes all of a bucket's ``gen_size`` steps before the next
+batch starts: short requests wait behind long ones, and filler rows
+decode at full cost. This module schedules at the *step* level instead
+(Orca, Yu et al., OSDI '22), over a persistent
 device-resident KV **slot pool** (the static-shape analogue of vLLM's
 paged KV blocks, Kwon et al., SOSP '23):
 
 - :class:`SlotPoolRuntime` owns the pool + per-slot lanes and the
   AOT-compiled device primitives (trlx_tpu.models.generation):
-  ``prefill_into_slots`` — one executable per (batch, prompt_len)
-  admission bucket (two under the paged layout: plain + the
-  ``prefill_suffix`` prefix-context variant) — and ``decode_step`` —
+  ``prefill_into_slots`` — two executables per (batch, prompt_len)
+  admission bucket (plain + the ``prefill_suffix`` prefix-context
+  variant) — and ``decode_step`` —
   ONE executable for all slots. The pool is per-layer leaves, each
   donated on accelerators and written by one scatter, so a step
   updates it in place (``serve/decode_alias_bytes``); warmup runs every
@@ -20,35 +20,32 @@ paged KV blocks, Kwon et al., SOSP '23):
   ids (scatters ``mode="drop"`` — compiles the shape, touches nothing),
   then one decode step. Steady state is first-compiles only:
   ``compile/recompiles == 0`` stays the serving invariant.
-- Under ``serve.kv_layout: paged`` (the default) the pool is
-  block-granular: fixed-size KV pages shared by all slots, addressed
+- The pool is block-granular: fixed-size KV pages shared by all slots,
+  addressed
   through per-slot page tables, with a host free-list allocator and a
   radix-tree prefix cache (trlx_tpu.serve.paged) — admission reserves
   ``ceil((prompt + max_new) / page_size)`` pages instead of the
   worst-case buffer, prompts sharing committed prefixes skip
   re-prefilling them, and page exhaustion QUEUES requests (never
-  fails). ``serve.kv_layout: contiguous`` keeps the PR-5
-  one-region-per-slot pool as the A/B fallback.
+  fails).
 - :class:`SlotScheduler` runs the host loop: at every step boundary it
   **harvests** finished rows (EOS, or the request's own
   ``max_new_tokens`` — not the bucket's gen extent), frees their slots
   (and pages) immediately, and **admits** queued requests into free
   slots via bucketed prefill. Short requests no longer wait for long
   ones; filler rows become free slots; steady-state **slot occupancy**
-  (``serve/slot_occupancy``) replaces ``batch_fill_ratio`` as the
-  utilization signal.
+  (``serve/slot_occupancy``) is the utilization signal.
 
-Containment mirrors the static path: the worker thread enters the serve
-supervisor; admission runs as the ``serve_admit`` phase (chaos seam
-``serve_admit`` — a wedged admission is a stall the watchdog can
+Containment: the worker thread enters the serve supervisor; admission
+runs as the ``serve_admit`` phase (chaos seam ``serve_admit`` — a wedged admission is a stall the watchdog can
 attribute, not silence) and each decode step as ``serve_decode`` with a
 heartbeat per step. Crash-only recovery (docs "Fault tolerance",
 "serving lifecycle"): the unit of failure is the STEP, not the request.
 A poisoned step (or admission) dumps the flight recorder, resets the
 lanes + prefix cache, and RE-QUEUES every in-flight request with its
 committed tokens journaled host-side — re-admission prefills
-``prompt + committed`` (paged: the committed prefix maps copy-free
-through the radix cache) and resumes decode from the last committed
+``prompt + committed`` (the committed prefix maps copy-free through the
+radix cache) and resumes decode from the last committed
 token, bit-identical under greedy decode. The per-request replay budget
 is ``serve.max_replays`` (exceed -> ReplayExhausted, HTTP 503). The
 ``serve_replay`` chaos seam fires at recovery entry; a fault THERE is a
@@ -67,9 +64,7 @@ the paged-pool family (``serve/prefix_tokens_saved`` /
 ``serve/pages_per_request`` histogram), plus the shared
 ``serve/requests|responses|rejected|request_errors|generated_tokens``
 family and the path-labeled ``serve/request_latency{path=slots}``
-histogram. The old batch-to-completion path stays available as
-``serve.scheduler: static`` for A/B (bench.py replays the same
-mixed-length trace against both schedulers and both KV layouts).
+histogram.
 
 Overload containment (docs "Fault tolerance"): requests carry a tenant;
 ``serve.tenants`` quotas are enforced at :meth:`SlotScheduler.submit`
@@ -92,7 +87,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from trlx_tpu import supervisor, telemetry
-from trlx_tpu.serve.batcher import (
+from trlx_tpu.serve.admission import (
     DEFAULT_TENANT,
     Draining,
     DrainTimeout,
@@ -123,7 +118,6 @@ class SlotPoolRuntime:
         from trlx_tpu.models.generation import (
             _segments_of,
             init_page_pool,
-            init_slot_pool,
             init_slot_state,
         )
         from trlx_tpu.serve import layouts
@@ -131,7 +125,6 @@ class SlotPoolRuntime:
         self.engine = engine
         self.num_slots = engine.slot_count() if num_slots is None \
             else int(num_slots)
-        self.kv_layout = engine.serve.kv_layout
         self._segments, self._seg_sizes = _segments_of(engine.blocks)
         self._vocab = engine.spec.vocab_size
         # CPU has no buffer donation; donating there only prints warnings
@@ -144,46 +137,35 @@ class SlotPoolRuntime:
         #: a model with window layers keeps a second class of page: every
         #: program then takes the window-class tables beside the full ones
         #: and returns the expert layer's routing counts
-        self.two_class = (
-            self.kv_layout == "paged"
-            and "window" in engine.spec.page_classes
-        )
+        self.two_class = "window" in engine.spec.page_classes
         self.ring_pages = self.num_window_pages = 0
         self.moe_stats = []  # device arrays [L, 4] since the last fetch
         self.moe_stats_host = []  # the same, fetched with the last step
-        if self.kv_layout == "paged":
-            self.page_size = engine.page_size_tokens()
-            self.max_pages = engine.pages_per_slot()
-            self.num_pages = engine.page_count()
-            if self.two_class:
-                self.ring_pages = engine.window_ring_pages()
-                self.num_window_pages = engine.window_page_count()
-            # logical per-slot extent rounds UP to whole pages
-            self.buffer_len = self.max_pages * self.page_size
-            # serve.kv_dtype picks the pool tier: int8 swaps each (k, v)
-            # array for (codes, scales) pairs (transformer.quantize_kv);
-            # everything downstream — shardings, prefill/decode, reset —
-            # flows from this partial, so the tier is set exactly once
-            cache_dtype = (
-                jnp.int8 if engine.serve.kv_dtype == "int8"
-                else jnp.bfloat16
-            )
-            self._init_pool = functools.partial(
-                init_page_pool, engine.spec, self._seg_sizes,
-                {"full": self.num_pages, "window": self.num_window_pages}
-                if self.two_class else self.num_pages,
-                self.page_size, cache_dtype=cache_dtype,
-            )
-        else:
-            self.page_size = self.max_pages = self.num_pages = 0
-            self.buffer_len = engine.slot_buffer_len()
-            self._init_pool = functools.partial(
-                init_slot_pool, engine.spec, self._seg_sizes,
-                self.num_slots, self.buffer_len,
-            )
+        self.page_size = engine.page_size_tokens()
+        self.max_pages = engine.pages_per_slot()
+        self.num_pages = engine.page_count()
+        if self.two_class:
+            self.ring_pages = engine.window_ring_pages()
+            self.num_window_pages = engine.window_page_count()
+        # logical per-slot extent rounds UP to whole pages
+        self.buffer_len = self.max_pages * self.page_size
+        # serve.kv_dtype picks the pool tier: int8 swaps each (k, v)
+        # array for (codes, scales) pairs (transformer.quantize_kv);
+        # everything downstream — shardings, prefill/decode, reset —
+        # flows from this partial, so the tier is set exactly once
+        cache_dtype = (
+            jnp.int8 if engine.serve.kv_dtype == "int8"
+            else jnp.bfloat16
+        )
+        self._init_pool = functools.partial(
+            init_page_pool, engine.spec, self._seg_sizes,
+            {"full": self.num_pages, "window": self.num_window_pages}
+            if self.two_class else self.num_pages,
+            self.page_size, cache_dtype=cache_dtype,
+        )
         self._init_state = functools.partial(
             init_slot_state, self.num_slots, self.buffer_len, self._vocab,
-            max_pages=self.max_pages or None,
+            max_pages=self.max_pages,
         )
         # KV pages shard on the head dim under tp; the per-slot lanes
         # (and page tables — host data, never shape) replicate. Built
@@ -204,7 +186,7 @@ class SlotPoolRuntime:
         self.state = jax.jit(
             self._init_state, out_shardings=self._state_shardings
         )()
-        self._prefill_fns = {}  # (Bp, P[, suffix]) -> aot_jit'd closure
+        self._prefill_fns = {}  # (Bp, P, suffix) -> aot_jit'd closure
         self._step_fn = None
         #: speculation: k proposed tokens verified per step (0 = off);
         #: K is STATIC, so verify_step is one more executable compiled
@@ -231,7 +213,7 @@ class SlotPoolRuntime:
     # -- compiled closures ----------------------------------------------- #
 
     def _prefill_fn(self, bucket, suffix: bool = False):
-        key = (*bucket, suffix) if self.kv_layout == "paged" else bucket
+        key = (*bucket, suffix)
         fn = self._prefill_fns.get(key)
         if fn is None:
             from trlx_tpu.models.generation import prefill_into_slots
@@ -239,42 +221,31 @@ class SlotPoolRuntime:
 
             spec = self.engine.spec
             compute = self.engine._compute_dtype
+            ps = self.page_size
 
-            if self.kv_layout == "paged":
-                ps = self.page_size
-
-                # a model with window layers also hands over its
-                # window-class tables (and is always ``suffix``)
-                def run(blocks, embed, ln_f, pool, state, tokens, mask,
-                        slot_ids, max_new, page_tables, start,
-                        window_tables=None, window_base=None):
-                    return prefill_into_slots(
-                        spec, blocks, embed, ln_f, pool, state, tokens,
-                        mask, slot_ids, max_new, compute_dtype=compute,
-                        page_tables=page_tables, page_size=ps,
-                        start=start, prefix_context=suffix,
-                        window_tables=window_tables,
-                        window_base=window_base,
-                    )
-            else:
-
-                def run(blocks, embed, ln_f, pool, state, tokens, mask,
-                        slot_ids, max_new):
-                    return prefill_into_slots(
-                        spec, blocks, embed, ln_f, pool, state, tokens,
-                        mask, slot_ids, max_new, compute_dtype=compute,
-                    )
+            # a model with window layers also hands over its
+            # window-class tables (and is always ``suffix``)
+            def run(blocks, embed, ln_f, pool, state, tokens, mask,
+                    slot_ids, max_new, page_tables, start,
+                    window_tables=None, window_base=None):
+                return prefill_into_slots(
+                    spec, blocks, embed, ln_f, pool, state, tokens,
+                    mask, slot_ids, max_new, page_tables, ps,
+                    compute_dtype=compute, start=start,
+                    prefix_context=suffix,
+                    window_tables=window_tables,
+                    window_base=window_base,
+                )
 
             # the program's name in a device trace (jit_run_prefill_b4p128),
             # as its span's: a reader finds it without guessing
             Bp, P = bucket
             run.__name__ = f"run_prefill{'_sfx' if suffix else ''}_b{Bp}p{P}"
-            # host args (tokens/mask/slot_ids/max_new[/tables/start])
+            # host args (tokens/mask/slot_ids/max_new/tables/start)
             # replicate; pool + state keep their build shardings in AND
             # out — the step loop's signatures are pinned, so
             # compile/recompiles == 0 survives the mesh
-            n_host = 8 if self.two_class \
-                else 6 if self.kv_layout == "paged" else 4
+            n_host = 8 if self.two_class else 6
             fn = self._prefill_fns[key] = aot_jit(
                 run, donate_argnums=(3, 4) if self._donate else (),
                 in_shardings=(
@@ -309,10 +280,7 @@ class SlotPoolRuntime:
             # devices so tp head-sharding (and greedy parity) holds.
             # Prefill stays jnp either way — the kernel is decode-only.
             paged_decode_fn = None
-            if (
-                self.kv_layout == "paged"
-                and self.engine.serve.attention == "pallas"
-            ):
+            if self.engine.serve.attention == "pallas":
                 from trlx_tpu.ops.paged_attention import (
                     make_paged_decode_fn,
                 )
@@ -398,15 +366,15 @@ class SlotPoolRuntime:
     # -- device calls ------------------------------------------------------ #
 
     def prefill(self, bucket, tokens: np.ndarray, mask: np.ndarray,
-                slot_ids, max_new, page_tables=None, start=None,
+                slot_ids, max_new, page_tables, start,
                 suffix: bool = False, window_tables=None,
                 window_base=None) -> None:
         """Admit one prompt bucket into the pool (filler rows carry the
-        out-of-bounds sentinel and are dropped on device). Paged layout:
+        out-of-bounds sentinel and are dropped on device).
         ``page_tables`` [Bp, max_pages] maps each row's logical pages
         (sentinel-padded), ``start`` is its committed prefix length, and
         ``suffix=True`` selects the prefix-context (``prefill_suffix``)
-        executable; tokens/mask are right-padded there."""
+        executable; tokens/mask are right-padded."""
         e = self.engine
         suffix = suffix or self.two_class  # two classes: the one variant
         fn = self._prefill_fn(bucket, suffix)
@@ -416,12 +384,9 @@ class SlotPoolRuntime:
             np.ascontiguousarray(mask, np.int32),
             np.asarray(slot_ids, np.int32),
             np.asarray(max_new, np.int32),
+            np.ascontiguousarray(page_tables, np.int32),
+            np.asarray(start, np.int32),
         ]
-        if self.kv_layout == "paged":
-            args += [
-                np.ascontiguousarray(page_tables, np.int32),
-                np.asarray(start, np.int32),
-            ]
         if self.two_class:
             Bp, P = bucket
             if window_tables is None:  # nothing mapped: every write drops
@@ -593,29 +558,23 @@ class SlotPoolRuntime:
         first-call seconds}."""
         pad = self.engine.pad_token_id
         latencies = {}
-        paged = self.kv_layout == "paged"
         # a model with window layers has the prefix-context variant alone
-        variants = (True,) if self.two_class \
-            else (False, True) if paged else (False,)
+        variants = (True,) if self.two_class else (False, True)
         for P, extents in self.engine.prompt_classes():
             for Bp in extents:
                 for suffix in variants:
                     tokens = np.full((Bp, P), pad, np.int32)
                     mask = np.zeros((Bp, P), np.int32)
-                    if paged:  # right-padded: one real token FIRST
-                        tokens[:, 0] = 0
-                        mask[:, 0] = 1
-                    else:
-                        tokens[:, -1] = 0
-                        mask[:, -1] = 1
+                    tokens[:, 0] = 0  # right-padded: one real token FIRST
+                    mask[:, 0] = 1
                     self.prefill(
                         (Bp, P), tokens, mask,
                         np.full((Bp,), self.num_slots, np.int32),
                         np.ones((Bp,), np.int32),
                         page_tables=np.full(
                             (Bp, self.max_pages), self.num_pages, np.int32
-                        ) if paged else None,
-                        start=np.zeros((Bp,), np.int32) if paged else None,
+                        ),
+                        start=np.zeros((Bp,), np.int32),
                         suffix=suffix,
                     )
         self.step(0)
@@ -653,7 +612,7 @@ class SlotPoolRuntime:
 
 class _LiveSlot:
     """Host bookkeeping for one occupied slot. ``pages`` is the slot's
-    full page-table content under the paged layout (matched prefix pages
+    full page-table content (matched prefix pages
     first — every entry holds one allocator reference released at
     harvest); ``committed`` the pages this admission inserted into the
     radix tree (the rollback handle for a failed prefill)."""
@@ -678,11 +637,9 @@ class _LiveSlot:
 
 class SlotScheduler:
     """The continuous-batching decode driver: one worker thread running
-    the admit -> step -> harvest loop over the slot pool.
-
-    Drop-in for :class:`trlx_tpu.serve.batcher.MicroBatcher` on the
-    server side: same ``submit``/``start``/``stop``/``queue_depth``
-    surface, same :class:`Request` completion contract.
+    the admit -> step -> harvest loop over the slot pool
+    (``submit``/``start``/``stop``/``queue_depth``; a submitted
+    :class:`Request` completes through its ``done`` event).
     """
 
     def __init__(self, engine, max_queue: Optional[int] = None,
@@ -693,11 +650,8 @@ class SlotScheduler:
         self.max_queue = cfg.max_queue if max_queue is None else max_queue
         self.run_supervisor = run_supervisor
         self.runtime = SlotPoolRuntime(engine, num_slots=slots)
-        #: host paged-KV broker (allocator + radix prefix cache); None
-        #: under the contiguous layout
-        self.cache: Optional[RadixCache] = None
-        if self.runtime.kv_layout == "paged":
-            self.cache = self._new_cache()
+        #: host paged-KV broker (allocator + radix prefix cache)
+        self.cache = self._new_cache()
         #: the slots' window-class rings (a model with window layers):
         #: host data handed to every decode step, logical page n of slot
         #: s at entry n % ring_pages
@@ -853,11 +807,10 @@ class SlotScheduler:
                deadline_ms: Optional[float] = None,
                priority: Optional[int] = None,
                tenant: Optional[str] = None) -> Request:
-        """Enqueue one request; same validation/admission contract as the
-        static micro-batcher (ValueError when no bucket fits, QueueFull
+        """Enqueue one request (ValueError when no bucket fits, QueueFull
         past ``max_queue``, Draining during a graceful drain). ``seed``
-        is accepted for surface parity but the sampling stream is
-        per-STEP here (a request's draws depend on which steps it rides),
+        is accepted (the HTTP body carries one) but the sampling stream
+        is per-STEP (a request's draws depend on which steps it rides),
         so only greedy decode is exactly reproducible.
 
         Overload control: ``deadline_ms`` bounds queueing — a request
@@ -899,26 +852,23 @@ class SlotScheduler:
             telemetry.inc("serve/brownout_clamped",
                           labels={"tenant": tenant})
         shape = self.engine.pick_shape(len(tokens), max_new_tokens)
-        if self.cache is not None:
-            need = self.engine.request_page_need(
-                len(tokens), max_new_tokens
+        need = self.engine.request_page_need(len(tokens), max_new_tokens)
+        if need > self.runtime.num_pages:
+            raise ValueError(
+                f"request needs {need} KV pages worst-case but the "
+                f"pool holds {self.runtime.num_pages}; raise "
+                f"serve.pages (or serve.page_size) — queueing could "
+                f"never admit it"
             )
-            if need > self.runtime.num_pages:
-                raise ValueError(
-                    f"request needs {need} KV pages worst-case but the "
-                    f"pool holds {self.runtime.num_pages}; raise "
-                    f"serve.pages (or serve.page_size) — queueing could "
-                    f"never admit it"
-                )
-            if self.runtime.two_class and (
-                self._window_quota(need, 0) > self.runtime.num_window_pages
-            ):
-                raise ValueError(
-                    f"request needs up to {self._window_quota(need, 0)} "
-                    f"window-class KV pages at once but the pool holds "
-                    f"{self.runtime.num_window_pages}; raise "
-                    f"serve.window_pages — queueing could never admit it"
-                )
+        if self.runtime.two_class and (
+            self._window_quota(need, 0) > self.runtime.num_window_pages
+        ):
+            raise ValueError(
+                f"request needs up to {self._window_quota(need, 0)} "
+                f"window-class KV pages at once but the pool holds "
+                f"{self.runtime.num_window_pages}; raise "
+                f"serve.window_pages — queueing could never admit it"
+            )
         if trace is None and self._tracing:
             trace = RequestTrace()
         req = Request(list(tokens), max_new_tokens, shape, seed=seed,
@@ -977,7 +927,7 @@ class SlotScheduler:
         ``serve.degrade_step_ms`` budget."""
         if self._starved:
             return True
-        if self.cache is not None and self.cache.free_pages() == 0:
+        if self.cache.free_pages() == 0:
             return True
         limit_ms = float(getattr(self.engine.serve, "degrade_step_ms", 0.0))
         return bool(limit_ms > 0 and self._last_step_ms > limit_ms)
@@ -1024,9 +974,8 @@ class SlotScheduler:
             "queue_depth": len(self._queue),
             "free_slots": len(self._free),
             "retry_after_s": self.retry_after_s(),
+            "pages_free": self.cache.free_pages(),
         }
-        if self.cache is not None:
-            out["pages_free"] = self.cache.free_pages()
         if self.spec_k > 0:
             out["spec_acceptance_rate"] = round(
                 self._spec_acceptance_rate(), 4
@@ -1062,7 +1011,7 @@ class SlotScheduler:
         ``seq``). Queued requests past their ``deadline_ms`` are shed
         here (DeadlineExceeded, ``serve/shed_expired``) before any slot
         is spent on them. Sets ``_starved`` when requests are left
-        waiting with no free slot (or, paged, no obtainable page) — the
+        waiting with no free slot (or no obtainable page) — the
         next step then counts as ``serve/preempted_steps``.
 
         Priority aging: every scan bumps each queued request's ``age``;
@@ -1112,8 +1061,8 @@ class SlotScheduler:
                 except Exception as e:
                     # a poisoned admission RE-QUEUES its requests for
                     # replay (bounded by serve.max_replays) instead of
-                    # failing them (paged: page-starved ones were
-                    # already re-queued and removed from `batch`); the
+                    # failing them (page-starved ones were already
+                    # re-queued and removed from `batch`); the
                     # pool lanes were only touched if the device call
                     # ran, and dropped-sentinel scatters cannot corrupt
                     # live slots
@@ -1145,56 +1094,15 @@ class SlotScheduler:
         )
 
     def _prefill_batch(self, batch: List[Request], P: int, extents) -> bool:
-        """Prefill one admission batch; returns False when the paged
+        """Prefill one admission batch; returns False when the page
         allocator ran dry and part of the batch went back to the queue."""
-        if self.cache is not None:
-            if self.runtime.two_class or self.engine.chunk_len(P):
-                return self._prefill_batch_classes(batch, P, extents)
-            return self._prefill_batch_paged(batch, P, extents)
-        Bp = next(b for b in extents if b >= len(batch))
-        slots = [self._free.pop() for _ in batch]
-        sentinel = self.runtime.num_slots
-        slot_ids = slots + [sentinel] * (Bp - len(batch))
-        # replayed requests prefill prompt + journaled committed tokens
-        # and decode only the REMAINING budget — greedy decode is Markov
-        # on the token prefix, so the resumed stream is bit-identical
-        rows = [r.tokens + r.committed for r in batch]
-        tokens, mask = self.engine.pad_batch(rows, (Bp, P, 0))
-        max_new = [r.remaining_new_tokens() for r in batch]
-        max_new += [1] * (Bp - len(batch))
-        admit_at = monotonic()
-        version = self.engine.model_version
-        for r in batch:
-            r.model_version = version
-            if r.trace is not None:
-                r.trace.admitted = admit_at
-                r.trace.bucket = (Bp, P)
-                r.trace.prefill_start = admit_at
-                r.trace.model_version = version
-        try:
-            self.runtime.prefill((Bp, P), tokens, mask, slot_ids, max_new)
-        except Exception:
-            self._free.extend(slots)  # nothing was admitted
-            raise
-        prefill_end = monotonic()
-        for r, s in zip(batch, slots):
-            if r.trace is not None:
-                r.trace.prefill_end = prefill_end
-            live = _LiveSlot(r)
-            live.tokens = list(r.committed)
-            self._live[s] = live
-            self.events.append(("admit", s, r))
-            self._spawn_speculator(s, r.tokens + r.committed)
-        self._fr_admitted += len(batch)
-        telemetry.inc("serve/admissions", len(batch))
-        for r in batch:
-            telemetry.inc("serve/admissions", labels={"tenant": r.tenant})
-        telemetry.set_gauge("serve/slot_occupancy", self._occupancy())
-        return True
+        if self.runtime.two_class or self.engine.chunk_len(P):
+            return self._prefill_batch_classes(batch, P, extents)
+        return self._prefill_batch_paged(batch, P, extents)
 
     def _prefill_batch_paged(self, batch: List[Request], P: int,
                              extents) -> bool:
-        """Paged admission: radix-match each prompt, reserve pages for
+        """Admission: radix-match each prompt, reserve pages for
         the unmatched suffix + decode budget, map hit pages copy-free
         into the page table, and prefill ONLY the suffix. Requests the
         allocator cannot cover (even after LRU eviction) go back to the
@@ -1627,8 +1535,7 @@ class SlotScheduler:
         from trlx_tpu.telemetry.flops import kv_bytes_per_token
 
         kv_dtype = self.engine.serve.kv_dtype
-        stats = {
-            "kv_layout": self.runtime.kv_layout,
+        return {
             "kv_dtype": kv_dtype,
             "kv_bytes_per_token": kv_bytes_per_token(
                 self.engine.spec, kv_dtype
@@ -1638,18 +1545,14 @@ class SlotScheduler:
                 layouts.tree_bytes_per_device(self.runtime.pool) / 2**30,
                 6,
             ),
+            "page_size": self.runtime.page_size,
+            "pages_total": self.runtime.num_pages,
+            "pages_free": self.cache.free_pages(),
+            "pages_cached": self.cache.cached_pages(),
+            "evicted_pages": self.cache.evicted_pages,
+            "prefix_hit_rate": round(self._hit_rate(), 4),
+            "prefix_tokens_saved": self._prefix_tokens_saved,
         }
-        if self.cache is not None:
-            stats.update(
-                page_size=self.runtime.page_size,
-                pages_total=self.runtime.num_pages,
-                pages_free=self.cache.free_pages(),
-                pages_cached=self.cache.cached_pages(),
-                evicted_pages=self.cache.evicted_pages,
-                prefix_hit_rate=round(self._hit_rate(), 4),
-                prefix_tokens_saved=self._prefix_tokens_saved,
-            )
-        return stats
 
     def _clamp_proposal(self, live: _LiveSlot, n: int) -> int:
         """Cap a slot's proposal at the request's remaining budget: the
@@ -1792,14 +1695,13 @@ class SlotScheduler:
                 del self._live[slot]
                 self._speculators.pop(slot, None)
                 self._free.append(slot)
-                if self.cache is not None:
-                    # committed (trie-owned) pages stay cached at
-                    # refcount 0 — hit-able until LRU eviction; the rest
-                    # return to the free list
-                    self._release_slot_pages(live)
-                    telemetry.set_gauge(
-                        "serve/pages_free", self.cache.free_pages()
-                    )
+                # committed (trie-owned) pages stay cached at refcount 0
+                # — hit-able until LRU eviction; the rest return to the
+                # free list
+                self._release_slot_pages(live)
+                telemetry.set_gauge(
+                    "serve/pages_free", self.cache.free_pages()
+                )
                 self.events.append(("free", slot, req))
                 self._fr_evicted += 1
                 telemetry.inc("serve/evictions")
@@ -1821,12 +1723,9 @@ class SlotScheduler:
         runs, so every page mapping (and every cached prefix whose
         content can no longer be trusted — poisoned step, or KV computed
         under pre-swap weights) resets with them."""
-        if self.cache is not None:
-            self.cache = self._new_cache()
-            self._wtable[:] = self.runtime.num_window_pages
-            telemetry.set_gauge(
-                "serve/pages_free", self.cache.free_pages()
-            )
+        self.cache = self._new_cache()
+        self._wtable[:] = self.runtime.num_window_pages
+        telemetry.set_gauge("serve/pages_free", self.cache.free_pages())
 
     def _fail_live(self, error: BaseException) -> None:
         """Last-resort containment (double fault, or replay disabled):
@@ -2102,29 +2001,18 @@ class SlotScheduler:
         pad = self.engine.pad_token_id
         tokens = np.full((Bp, P), pad, np.int32)
         mask = np.zeros((Bp, P), np.int32)
-        paged = rt.kv_layout == "paged"
-        if paged:
-            tokens[:, 0] = 0
-            mask[:, 0] = 1
-        else:
-            tokens[:, -1] = 0
-            mask[:, -1] = 1
+        tokens[:, 0] = 0
+        mask[:, 0] = 1
         slot_ids = np.full((Bp,), rt.num_slots, np.int32)
         slot_ids[0] = 0  # ONE real row — the probe reads its logits
-        page_tables = None
-        start = None
-        if paged:
-            page_tables = np.full(
-                (Bp, rt.max_pages), rt.num_pages, np.int32
-            )
-            need = self.engine.request_page_need(1, 1)
-            # the cache was reset just above: pages 0..need-1 are free
-            # and unmapped, and the post-probe reset unmaps them again
-            page_tables[0, :need] = np.arange(need, dtype=np.int32)
-            start = np.zeros((Bp,), np.int32)
+        page_tables = np.full((Bp, rt.max_pages), rt.num_pages, np.int32)
+        need = self.engine.request_page_need(1, 1)
+        # the cache was reset just above: pages 0..need-1 are free and
+        # unmapped, and the post-probe reset unmaps them again
+        page_tables[0, :need] = np.arange(need, dtype=np.int32)
         rt.prefill(
             (Bp, P), tokens, mask, slot_ids, np.ones((Bp,), np.int32),
-            page_tables=page_tables, start=start,
+            page_tables=page_tables, start=np.zeros((Bp,), np.int32),
         )
         logits = np.asarray(rt.state.logits[0])
         rt.reset_lanes()
@@ -2156,9 +2044,8 @@ class SlotScheduler:
             "admit_ms": round(self._admit_s * 1000.0, 3),
             "fetch_ms": round(self.runtime.fetch_s * 1000.0, 3),
             "harvest_ms": round(self._harvest_s * 1000.0, 3),
+            "pages_free": self.cache.free_pages(),
         }
-        if self.cache is not None:
-            rec["pages_free"] = self.cache.free_pages()
         if self.runtime.two_class:
             in_use = self._pages_in_use()
             rec.update(pages_full=in_use["full"],

@@ -17,8 +17,8 @@ is the host-side-only fix; nothing here crosses into a jitted program:
   ITL count/total/min/max), harvested, responded. :meth:`complete` derives the SLO
   family — ``serve/ttft``, ``serve/itl``, ``serve/queue_time``,
   ``serve/prefill_time``, ``serve/decode_time``, the
-  ``serve/request_latency`` histogram labeled per scheduler path
-  (``{path="slots"|"static"}``) and the ``serve/goodput``
+  ``serve/request_latency`` histogram labeled ``{path="slots"}`` (one
+  value; dashboards key on the label) and the ``serve/goodput``
   gauge (fraction of requests with TTFT under ``serve.slo_ttft_ms``) —
   and exports the request as its own Perfetto track (one ``tid`` per
   request, child spans per phase) through the session's SpanTracer.
@@ -276,22 +276,6 @@ class RequestTrace:
             self.itl_total += gap
             telemetry.observe("serve/itl", gap)
         self.last_token = now
-
-    def note_static_decode(self, start: float, end: float,
-                           n_tokens: int) -> None:
-        """The batch-to-completion path has no per-step timestamps — the
-        whole decode is one program, so its first token materializes at
-        decode END and ITL is the uniform ``decode_time / tokens``
-        approximation (one ``serve/itl`` observation per request, not
-        per gap — documented in docs/source/observability.rst)."""
-        self.prefill_start = self.prefill_end = start
-        self.first_token = self.last_token = end
-        if n_tokens > 1:
-            gap = (end - start) / n_tokens
-            self.itl_count = n_tokens - 1
-            self.itl_total = gap * self.itl_count
-            self.itl_min = self.itl_max = gap
-            telemetry.observe("serve/itl", gap)
 
     def itl_mean(self) -> float:
         return self.itl_total / self.itl_count if self.itl_count else 0.0

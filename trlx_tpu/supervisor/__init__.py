@@ -57,7 +57,7 @@ from trlx_tpu.supervisor.seams import (  # noqa: F401  (re-exports)
 )
 
 #: the containment clock: deadline/budget arithmetic for stall watchdogs
-#: and the serve micro-batcher's flush deadlines sources monotonic time
+#: and the serve scheduler's request deadlines sources monotonic time
 #: from HERE, not ad-hoc time.* calls — control-flow clocks live with the
 #: supervision machinery, measurements go through trlx_tpu.telemetry
 #: (enforced by tests/test_style.py)

@@ -18,9 +18,8 @@ A schedule is a ``;``-separated list of rules::
   ``ppo_update``, ``ilql_update``, ``eval``, and ``checkpoint_save``
   (fired once at phase entry). The serving subsystem (trlx_tpu.serve)
   adds ``serve_decode`` (fired inside the supervised ``serve_decode``
-  phase, before the decode dispatch — the static batcher's whole-batch
-  decode and the slot scheduler's per-step decode alike; a ``hang``
-  there drives the watchdog stall path), ``serve_admit`` (fired inside
+  phase, before the slot scheduler's per-step decode dispatch; a
+  ``hang`` there drives the watchdog stall path), ``serve_admit`` (fired inside
   the slot scheduler's ``serve_admit`` phase after an admission batch is
   selected, before its prefill dispatch — a ``hang`` makes a wedged
   admission an attributable stall, an ``exc`` fails just that batch),

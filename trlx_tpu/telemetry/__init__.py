@@ -18,9 +18,10 @@ What flows where:
   all carry the breakdown unchanged (flat float dict, the existing
   tracker protocol);
 - at ``learn()`` exit, ``session.finish()`` prints a one-line digest
-  (stderr, so bench.py's stdout JSON protocol stays clean) and writes
-  ``<run_dir>/telemetry.json`` (the run-level summary, headline
-  ``metric``/``value``/``unit`` at the top like a BENCH record) plus
+  (stderr: stdout belongs to the entry point, and benchmarks/run.py's
+  is one JSON line) and writes ``<run_dir>/telemetry.json`` (the
+  run-level summary, headline ``metric``/``value``/``unit`` at the top)
+  plus
   ``<run_dir>/trace.jsonl`` (Chrome-trace/Perfetto span timeline).
 
 ``run_dir`` resolves to ``train.telemetry_dir`` or, when unset, to
@@ -123,8 +124,8 @@ class TelemetrySession:
         }
 
     def summary(self) -> Dict[str, Any]:
-        """Run-level record: headline metric/value/unit at the top (the
-        shape bench.py's BENCH records use), then the full registry."""
+        """Run-level record: headline metric/value/unit at the top, then
+        the full registry."""
         sample_device_stats(self.registry)
         out: Dict[str, Any] = dict(self.headline or {})
         out.update(self.registry.summary())
@@ -157,8 +158,8 @@ class TelemetrySession:
     def finish(self) -> None:
         """Persist + print the digest. Called at every learn() exit (safe
         to call repeatedly — later calls overwrite with the newer state).
-        The digest goes to stderr: bench.py's contract is ONE JSON line on
-        stdout."""
+        The digest goes to stderr: an entry point's stdout may be a
+        protocol (benchmarks/run.py's is ONE JSON line)."""
         paths = self.write()
         if paths is None:
             return

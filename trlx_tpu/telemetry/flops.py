@@ -4,8 +4,8 @@ The standard yardstick for "as fast as the hardware allows" is model
 FLOPs utilization — achieved matmul flops over the chip's peak (the
 hardware-utilization accounting popularized by PaLM-scale training
 reports). These helpers are shared by the learn loops' per-iteration
-``throughput/mfu`` estimate and by bench.py (which previously kept its
-own copies); one formula, one place.
+``throughput/mfu`` estimate and the serve engine's KV sizing; one
+formula, one place.
 
 All estimates count matmul flops only and exclude the attention
 quadratic terms (negligible against the projections at the short
@@ -75,9 +75,8 @@ def kv_bytes_per_token(spec, kv_dtype: str = "bf16") -> int:
     ``bf16``: k+v, each ``head_dim`` 2-byte elements per kv-head per
     layer. ``int8`` (serve.kv_dtype): ``head_dim`` 1-byte codes plus one
     f32 scale per (token, kv-head) — the quantize_kv layout. The single
-    source of truth for pool sizing: slots.pool_stats, the
-    ``serve/kv_bytes_per_token`` gauge, and bench.py's slots-per-GB /
-    HBM-precheck accounting all read this.
+    source of truth for pool sizing: slots.pool_stats and the
+    ``serve/kv_bytes_per_token`` gauge both read this.
     """
     per_head = (
         spec.head_dim + 4 if kv_dtype == "int8" else 2 * spec.head_dim

@@ -13,9 +13,9 @@ Where the cache lives is the operator's call, not the program's:
   not move between runs (no tempfile, pid or time in the path) and must
   not depend on the working directory. ``.gitignore`` lists it.
 
-Called by the entry points only (``chip_smoke.py``, ``bench.py``,
-``python -m trlx_tpu.serve``, ``examples/*.py``, ``__graft_entry__.py``)
-and never on ``import trlx_tpu``: a library import must not start
+Called by the entry points only (``chip_smoke.py``,
+``benchmarks/run.py``, ``python -m trlx_tpu.serve``, ``examples/*.py``,
+``__graft_entry__.py``) and never on ``import trlx_tpu``: a library import must not start
 writing files. Tests stay cache-less (tests/conftest.py).
 """
 
