@@ -420,6 +420,74 @@ def _mosaic_compiled(fn, *args):
     return compiled
 
 
+def _check_paged(label, S, H, Hkv, hd, page_size, max_pages, num_pages,
+                 lengths, tiers) -> None:
+    """The paged decode kernel, compiled by Mosaic, against the jnp
+    gather + score path on one random pool: ``lengths`` [S] visible
+    positions a slot, its table sentinel past them."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from trlx_tpu.models.transformer import (
+        attention_scores,
+        dequantize_kv,
+        quantize_kv,
+    )
+    from trlx_tpu.ops.paged_attention import (
+        block_plan,
+        paged_decode_attention,
+    )
+
+    rng = np.random.default_rng(page_size + hd)
+    kq, kk_, kv_ = jax.random.split(jax.random.PRNGKey(hd), 3)
+    q1 = jax.random.normal(kq, (S, H, hd), jnp.bfloat16)
+    pool_shape = (num_pages, page_size, Hkv, hd)
+    k_pool = jax.random.normal(kk_, pool_shape, jnp.bfloat16)
+    v_pool = jax.random.normal(kv_, pool_shape, jnp.bfloat16)
+    table = rng.integers(0, num_pages, size=(S, max_pages)).astype(np.int32)
+    need = -(-np.asarray(lengths) // page_size)
+    table[np.arange(max_pages)[None, :] >= need[:, None]] = 2**30
+    T_buf = max_pages * page_size
+    bias = jnp.where(
+        jnp.arange(T_buf)[None, :] < jnp.asarray(lengths)[:, None],
+        0.0, -1e9,
+    ).astype(jnp.float32)
+    table = jnp.asarray(table)
+
+    def jnp_path(q1, k_pool, v_pool):
+        ctx = jnp.clip(table, 0, num_pages - 1)
+        k_ctx = k_pool[ctx].reshape(S, T_buf, Hkv, hd)
+        v_ctx = v_pool[ctx].reshape(S, T_buf, Hkv, hd)
+        return attention_scores(
+            q1[:, None], k_ctx, v_ctx, bias[:, None, None, :]
+        )[:, 0]
+
+    for tier in tiers:
+        if tier == "int8":
+            k_in, v_in = quantize_kv(k_pool), quantize_kv(v_pool)
+            k_ref = dequantize_kv(*k_in, jnp.bfloat16)
+            v_ref = dequantize_kv(*v_in, jnp.bfloat16)
+        else:
+            k_in, v_in, k_ref, v_ref = k_pool, v_pool, k_pool, v_pool
+
+        def kernel(q1, k_in, v_in):
+            return paged_decode_attention(q1, k_in, v_in, table, bias)
+
+        got = _mosaic_compiled(kernel, q1, k_in, v_in)(q1, k_in, v_in)
+        ref = jax.jit(jnp_path)(q1, k_ref, v_ref)
+        check(bool(jnp.isfinite(got.astype(jnp.float32)).all()),
+              "kernels", f"paged {label} {tier}: non-finite output")
+        err = _rel_err(got, ref)
+        plan = block_plan(pool_shape, jax.tree_util.tree_leaves(k_in)[0].dtype,
+                          max_pages)
+        log(f"kernels: paged {label} {H}q/{Hkv}kv x{hd} {tier} "
+            f"{plan[0]} pages a block x {plan[1]} rel err {err:.1e}")
+        check(err <= KERNEL_REL_TOL, "kernels",
+              f"paged decode {label} {tier}: rel err {err} > "
+              f"{KERNEL_REL_TOL}")
+
+
 def kernels_phase() -> None:
     import jax
     import jax.numpy as jnp
@@ -428,11 +496,8 @@ def kernels_phase() -> None:
     from trlx_tpu.models.transformer import (
         attention_scores,
         causal_mask_bias,
-        dequantize_kv,
-        quantize_kv,
     )
     from trlx_tpu.ops import pallas_mode
-    from trlx_tpu.ops.paged_attention import paged_decode_attention
     from trlx_tpu.ops.pallas_attention import flash_attention
 
     check(not pallas_mode.interpret(), "kernels",
@@ -472,58 +537,21 @@ def kernels_phase() -> None:
 
         # -- paged decode ----------------------------------------------
         for page_size in (64, 16):
-            S, max_pages, num_pages = 16, 4, 80
             rng = np.random.default_rng(page_size + hd)
-            kq, kk_, kv_ = jax.random.split(jax.random.PRNGKey(hd), 3)
-            q1 = jax.random.normal(kq, (S, H, hd), jnp.bfloat16)
-            pool_shape = (num_pages, page_size, Hkv, hd)
-            k_pool = jax.random.normal(kk_, pool_shape, jnp.bfloat16)
-            v_pool = jax.random.normal(kv_, pool_shape, jnp.bfloat16)
-            lengths = rng.integers(1, max_pages * page_size + 1, size=S)
-            table = rng.permutation(num_pages)[:S * max_pages].reshape(
-                S, max_pages
-            ).astype(np.int32)
-            need = -(-lengths // page_size)
-            table[np.arange(max_pages)[None, :] >= need[:, None]] = 2**30
-            T_buf = max_pages * page_size
-            bias = jnp.where(
-                jnp.arange(T_buf)[None, :] < jnp.asarray(lengths)[:, None],
-                0.0, -1e9,
-            ).astype(jnp.float32)
-            table = jnp.asarray(table)
-
-            def jnp_path(q1, k_pool, v_pool):
-                ctx = jnp.clip(table, 0, num_pages - 1)
-                k_ctx = k_pool[ctx].reshape(S, T_buf, Hkv, hd)
-                v_ctx = v_pool[ctx].reshape(S, T_buf, Hkv, hd)
-                return attention_scores(
-                    q1[:, None], k_ctx, v_ctx, bias[:, None, None, :]
-                )[:, 0]
-
-            for tier in ("bf16", "int8"):
-                if tier == "int8":
-                    k_in, v_in = quantize_kv(k_pool), quantize_kv(v_pool)
-                    k_ref = dequantize_kv(*k_in, jnp.bfloat16)
-                    v_ref = dequantize_kv(*v_in, jnp.bfloat16)
-                else:
-                    k_in, v_in, k_ref, v_ref = k_pool, v_pool, k_pool, v_pool
-
-                def kernel(q1, k_in, v_in):
-                    return paged_decode_attention(q1, k_in, v_in, table,
-                                                  bias)
-
-                got = _mosaic_compiled(kernel, q1, k_in, v_in)(
-                    q1, k_in, v_in
-                )
-                ref = jax.jit(jnp_path)(q1, k_ref, v_ref)
-                check(bool(jnp.isfinite(got.astype(jnp.float32)).all()),
-                      "kernels", f"paged {label} {tier}: non-finite output")
-                err = _rel_err(got, ref)
-                log(f"kernels: paged {label} {H}q/{Hkv}kv x{hd} "
-                    f"page_size={page_size} {tier} rel err {err:.1e}")
-                check(err <= KERNEL_REL_TOL, "kernels",
-                      f"paged decode {label} page_size={page_size} "
-                      f"{tier}: rel err {err} > {KERNEL_REL_TOL}")
+            _check_paged(
+                f"{label} page_size={page_size}", 16, H, Hkv, hd, page_size,
+                4, 80, rng.integers(1, 4 * page_size + 1, size=16),
+                ("bf16", "int8"),
+            )
+    # the long-context serve cell's own size: 32 slots, half of them long
+    # sessions, each class of page with its own table and pages a block
+    rng = np.random.default_rng(0)
+    for table, num_pages in ((454, 7168), (66, 2560)):
+        pages = np.stack([rng.integers(190, 451, size=16),
+                          rng.integers(2, 20, size=16)], 1).reshape(-1)
+        lengths = np.minimum(pages, table) * 64 - rng.integers(0, 64, size=32)
+        _check_paged(f"command-a-plus table={table}", 32, 128, 8, 128, 64,
+                     table, num_pages, lengths, ("bf16",))
     _flash_in_train_step()
     _relayout_aot()
 

@@ -102,12 +102,24 @@ def test_train_forward_equals_the_reference(offset, unfrozen):
 
 
 # ------------------------------------------------------------ (b) prefill, then decode through two classes of page
-@pytest.mark.parametrize("attention", ["jnp", "pallas"])
-def test_decode_behind_the_window_equals_the_full_forward(attention):
-    sched = build(attention=attention)
+# 8 kv heads of 128: the narrowest pool the paged decode kernel walks in blocks (ops/paged_attention.folds_pages)
+WIDE_KV = {**SPEC, "n_head": 16, "n_kv_heads": 8, "head_size": 128}
+
+
+@pytest.mark.parametrize("attention, spec", [("jnp", SPEC), ("pallas", SPEC), ("pallas", WIDE_KV)],
+                         ids=["jnp", "pallas", "pallas-blocks"])
+def test_decode_behind_the_window_equals_the_full_forward(attention, spec, monkeypatch):
+    from trlx_tpu.ops import paged_attention
+
+    # 4 pages a block at the wide pool's 32 KiB a page: the full table of 18 in 5 blocks, the ring in one
+    monkeypatch.setattr(paged_attention, "BLOCK_VMEM_BYTES", 4 * 4 * 8 * 8 * 128 * 4)
+    sched = build(spec, attention=attention)
+    if spec is WIDE_KV:
+        k_pages = [kv for seg in sched.runtime.pool for kv in seg][3][0]  # the full layer's
+        assert paged_attention.block_plan(k_pages.shape, k_pages.dtype, sched.runtime.max_pages) == (4, 5)
     prompt = prompt_of(100)  # 6 windows; prefilled in 6 chunks of 16 and a rest of 4
     req, got = run_to_end(sched, prompt, 12)
-    ref = reference_logits(SPEC, prompt, req.result)
+    ref = reference_logits(spec, prompt, req.result)
     assert np.abs(got - ref).max() < POOL_TOL
     assert req.result == [int(t) for t in ref.argmax(-1)]
     cache = sched.cache

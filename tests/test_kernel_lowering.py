@@ -1,6 +1,8 @@
 """Both Pallas kernels lowered for the TPU platform from this CPU host,
 at the head shapes of the presets in trlx_tpu/data/configs.py: 12x64
-(gpt2), 16x256 (gpt-j-6b), 32 q / 8 kv x 128 (llama-3 GQA).
+(gpt2), 16x256 (gpt-j-6b), 32 q / 8 kv x 128 (llama-3 GQA), and the
+paged decode kernel at the long-context serve cell's own size (128 q / 8
+kv x 128, 32 slots, tables of 454 and of 66 pages of 64).
 
 ``interpret=False`` + ``lower(lowering_platforms=("tpu",))`` runs Pallas'
 TPU lowering without a chip, and with it the block rules ("the last two
@@ -51,6 +53,23 @@ def test_paged_decode_lowers_for_tpu(compiled_not_interpreted, H, Hkv, hd,
         pages = (_sds(pool, jnp.int8), _sds(pool[:3], jnp.float32))
     else:
         pages = _sds(pool, jnp.bfloat16)
+    _lower_for_tpu(
+        paged_decode_attention,
+        _sds((S, H, hd), jnp.bfloat16), pages, pages,
+        _sds((S, max_pages), jnp.int32),
+        _sds((S, max_pages * page_size), jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("max_pages,num_pages", [(454, 7168), (66, 2560)],
+                         ids=["full-table-454", "window-ring-66"])
+def test_paged_decode_lowers_at_the_long_context_cells_size(
+        compiled_not_interpreted, max_pages, num_pages):
+    """command-a-plus-05-2026.serve-longshort32's decode attention: 128
+    query heads over 8 kv heads of 128, pages of 64, 32 slots, each class
+    of page with its own table width and so its own pages a block."""
+    S, H, Hkv, hd, page_size = 32, 128, 8, 128, 64
+    pages = _sds((num_pages, page_size, Hkv, hd), jnp.bfloat16)
     _lower_for_tpu(
         paged_decode_attention,
         _sds((S, H, hd), jnp.bfloat16), pages, pages,

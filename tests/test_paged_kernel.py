@@ -139,6 +139,101 @@ def test_make_paged_decode_fn_single_device_is_direct_call():
     )
 
 
+# -- the block walk: P pages a step, (page_size, Hkv) folded into keys -- #
+
+SENT = 2**30  # the host allocator's out-of-pool sentinel
+
+#: per case: (table rows, visible positions per row), at page_size 4 over
+#: tables of 7 walked 3 pages a block (padded to 9); pages are pool ids
+BLOCK_CASES = {
+    # every entry live: the third block holds one real entry and two pads
+    "table-not-a-multiple-of-P": (
+        [[1, 3, 5, 7, 9, 11, 13], [0, 2, 4, 6, 8, 10, 12]],
+        [range(0, 28), range(0, 26)],
+    ),
+    # extents of 4 and 5 entries end inside the second block; one of 1
+    "extent-ends-inside-a-block": (
+        [[1, 3, 5, 7, SENT, SENT, SENT], [0, 2, 4, 6, 8, SENT, SENT],
+         [9, SENT, SENT, SENT, SENT, SENT, SENT]],
+        [range(0, 14), range(0, 20), range(0, 2)],
+    ),
+    # a sentinel entry between live ones (its positions masked)
+    "sentinel-inside-a-live-block": (
+        [[1, SENT, 5, 7, 9, SENT, SENT], [0, 2, 4, 6, SENT, 10, 12]],
+        [[*range(0, 4), *range(8, 19)], [*range(0, 16), *range(20, 27)]],
+    ),
+    # a free slot between two busy ones: all sentinel, nothing visible
+    "a-row-with-nothing-live": (
+        [[1, 3, 5, 7, 9, 11, 13], [SENT] * 7, [0, 2, 4, 6, SENT, SENT, SENT]],
+        [range(0, 28), [], range(3, 15)],
+    ),
+    # a ring written past its end: live entries on both sides of a hole
+    # of stale pages, the newest page and the oldest both partly seen
+    "wrapped-ring": (
+        [[1, 3, 5, 7, 9, 11, 13], [0, 2, 4, 6, 8, 10, 12]],
+        [[*range(0, 6), *range(21, 28)], [*range(0, 3), *range(14, 28)]],
+    ),
+}
+
+
+@pytest.mark.parametrize("tier", ["compute-dtype", "int8"])
+@pytest.mark.parametrize("G", [1, 4], ids=["G=1", "G=4"])
+@pytest.mark.parametrize("case", list(BLOCK_CASES))
+def test_kernel_matches_jnp_reference_over_blocks(case, G, tier,
+                                                  monkeypatch):
+    """What walking a table in blocks brings, each against the gather +
+    softmax reference: 8 kv heads of 128 (the narrowest pool the block
+    kernel takes; the int8 tier walks the same tables a page a step). A
+    row the bias lets nothing through for reads zeros (the reference
+    averages every masked key there, which no caller reads)."""
+    from trlx_tpu.ops import paged_attention as pa
+
+    table, visible = BLOCK_CASES[case]
+    S, Hkv, hd, page_size, num_pages = len(table), 8, 128, 4, 14
+    max_pages = len(table[0])
+    pool = (num_pages, page_size, Hkv, hd)
+    # 3 pages a block, whatever the budget's default
+    monkeypatch.setattr(pa, "BLOCK_VMEM_BYTES",
+                        4 * 3 * page_size * Hkv * hd * 4)
+    rng = np.random.default_rng(len(case) + G)
+    q = jnp.asarray(rng.standard_normal((S, Hkv * G, hd)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal(pool), jnp.float32)
+    v = jnp.asarray(rng.standard_normal(pool), jnp.float32)
+    bias = np.full((S, max_pages * page_size), NEG_INF, np.float32)
+    for row, seen in enumerate(visible):
+        bias[row, list(seen)] = 0.0
+    pt, bias = jnp.asarray(table, jnp.int32), jnp.asarray(bias)
+    if tier == "int8":
+        assert pa.block_plan(pool, jnp.int8, max_pages) == (1, max_pages)
+        k_in, v_in = quantize_kv(k), quantize_kv(v)
+        k, v = (dequantize_kv(*k_in, jnp.float32),
+                dequantize_kv(*v_in, jnp.float32))
+    else:
+        assert pa.block_plan(pool, k.dtype, max_pages) == (3, 3)
+        k_in, v_in = k, v
+    out = np.asarray(jax.jit(paged_decode_attention)(q, k_in, v_in, pt, bias))
+    ref = np.asarray(_jnp_paged_reference(q, k, v, pt, bias))
+    for row, seen in enumerate(visible):
+        if len(seen):
+            np.testing.assert_allclose(out[row], ref[row], atol=2e-5)
+        else:
+            assert not out[row].any()
+
+
+def test_block_plan_follows_the_pool_it_is_given():
+    """Pages a block from the page's bytes against one VMEM budget, evened
+    over the table; the pools Mosaic cannot slice by page walk pages."""
+    from trlx_tpu.ops.paged_attention import block_plan
+
+    cell3 = (7168, 64, 8, 128)  # 128 KiB a page and operand in bfloat16
+    assert block_plan(cell3, jnp.bfloat16, 454) == (8, 57)
+    assert block_plan((2560, *cell3[1:]), jnp.bfloat16, 66) == (8, 9)
+    assert block_plan(cell3, jnp.bfloat16, 4) == (4, 1)  # at most the table
+    assert block_plan((96, 64, 16, 256), jnp.bfloat16, 6) == (2, 3)  # gpt-j
+    assert block_plan((96, 64, 16, 256), jnp.int8, 6) == (1, 6)
+    assert block_plan((512, 64, 25, 64), jnp.bfloat16, 8) == (1, 8)  # gpt2-xl
+
+
 # --------------------------------------------------------------------- #
 # e2e: serve.attention pallas — greedy parity vs one-shot generate()
 # --------------------------------------------------------------------- #
@@ -184,6 +279,13 @@ def test_pallas_engine_greedy_parity_sweep(page_size, fresh_registry):
                 f"page_size={page_size} under the pallas kernel"
             )
         assert registry.counters.get("compile/recompiles", 0.0) == 0.0
+        # warm-up named what the kernel chose for this pool: 4 heads of
+        # 16 fill no tile, so a page a grid step
+        rt = s.runtime
+        assert registry.gauges[
+            "serve/paged_attn_pages_per_block{class=full}"] == 1.0
+        assert registry.gauges["serve/paged_attn_grid_steps{class=full}"] \
+            == rt.num_slots * rt.max_pages
         if page_size < 8:
             assert registry.counters["serve/prefix_tokens_saved"] > 0
         assert s.free_slots() == s.runtime.num_slots

@@ -206,6 +206,46 @@ def test_kernels_compile_under_mosaic_for_a_v5e_from_this_host(
     )
 
 
+@pytest.mark.parametrize("max_pages,num_pages", [(454, 7168), (66, 2560)],
+                         ids=["full-table-454", "window-ring-66"])
+def test_paged_decode_compiles_at_the_long_context_cells_size(
+        on_chip, monkeypatch, max_pages, num_pages):
+    """The block walk as command-a-plus-05-2026.serve-longshort32 runs it
+    (128 query heads over 8 kv heads of 128, pages of 64, 32 slots, the
+    pool left in HBM and fetched a block of pages ahead), through Mosaic
+    for a v5e: the sizes the small cases above never reach (VMEM for 8
+    pages of K and V twice over, the fold of 64 x 8 keys, a table of 454
+    in scalar memory)."""
+    import jax
+    import jax.numpy as jnp
+
+    from trlx_tpu.ops import pallas_mode
+    from trlx_tpu.ops.paged_attention import (
+        block_plan,
+        paged_decode_attention,
+    )
+
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    S, H, Hkv, hd, page_size = 32, 128, 8, 128, 64
+    pool = (num_pages, page_size, Hkv, hd)
+    assert block_plan(pool, jnp.bfloat16, max_pages)[0] > 1
+    pages = sds(pool, jnp.bfloat16)
+    with jax.default_matmul_precision("default"):  # as on the chip
+        text = jax.jit(paged_decode_attention).lower(
+            sds((S, H, hd), jnp.bfloat16), pages, pages,
+            sds((S, max_pages), jnp.int32),
+            sds((S, max_pages * page_size), jnp.float32),
+        ).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the pools reach the kernel as they are stored: no copy of either
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and f"[{num_pages}," in line]
+
+
 # -- what XLA:TPU makes of the decode step's projections ------------------ #
 
 

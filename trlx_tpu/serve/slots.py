@@ -547,8 +547,43 @@ class SlotPoolRuntime:
         )
         telemetry.set_gauge("serve/decode_weight_copy_bytes", moved)
         said.append(f"{moved / 2**30:.3f} GiB of weight-sized copies")
+        if e.serve.attention == "pallas":
+            said += self._report_paged_walk()
         print(f"[trlx_tpu.serve] decode step: {', '.join(said)}",
               file=sys.stderr, flush=True)
+
+    def _report_paged_walk(self) -> list:
+        """What the paged decode kernel chose for each class of page, from
+        the pool a device holds and the table's width: the pages it
+        fetches and scores a block
+        (``serve/paged_attn_pages_per_block{class=}``; 1 = a pool it
+        walks a page a grid step) and the grid steps of one layer's call
+        (``serve/paged_attn_grid_steps{class=}``)."""
+        import jax
+
+        from trlx_tpu.ops.paged_attention import block_plan, grid_steps
+
+        spec = self.engine.spec
+        layers = [kv for seg in self.pool for kv in seg]
+        tables = {"full": self.max_pages, "window": self.ring_pages}
+        said = []
+        for kind in spec.page_classes:
+            k_pages = next(kv[0] for i, kv in enumerate(layers)
+                           if spec.layer_kind(i) == kind)
+            codes = jax.tree_util.tree_leaves(k_pages)[0]
+            shape = codes.sharding.shard_shape(codes.shape)
+            pages, blocks = block_plan(shape, codes.dtype, tables[kind])
+            steps = grid_steps(shape, codes.dtype, self.num_slots,
+                               tables[kind])
+            labels = {"class": kind}
+            telemetry.set_gauge("serve/paged_attn_pages_per_block", pages,
+                                labels)
+            telemetry.set_gauge("serve/paged_attn_grid_steps", steps, labels)
+            said.append(
+                f"{kind} pages walked {pages} a block, {blocks} blocks a "
+                f"table of {tables[kind]}, {steps} grid steps a call"
+            )
+        return said
 
     def warmup(self) -> Dict[str, float]:
         """Compile every admission bucket + the decode step up front.
