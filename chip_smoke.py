@@ -488,6 +488,53 @@ def _check_paged(label, S, H, Hkv, hd, page_size, max_pages, num_pages,
               f"{KERNEL_REL_TOL}")
 
 
+def _check_latent() -> None:
+    """The absorbed latent decode kernel at the shared-documents cell's own
+    size (64 query rows of 640 over pages of 64 tokens, a table of 648)
+    against the jnp absorbed form: a row of a whole table, rows that end
+    inside a block, a row with nothing live."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from trlx_tpu.ops.latent_attention import (
+        NEG_INF,
+        block_plan,
+        latent_decode_attention,
+    )
+
+    S, H, W, r, page_size, table, num_pages = 8, 64, 640, 512, 64, 648, 2048
+    rng = np.random.default_rng(0)
+    lengths = np.array([table * 64, 40_000, 16_385, 1, 0, 24_576, 25, 3_000])
+    pages = jax.random.normal(jax.random.PRNGKey(0),
+                              (num_pages, page_size, W), jnp.bfloat16)
+    q = jax.random.normal(jax.random.PRNGKey(1), (S, H, W), jnp.bfloat16)
+    ids = rng.integers(0, num_pages, size=(S, table)).astype(np.int32)
+    for s, n in enumerate(lengths):
+        ids[s, -(-n // page_size):] = num_pages  # the sentinel
+    bias = jnp.where(jnp.arange(table * page_size)[None, :]
+                     < lengths[:, None], 0.0, NEG_INF).astype(jnp.float32)
+    ids = jnp.asarray(ids)
+
+    def kernel(q, pages):
+        return latent_decode_attention(q, pages, ids, bias, r, 0.135)
+
+    def jnp_path(q, pages):
+        lat = pages[jnp.clip(ids, 0, num_pages - 1)].reshape(S, -1, W)
+        s = jnp.einsum("shw,skw->shk", q, lat).astype(jnp.float32)
+        p = jax.nn.softmax(s * 0.135 + bias[:, None, :], -1)
+        out = jnp.einsum("shk,skr->shr", p.astype(lat.dtype), lat[..., :r])
+        return jnp.where((lengths > 0)[:, None, None], out, 0)
+
+    got = _mosaic_compiled(kernel, q, pages)(q, pages)
+    err = _rel_err(got, jax.jit(jnp_path)(q, pages))
+    plan = block_plan(pages.shape, pages.dtype, table)
+    log(f"kernels: latent decode 64x640 table={table}: {plan[0]} pages a "
+        f"block x {plan[1]} rel err {err:.1e}")
+    check(err <= KERNEL_REL_TOL, "kernels",
+          f"latent decode: rel err {err} > {KERNEL_REL_TOL}")
+
+
 def kernels_phase() -> None:
     import jax
     import jax.numpy as jnp
@@ -552,6 +599,7 @@ def kernels_phase() -> None:
         lengths = np.minimum(pages, table) * 64 - rng.integers(0, 64, size=32)
         _check_paged(f"command-a-plus table={table}", 32, 128, 8, 128, 64,
                      table, num_pages, lengths, ("bf16",))
+    _check_latent()
     _flash_in_train_step()
     _relayout_aot()
 

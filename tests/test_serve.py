@@ -581,9 +581,9 @@ def test_accepted_serve_cells_load_as_they_are(cell, block):
 
     data = json.loads((REPO / "benchmarks" / "workloads" / cell).read_text())
     section = data["serve"] if block == "serve" else data["rehearse"]["serve"]
-    if block == "serve":
-        assert section["scheduler"] == "slots"
-        assert section["kv_layout"] == "paged"
+    if block == "serve":  # where a file still names them (the cells of before PR 31 do)
+        assert section.get("scheduler", "slots") == "slots"
+        assert section.get("kv_layout", "paged") == "paged"
     cfg = ServeConfig.from_dict(section)
     fields = {f.name for f in dataclasses.fields(ServeConfig)}
     for key, value in section.items():

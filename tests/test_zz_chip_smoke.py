@@ -319,3 +319,74 @@ def test_decode_step_streams_its_qkv_weights_as_stored(
     folded = moves()
     assert len(folded) >= 3, folded  # q, k and v, in every layer
     assert all("attn" in m.op_name or not m.op_name for m in folded), folded
+
+
+def test_latent_decode_step_compiles_for_a_v5e_and_streams_its_projections(
+        on_chip, monkeypatch):
+    """The decode step of a latent-attention model at the published
+    attention widths (64 heads of 128 + 64 / 128 over a latent of 512 +
+    64), compiled for a v5e with the absorbed Pallas kernel: Mosaic takes
+    the latent page (a latent of 576 kept out to 640: at 576 it says
+    "Slice shape along dimension 2 must be aligned to tiling (128)"), the
+    pool is written in place, and none of the five projections is written
+    out again in another layout (``w_uk`` / ``w_uv`` are kept by head for
+    that: as [r, H * dn] matrices each was re-laid, 8 MiB a layer, for the
+    absorption's small dots)."""
+    import jax
+    import jax.numpy as jnp
+
+    from trlx_tpu.data.configs import ModelSpec
+    from trlx_tpu.models import generation as G
+    from trlx_tpu.models import transformer as T
+    from trlx_tpu.ops import pallas_mode
+    from trlx_tpu.ops.latent_attention import latent_decode_attention
+    from trlx_tpu.utils.hlo_text import large_moves
+
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    spec = ModelSpec(
+        arch="sarvam_mla", vocab_size=512, n_layer=2, n_head=64,
+        d_model=4096, d_ff=256, n_positions=4096, tie_lm_head=False,
+        layer_norm_epsilon=1e-6, n_experts=16, experts_per_token=2,
+        n_shared_experts=1, expert_width=128, experts_held=4,
+        router_bias=True, routed_scaling_factor=2.5, first_dense_layers=1,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, rope_factor=40.0, rope_mscale_all_dim=1.0,
+        rope_original_positions=4096,
+    )
+    assert spec.latent_page_width == 640
+    rows, page_size, max_pages, num_pages = 32, 64, 40, 600
+    config = G.GenerationConfig(
+        gen_size=1, sampling=G.SamplingParams(do_sample=False),
+        eos_token_id=256, pad_token_id=0, min_new_tokens=0,
+    )
+
+    def arguments():
+        blocks = T.init_block_params(jax.random.PRNGKey(0), spec, 2,
+                                     jnp.bfloat16)
+        embed = T.init_embed_params(jax.random.PRNGKey(1), spec, jnp.bfloat16)
+        pool = G.init_page_pool(spec, [1, 1], num_pages, page_size)
+        state = G.init_slot_state(rows, max_pages * page_size,
+                                  spec.vocab_size, max_pages=max_pages)
+        return (blocks, embed, T.init_ln_f_params(spec, jnp.bfloat16), pool,
+                state, jnp.int32(0))
+
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip),
+        jax.eval_shape(arguments),
+    )
+
+    def run_decode_step(blocks, embed, ln_f, pool, state, seed):
+        return G.decode_step(spec, blocks, embed, ln_f, pool, state, seed,
+                             config, compute_dtype=jnp.bfloat16,
+                             paged_decode_fn=latent_decode_attention)
+
+    with jax.default_matmul_precision("default"):  # as on the chip
+        compiled = jax.jit(run_decode_step, donate_argnums=(3, 4)).lower(
+            *args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 2
+    assert "latent_decode_attention" in text
+    smallest = 4096 * (512 + 64) * 2  # w_dkv: the smallest of the five
+    assert large_moves(text, smallest) == []
+    pool_bytes = 2 * num_pages * page_size * 640 * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes

@@ -29,7 +29,7 @@ class ModelSpec:
     contract when importing pretrained HF weights.
     """
 
-    arch: str = "gpt2"  # gpt2 | gptj | gptneox | llama | cohere2_moe
+    arch: str = "gpt2"  # gpt2 | gptj | gptneox | llama | cohere2_moe | sarvam_mla
     vocab_size: int = 50257
     n_layer: int = 12
     n_head: int = 12
@@ -62,6 +62,32 @@ class ModelSpec:
     experts_held: int = 0
     expert_offset: int = 0
     logit_scale: float = 1.0
+    # The router's extras (DeepSeek-V3's): a per-expert bias added to the
+    # scores for the CHOICE of the top experts alone (the gates weigh by
+    # the scores), and a factor on the normalised gates. The first
+    # ``first_dense_layers`` layers keep a dense FFN of width ``d_ff``.
+    router_bias: bool = False
+    routed_scaling_factor: float = 1.0
+    first_dense_layers: int = 0
+    # Latent attention (MLA; 0 = per-head K and V): the cache holds, a
+    # token a layer, one latent of ``kv_lora_rank`` and one rotated key
+    # part of ``qk_rope_head_dim`` shared by all heads; a head scores with
+    # ``qk_nope_head_dim + qk_rope_head_dim`` and reads values of
+    # ``v_head_dim``.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN ("deepseek_yarn"; factor 0 = plain RoPE): the rotated
+    # frequencies are blended between theta^(-2k/d) and the same over
+    # ``rope_factor``, by where each falls between ``rope_beta_fast`` and
+    # ``rope_beta_slow`` turns over ``rope_original_positions``; the
+    # score scale carries mscale(factor, rope_mscale_all_dim) squared.
+    rope_factor: float = 0.0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    rope_original_positions: int = 0
 
     def __post_init__(self):
         if self.d_ff == 0:
@@ -81,6 +107,20 @@ class ModelSpec:
                 )
         if self.d_model % self.n_head != 0:
             raise ValueError("d_model must be divisible by n_head")
+        if self.kv_lora_rank:
+            if min(self.qk_nope_head_dim, self.qk_rope_head_dim,
+                   self.v_head_dim) <= 0 or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "latent attention needs qk_nope_head_dim, v_head_dim "
+                    "and an even qk_rope_head_dim"
+                )
+            if self.layer_pattern or self.n_kv_heads:
+                raise ValueError(
+                    "latent attention keeps one class of page and no "
+                    "kv heads: leave layer_pattern and n_kv_heads unset"
+                )
+        if not 0 <= self.first_dense_layers <= self.n_layer:
+            raise ValueError("first_dense_layers must be in 0..n_layer")
         if self.n_kv_heads and self.n_head % self.n_kv_heads != 0:
             raise ValueError("n_head must be divisible by n_kv_heads")
         if any(k not in ("full", "window") for k in self.layer_pattern):
@@ -117,6 +157,24 @@ class ModelSpec:
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_head
+
+    @property
+    def latent_width(self) -> int:
+        """Numbers a token a layer a latent page holds: the latent and the
+        shared rotated key part (0: per-head K and V)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim \
+            if self.kv_lora_rank else 0
+
+    @property
+    def latent_page_width(self) -> int:
+        """Numbers a token of a latent PAGE: ``latent_width`` out to whole
+        lane tiles of 128 (576 -> 640, the tail zero). The TPU lays the
+        last dimension of a buffer out so whether asked or not, and the
+        decode kernel can slice a page out of the pool only if it is asked
+        (Mosaic: "Slice shape along dimension 2 must be aligned to tiling
+        (128), but is 576"; the operand's memref is the padded 640)."""
+        return -(-self.latent_width // 128) * 128
+
 
     @classmethod
     def from_dict(cls, config: Dict[str, Any]) -> "ModelSpec":

@@ -39,6 +39,7 @@ from trlx_tpu.models.transformer import (
     ArchFlags,
     NEG_INF,
     _mixed_layers,
+    require_supported,
     apply_blocks_with_cache,
     attention_scores,
     block_apply,
@@ -304,6 +305,7 @@ def generate(
             f"'{spec.arch}' mixes {spec.layer_pattern} layers and is served "
             f"through the paged slot pool (trlx_tpu.serve.slots)"
         )
+    require_supported(spec, rollout_cache="contiguous")
     if S > spec.n_positions:
         raise ValueError(
             f"prompt ({P}) + gen_size ({G}) = {S} exceeds the model's "
@@ -655,7 +657,9 @@ def init_page_pool(spec: ModelSpec, seg_sizes, num_pages,
     """PAGE pool: per segment, per layer, (k, v) pages [num_pages,
     page_size, Hkv, hd]: HBM is sized in pages shared by all slots, not
     slots x worst-case length. The int8 tier makes each of k/v a ``(codes,
-    scales)`` pair (transformer.init_paged_kv_cache).
+    scales)`` pair (transformer.init_paged_kv_cache). A latent-attention
+    model's entry is ONE array of latent pages [num_pages, page_size,
+    latent_page_width]: there is no V buffer.
 
     ``num_pages`` is one count, or ``{class: count}`` for a model whose
     layers differ: every layer of a kind keeps ``num_pages[kind]`` pages,
@@ -924,6 +928,21 @@ def prefill_into_slots(
             },
             mask_bias=None, positions=positions, cache_row_offsets=start,
             page_size=page_size, attention_fn=attention_fn,
+            token_mask=prompt_mask > 0, moe_stats=moe_stats,
+        )
+    elif spec.kv_lora_rank:
+        # latent pages: every layer writes its latents through the one
+        # table and reads them back in blocks (latent.attend_pages), in
+        # the order the static chunk length picks
+        if not prefix_context:
+            raise ValueError(
+                "a latent-attention model is prefilled through the "
+                "prefix-context program (there is no local K/V buffer)"
+            )
+        new_pool, h = _apply_layers_with_pool(
+            spec, segments, seg_sizes, pool, h,
+            mask_bias=None, positions=positions, cache_row_offsets=start,
+            page_table=page_tables, page_size=page_size,
             token_mask=prompt_mask > 0, moe_stats=moe_stats,
         )
     elif not prefix_context:
@@ -1252,8 +1271,8 @@ def decode_step(
     page ``n`` sits at entry ``n % R``. Its validity lane is computed
     here from the slot's write position, so what lies outside the window
     is masked and the attention programs see a short table and no window
-    argument. Such a model also returns its routing counts, ``moe_stats``
-    [L, 4], as a sixth output.
+    argument. A model with routed experts also returns its routing counts,
+    ``moe_stats`` [layers with experts, 4], as a sixth output.
     """
     S = state.offset.shape[0]
     segments, seg_sizes = _segments_of(blocks)
@@ -1338,6 +1357,7 @@ def decode_step(
             mask_bias=bias, positions=pos, cache_row_offsets=state.offset,
             page_table=pt_step, page_size=page_size,
             attention_fn=attention_fn, paged_decode_fn=paged_decode_fn,
+            token_mask=emitted[:, None], moe_stats=moe_stats,
         )
     with jax.named_scope("head"):
         h_normed = layer_norm(ln_f, h, spec.layer_norm_epsilon)

@@ -95,7 +95,7 @@ def spec_from_hf_config(hf_config) -> ModelSpec:
             n_kv_heads=hf_config.num_key_value_heads,
             rope_theta=getattr(hf_config, "rope_theta", 10000.0),
         )
-    if mt == "cohere2_moe":
+    if mt in ("cohere2_moe", "sarvam_mla"):
         from trlx_tpu.models.transformer import require_supported
 
         require_supported(

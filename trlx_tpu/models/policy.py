@@ -44,6 +44,7 @@ from trlx_tpu.models.transformer import (
     mask_arg_for,
     positions_from_mask,
     project_logits,
+    slice_layers,
 )
 
 Params = Dict[str, Any]
@@ -112,8 +113,8 @@ class HydraPolicy:
         k_embed, k_blocks, k_head = jax.random.split(rng, 3)
         embed = init_embed_params(k_embed, spec, param_dtype)
         blocks = init_block_params(k_blocks, spec, spec.n_layer, param_dtype)
-        bottom = jax.tree_util.tree_map(lambda x: x[: spec.n_layer - k], blocks)
-        top = jax.tree_util.tree_map(lambda x: x[spec.n_layer - k :], blocks)
+        bottom = slice_layers(blocks, 0, spec.n_layer - k)
+        top = slice_layers(blocks, spec.n_layer - k, spec.n_layer)
         ln_f = init_ln_f_params(spec, param_dtype)
 
         lm_head = embed.pop("lm_head", None)
@@ -260,8 +261,10 @@ class HydraPolicy:
     # -- decode support -----------------------------------------------------
 
     def all_blocks(self, params: Params) -> Params:
-        """(bottom, trainable top) stacked-segment pair — the live policy
-        the decode engine runs in order. Deliberately NOT concatenated:
+        """(bottom, trainable top) stacked segments — the live policy
+        the decode engine runs in order (a branch whose layers are not all
+        alike is itself a tuple of segments, and they are listed flat).
+        Deliberately NOT concatenated:
         inside a jitted rollout the concat materializes a full copy of
         the trunk as an HLO temp (~10 GB at gpt-j-6B — the single-chip
         OOM bench_gptj6b_train hit); generate() consumes the segments
@@ -273,7 +276,8 @@ class HydraPolicy:
         top = jax.tree_util.tree_map(
             lambda b: b.astype(frozen_dtype), params["trainable"]["blocks"]
         )
-        return (bottom, top)
+        flat = lambda b: tuple(b) if isinstance(b, (tuple, list)) else (b,)
+        return (*flat(bottom), *flat(top))
 
     def head_params_for_decode(self, params: Params) -> Tuple[Params, Params]:
         """(embed+lm_head dict, ln_f) for the live policy branch."""

@@ -24,6 +24,13 @@ Architecture variants (selected by ModelSpec.arch):
   attention with an explicit head size, window (RoPE) and full (NoPE)
   layers by ``ModelSpec.layer_pattern``, a routed + shared expert FFN,
   tied head times ``logit_scale``.
+- "sarvam_mla": sequential RMSNorm block, latent attention
+  (:mod:`trlx_tpu.models.latent`: one latent a token in the cache, an
+  up-projected and an absorbed order), ``first_dense_layers`` SwiGLU
+  layers and then routed experts chosen through a bias, one shared
+  expert, untied head. Its trunk is a dense segment followed by an
+  expert segment: leaves of different shape cannot share one stacked
+  tree.
 
 A block is three parts, one small function per kind: the **mixer**
 (:func:`_qkv`: projections, RoPE or none; the window is the caller's mask),
@@ -38,6 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from trlx_tpu.data.configs import ModelSpec
+from trlx_tpu.models import latent as L
 
 Params = Dict[str, Any]
 
@@ -57,6 +65,7 @@ class ArchFlags:
     swiglu: bool = False  # llama: silu(gate) * up MLP instead of gelu
     centred_scale_norm: bool = False  # cohere: LayerNorm without a bias
     moe: bool = False  # routed + shared experts in the FFN's place
+    latent: bool = False  # latent attention (models/latent.py)
 
     @classmethod
     def for_spec(cls, spec: ModelSpec) -> "ArchFlags":
@@ -74,6 +83,13 @@ class ArchFlags:
                 raise ValueError("arch 'cohere2_moe' needs n_experts > 0")
             return cls(True, True, False, False, rotary_interleaved=True,
                        centred_scale_norm=True, moe=True)
+        if arch == "sarvam_mla":
+            if not (spec.n_experts and spec.kv_lora_rank):
+                raise ValueError(
+                    "arch 'sarvam_mla' needs n_experts > 0 and kv_lora_rank"
+                )
+            return cls(False, True, False, True, rotary_interleaved=True,
+                       rmsnorm=True, swiglu=True, moe=True, latent=True)
         raise ValueError(f"unknown arch '{spec.arch}'")
 
 
@@ -87,11 +103,25 @@ def _dense_init(rng, shape, dtype, scale=0.02):
 
 
 def init_block_params(
-    rng: jax.Array, spec: ModelSpec, n_layers: int, dtype=jnp.float32
+    rng: jax.Array, spec: ModelSpec, n_layers: int, dtype=jnp.float32,
+    first_layer: int = 0,
 ) -> Params:
-    """Stacked parameters for `n_layers` transformer blocks: every leaf has
-    leading axis `n_layers`."""
+    """Stacked parameters for `n_layers` transformer blocks from depth
+    ``first_layer`` on: every leaf has leading axis `n_layers`. Where the
+    layers are not all alike (a model's leading dense layers before its
+    expert layers) the result is a tuple of such trees, one per run of
+    like layers, in order (:func:`slice_layers`, :func:`apply_blocks` and
+    the serve programs' segments take either)."""
     flags = ArchFlags.for_spec(spec)
+    dense_here = min(max(spec.first_dense_layers - first_layer, 0), n_layers)
+    if flags.moe and 0 < dense_here < n_layers:
+        k_dense, k_moe = jax.random.split(rng)
+        return (
+            init_block_params(k_dense, spec, dense_here, dtype, first_layer),
+            init_block_params(k_moe, spec, n_layers - dense_here, dtype,
+                              first_layer + dense_here),
+        )
+    moe_here = flags.moe and not dense_here
     d, f = spec.d_model, spec.d_ff
     d_q = spec.n_head * spec.head_dim  # == d unless the head size is explicit
     d_kv = spec.kv_heads * spec.head_dim  # < d under grouped-query attn
@@ -111,20 +141,31 @@ def init_block_params(
             p["bias"] = jnp.zeros((n_layers, d), dtype)
         return p
 
-    blocks: Params = {
-        "ln_1": norm_params(),
-        "attn": {
-            "wq": stack(lambda k, s: _dense_init(k, s, dtype), (d, d_q), keys[0]),
-            "wk": stack(lambda k, s: _dense_init(k, s, dtype), (d, d_kv), keys[1]),
-            "wv": stack(lambda k, s: _dense_init(k, s, dtype), (d, d_kv), keys[2]),
-            "wo": stack(
-                lambda k, s: _dense_init(k, s, dtype, resid_scale), (d_q, d), keys[3]
+    if flags.latent:
+        blocks: Params = {
+            "ln_1": norm_params(),
+            "attn": L.init_attn_params(
+                jax.random.split(keys[0], 5), spec, n_layers, dtype,
+                _dense_init, resid_scale,
             ),
-        },
-    }
-    if flags.moe:
+        }
+    else:
+        blocks = {
+            "ln_1": norm_params(),
+            "attn": {
+                "wq": stack(lambda k, s: _dense_init(k, s, dtype), (d, d_q), keys[0]),
+                "wk": stack(lambda k, s: _dense_init(k, s, dtype), (d, d_kv), keys[1]),
+                "wv": stack(lambda k, s: _dense_init(k, s, dtype), (d, d_kv), keys[2]),
+                "wo": stack(
+                    lambda k, s: _dense_init(k, s, dtype, resid_scale), (d_q, d), keys[3]
+                ),
+            },
+        }
+    if moe_here:
         blocks.update(_init_moe_params(keys[4:8], spec, n_layers, dtype,
                                        resid_scale))
+        if flags.separate_mlp_ln:  # a sequential block norms its FFN's input
+            blocks["ln_2"] = norm_params()
         return blocks
     blocks["mlp"] = {
         "w_in": stack(lambda k, s: _dense_init(k, s, dtype), (d, f), keys[4]),
@@ -172,6 +213,9 @@ def _init_moe_params(keys, spec: ModelSpec, n_layers: int, dtype,
         "w_up": normal(ke[1], (held, d, f)),
         "w_down": normal(ke[2], (held, f, d), resid_scale),
     }}
+    if spec.router_bias:
+        out["moe"]["router_bias"] = jnp.zeros((n_layers, spec.n_experts),
+                                              jnp.float32)
     if spec.n_shared_experts:
         out["shared"] = {
             "w_gate": normal(ks[0], (d, fs)),
@@ -480,7 +524,9 @@ def moe_ffn(spec: ModelSpec, p: Params, x, token_mask=None):
 
     The router scores ALL ``n_experts`` in float32 (sigmoid), takes the
     top ``experts_per_token`` and normalises their scores over all of
-    those chosen. This process holds experts ``[expert_offset,
+    those chosen; a ``router_bias`` [E] (float32, beside the router) is
+    added to the scores for the CHOICE alone and the gates weigh by the
+    scores themselves, times ``routed_scaling_factor``. This process holds experts ``[expert_offset,
     expert_offset + experts_held)``: the (token, expert) pairs whose
     expert lies there are sorted by expert and computed as one grouped
     product over the experts held (``jax.lax.ragged_dot``: a native grouped
@@ -490,7 +536,8 @@ def moe_ffn(spec: ModelSpec, p: Params, x, token_mask=None):
     added is left out. ``token_mask`` [B, T] takes padding and idle rows
     out of the pairs. The shared experts run on every token as one SwiGLU
     of width ``n_shared * width`` whose output is divided by ``n_shared``:
-    the average of the shared experts' outputs.
+    the average of the shared experts' outputs (one shared expert is
+    added as it is: the mean over one is the same number).
 
     stats: int32/float32 scalars (pairs_here, experts_hit, load_max,
     load_mean) of this call, for the scheduler's counters."""
@@ -503,8 +550,16 @@ def moe_ffn(spec: ModelSpec, p: Params, x, token_mask=None):
         scores = jax.nn.sigmoid(
             xf.astype(jnp.float32) @ mp["router"].astype(jnp.float32)
         )  # [N, E]
-        top_s, top_e = jax.lax.top_k(scores, K)  # [N, K]
+        if "router_bias" in mp:  # chooses, does not weigh
+            _, top_e = jax.lax.top_k(
+                scores + mp["router_bias"].astype(jnp.float32), K
+            )
+            top_s = jnp.take_along_axis(scores, top_e, axis=-1)
+        else:
+            top_s, top_e = jax.lax.top_k(scores, K)  # [N, K]
         gates = top_s / top_s.sum(-1, keepdims=True)
+        if spec.routed_scaling_factor != 1.0:
+            gates = gates * spec.routed_scaling_factor
     with jax.named_scope("experts"):
         local = top_e - off
         here = (local >= 0) & (local < held)
@@ -613,6 +668,18 @@ def block_apply(
     marks real tokens for the expert FFN; `moe_stats`, a list, receives
     that layer's routing counts.
     """
+    if flags.latent:
+        if paged_attend_fn is not None or page_base is not None or ring \
+                or (kv_cache is not None and page_table is None):
+            raise ValueError(
+                "a latent layer keeps one class of page and no contiguous "
+                "cache: it takes a page table or no cache at all"
+            )
+        return _latent_block_apply(
+            spec, flags, p, h, mask_bias, positions, kv_cache,
+            cache_row_offsets, page_table, page_size, paged_decode_fn,
+            token_mask, moe_stats,
+        )
     B, T, D = h.shape
     H, hd = spec.n_head, spec.head_dim
     Hkv = spec.kv_heads
@@ -714,6 +781,73 @@ def block_apply(
         ), new_cache
 
 
+def _latent_block_apply(spec, flags, p, h, mask_bias, positions, kv_cache,
+                        cache_row_offsets, page_table, page_size,
+                        latent_decode_fn, token_mask, moe_stats):
+    """block_apply for a latent-attention layer (models/latent.py): a
+    sequential RMSNorm block whose cache, when given, is ONE layer's
+    latent pages [num_pages, page_size, latent_page_width] behind a page
+    table.
+    No cache: the up-projected order over the chunk itself (the train
+    forward, ``mask_bias`` [B, 1, T, T]). With pages: the fresh latents
+    are scattered in, then a ``T == 1`` step under ``latent_decode_fn``
+    (ops/latent_attention.latent_decode_attention) runs the absorbed
+    kernel over ``mask_bias``'s validity lane, and everything else reads
+    the pages in blocks (``latent.attend_pages``), causal over the
+    buffer positions ``cache_row_offsets + j``, in the order ``T`` picks.
+    Whether the FFN is dense or routed is the layer's own parameters'."""
+    B, T, _ = h.shape
+    eps = spec.layer_norm_epsilon
+    attn = p["attn"]
+    new_cache = None
+    with jax.named_scope("attn"):
+        x = layer_norm(p["ln_1"], h, eps)
+        qn, qr, latent = L.project(
+            spec, attn, x, positions, lambda q, y: layer_norm(q, y, eps)
+        )
+        if kv_cache is None:
+            with jax.named_scope("ctx_attn"):
+                a = L.attend_chunk(spec, attn, qn, qr, latent, mask_bias)
+        else:
+            if cache_row_offsets is None or not page_size:
+                raise ValueError(
+                    "latent pages are written through cache_row_offsets "
+                    "and a page_size"
+                )
+            new_cache = L.write_pages(
+                kv_cache, latent, cache_row_offsets, page_table, page_size
+            )
+            if latent_decode_fn is not None and T == 1:
+                with jax.named_scope("absorb"):
+                    qa = L.absorb_query(spec, attn, qn, qr)
+                # the page's zero tail scores nothing: q padded to match
+                qa = jnp.pad(qa[:, 0], ((0, 0), (0, 0), (
+                    0, spec.latent_page_width - spec.latent_width)))
+                u = latent_decode_fn(
+                    qa, new_cache, page_table,
+                    mask_bias.reshape(B, -1), spec.kv_lora_rank,
+                    L.score_scale(spec),
+                )
+                with jax.named_scope("absorb"):
+                    a = L.unabsorb_output(spec, attn, u[:, None])
+            else:
+                q_pos = cache_row_offsets[:, None] + jnp.arange(T)[None, :]
+                a = L.attend_pages(spec, attn, qn, qr, new_cache,
+                                   page_table, q_pos, page_size)
+        with jax.named_scope("o_proj"):
+            a = a.reshape(B, T, -1) @ attn["wo"].astype(a.dtype)
+    h = h + a
+    with jax.named_scope("mlp"):
+        y = layer_norm(p["ln_2"], h, eps)
+        if "moe" in p:
+            m, stats = moe_ffn(spec, p, y, token_mask)
+            if moe_stats is not None:
+                moe_stats.append(stats)
+        else:
+            m = _dense_ffn(flags, p["mlp"], y)
+    return h + m, new_cache
+
+
 # ---------------------------------------------------------------------------
 # Trunk application
 # ---------------------------------------------------------------------------
@@ -752,38 +886,92 @@ def _mixed_layers(spec: ModelSpec) -> bool:
     return "window" in spec.layer_pattern
 
 
-#: what a model with routed experts or window layers runs under, per
-#: setting; anything else is refused by :func:`require_supported`
+def _mechanisms(spec: ModelSpec) -> tuple:
+    """What of a model the dense families lack, by name."""
+    return tuple(name for name, has in (
+        ("routed experts", bool(spec.n_experts)),
+        ("window layers", _mixed_layers(spec)),
+        ("latent attention", bool(spec.kv_lora_rank)),
+    ) if has)
+
+
+#: per setting: the values every model runs under, and which mechanism of
+#: a model refuses any other value, with what to do instead; a mechanism a
+#: setting does not name runs under every value of it
 _NEW_ARCH_RUNS_UNDER = {
-    "trainer": ((), "training through a router (its place in the hydra "
-                    "split, an auxiliary load loss) is not built; serve "
-                    "the model instead"),
-    "hf_import": ((), "no converter for this checkpoint layout; build "
-                      "the model from model.model_spec"),
-    "kv_dtype": (("bf16",), "the int8 page tier is not wired to two "
-                            "classes of page; use kv_dtype: bf16"),
-    "weights_dtype": (("bf16",), "the int8 weight tier does not cover "
-                                 "expert stacks; use weights_dtype: bf16"),
-    "speculation": (("off",), "the verifier reads one class of page "
-                              "table; use speculation: off"),
-    "mesh": ((None,), "there is no expert axis in serve/layouts.py; "
-                      "serve one chip's share on the default mesh"),
+    "trainer": ((), {
+        "routed experts": "training through a router (its place in the "
+                          "hydra split, an auxiliary load loss) is not "
+                          "built; serve the model instead",
+    }),
+    "hf_import": ((), {
+        "routed experts": "no converter for this checkpoint layout; build "
+                          "the model from model.model_spec",
+    }),
+    "rollout_cache": (("paged",), {
+        "latent attention": "generate()'s contiguous cache holds per-head "
+                            "K and V; a latent model is served through the "
+                            "paged slot pool (trlx_tpu.serve.slots)",
+    }),
+    "kv_dtype": (("bf16",), {
+        "window layers": "the int8 page tier is not wired to two classes "
+                         "of page; use kv_dtype: bf16",
+        "latent attention": "the int8 page tier keeps a scale a kv head and "
+                            "a latent page has none; use kv_dtype: bf16",
+    }),
+    "weights_dtype": (("bf16",), {
+        "routed experts": "the int8 weight tier does not cover expert "
+                          "stacks; use weights_dtype: bf16",
+    }),
+    "speculation": (("off",), {
+        "window layers": "the verifier reads one class of page table; use "
+                         "speculation: off",
+        "latent attention": "the verifier scores per-head K/V pages; use "
+                            "speculation: off",
+    }),
+    "mesh": ((None,), {
+        "routed experts": "there is no expert axis in serve/layouts.py; "
+                          "serve one chip's share on the default mesh",
+    }),
 }
 
 
 def require_supported(spec: ModelSpec, **settings) -> None:
     """The one place that refuses what an arch cannot run yet: raises
-    NotImplementedError naming the setting, its value and the arch. The
-    dense families run under every setting and pass."""
-    if not (spec.n_experts or _mixed_layers(spec)):
-        return
+    NotImplementedError naming the setting, its value, the arch and the
+    mechanism of it that refuses. The dense families run under every
+    setting and pass. (``attention: jnp`` is not in the table: the latent
+    pool has a jnp reader, ``latent.attend_pages``.)"""
+    has = _mechanisms(spec)
     for name, value in settings.items():
-        allowed, hint = _NEW_ARCH_RUNS_UNDER[name]
-        if value not in allowed:
-            raise NotImplementedError(
-                f"{name}={value!r} is not supported with arch "
-                f"'{spec.arch}' (routed experts, window layers): {hint}"
-            )
+        allowed, refusing = _NEW_ARCH_RUNS_UNDER[name]
+        if value in allowed:
+            continue
+        for mechanism in has:
+            if mechanism in refusing:
+                raise NotImplementedError(
+                    f"{name}={value!r} is not supported with arch "
+                    f"'{spec.arch}' ({mechanism}): {refusing[mechanism]}"
+                )
+
+
+def slice_layers(blocks, lo: int, hi: int):
+    """Layers ``[lo, hi)`` of a trunk: of one stacked tree, the slice of
+    every leaf; of a tuple of stacked trees (:func:`init_block_params`),
+    the runs that overlap, as one tree where one run is left (an empty
+    range keeps the first run's leaves at zero layers)."""
+    if not isinstance(blocks, (tuple, list)):
+        return jax.tree_util.tree_map(lambda x: x[lo:hi], blocks)
+    out, first = [], 0
+    for seg in blocks:
+        n = jax.tree_util.tree_leaves(seg)[0].shape[0]
+        a, b = max(lo - first, 0), min(hi - first, n)
+        if a < b:
+            out.append(jax.tree_util.tree_map(lambda x: x[a:b], seg))
+        first += n
+    if not out:
+        return jax.tree_util.tree_map(lambda x: x[:0], blocks[0])
+    return out[0] if len(out) == 1 else tuple(out)
 
 
 def mask_arg_for(
@@ -828,6 +1016,12 @@ def apply_blocks(
     exactly). ``first_layer`` is the depth at which this stack starts
     (the hydra policy's top branch)."""
     flags = ArchFlags.for_spec(spec)
+    if isinstance(blocks, (tuple, list)):  # runs of like layers, in order
+        for seg in blocks:
+            h = apply_blocks(seg, spec, h, mask_bias, positions, remat,
+                             attention_fn, first_layer)
+            first_layer += jax.tree_util.tree_leaves(seg)[0].shape[0]
+        return h
     n_layers = jax.tree_util.tree_leaves(blocks)[0].shape[0]
     if n_layers == 0:
         return h
@@ -935,6 +1129,11 @@ def init_paged_kv_cache(
     hd bytes of codes + 4 bytes of scale per (token, head) instead of
     2*hd bf16 bytes, so the same HBM holds ~2x the pages.
     """
+    if spec.kv_lora_rank:
+        # latent pages: one buffer a layer and no V buffer (the values are
+        # the first kv_lora_rank columns of the same page)
+        return jnp.zeros((num_pages, page_size, spec.latent_page_width),
+                         dtype)
     shape = (num_pages, page_size, spec.kv_heads, spec.head_dim)
     if jnp.dtype(dtype) == jnp.int8:
         sshape = shape[:-1]

@@ -138,6 +138,15 @@ class SlotPoolRuntime:
         #: program then takes the window-class tables beside the full ones
         #: and returns the expert layer's routing counts
         self.two_class = "window" in engine.spec.page_classes
+        #: latent attention: a layer's pool leaf is one array of latent
+        #: pages, read by models/latent.py's blocked readers and, under
+        #: ``attention: pallas``, the absorbed decode kernel
+        self.latent = bool(engine.spec.kv_lora_rank)
+        #: routed experts: every program also returns its routing counts
+        self.routed = bool(engine.spec.n_experts)
+        #: such models have the prefix-context prefill variant alone (no
+        #: local K/V buffer to prefill in)
+        self.context_only = self.two_class or self.latent
         self.ring_pages = self.num_window_pages = 0
         self.moe_stats = []  # device arrays [L, 4] since the last fetch
         self.moe_stats_host = []  # the same, fetched with the last step
@@ -255,7 +264,7 @@ class SlotPoolRuntime:
                 ),
                 out_shardings=(
                     self._pool_shardings, self._state_shardings,
-                    *([self._host_sharding] * self.two_class),
+                    *([self._host_sharding] * self.routed),
                 ),
             )
         return fn
@@ -280,7 +289,11 @@ class SlotPoolRuntime:
             # devices so tp head-sharding (and greedy parity) holds.
             # Prefill stays jnp either way — the kernel is decode-only.
             paged_decode_fn = None
-            if self.engine.serve.attention == "pallas":
+            if self.engine.serve.attention == "pallas" and self.latent:
+                from trlx_tpu.ops.latent_attention import (
+                    latent_decode_attention as paged_decode_fn,
+                )
+            elif self.engine.serve.attention == "pallas":
                 from trlx_tpu.ops.paged_attention import (
                     make_paged_decode_fn,
                 )
@@ -311,7 +324,7 @@ class SlotPoolRuntime:
                 ),
                 out_shardings=(
                     self._pool_shardings, self._state_shardings,
-                    *([self._host_sharding] * (3 + self.two_class)),
+                    *([self._host_sharding] * (3 + self.routed)),
                 ),
             )
         return self._step_fn
@@ -376,7 +389,7 @@ class SlotPoolRuntime:
         ``suffix=True`` selects the prefix-context (``prefill_suffix``)
         executable; tokens/mask are right-padded."""
         e = self.engine
-        suffix = suffix or self.two_class  # two classes: the one variant
+        suffix = suffix or self.context_only  # the one variant there is
         fn = self._prefill_fn(bucket, suffix)
         args = [
             e.blocks, e.embed, e.ln_f, self.pool, self.state,
@@ -400,7 +413,7 @@ class SlotPoolRuntime:
                 np.asarray(window_base, np.int32),
             ]
         with telemetry.span(self.prefill_span(bucket, suffix)):
-            if self.two_class:
+            if self.routed:
                 self.pool, self.state, stats = fn(*args)
                 self.moe_stats.append(stats)
             else:
@@ -410,33 +423,32 @@ class SlotPoolRuntime:
         """One decode step for every slot; returns host-side
         (tokens [S], emitted [S], finished [S]) numpy arrays. A model
         with window layers takes the slots' window-class ring tables
-        (``window_table`` [S, ring_pages], the scheduler's) and leaves the
-        routing counts of this step and of the prefills since the last
-        one in ``moe_stats_host`` — they ride the token fetch."""
+        (``window_table`` [S, ring_pages], the scheduler's); a model with
+        routed experts leaves the routing counts of this step and of the
+        prefills since the last one in ``moe_stats_host`` — they ride the
+        token fetch."""
         e = self.engine
         fn = self._decode_fn()
         with telemetry.span(self.STEP_SPAN):
+            extra = ()
             if self.two_class:
                 if window_table is None:
                     window_table = np.full(
                         (self.num_slots, self.ring_pages),
                         self.num_window_pages, np.int32,
                     )
-                self.pool, self.state, tok, emitted, finished, stats = fn(
-                    e.blocks, e.embed, e.ln_f, self.pool, self.state,
-                    np.int32(seed),
-                    np.ascontiguousarray(window_table, np.int32),
-                )
-                pending, self.moe_stats = self.moe_stats, []
-                tok, emitted, finished, stats, self.moe_stats_host = \
-                    self._fetch((tok, emitted, finished, stats, pending))
-                self.moe_stats_host.append(stats)  # the step's comes last
-                return tok, emitted, finished
-            self.pool, self.state, tok, emitted, finished = fn(
+                extra = (np.ascontiguousarray(window_table, np.int32),)
+            self.pool, self.state, *out = fn(
                 e.blocks, e.embed, e.ln_f, self.pool, self.state,
-                np.int32(seed),
+                np.int32(seed), *extra,
             )
-            return self._fetch((tok, emitted, finished))
+            if not self.routed:
+                return self._fetch(tuple(out))
+            pending, self.moe_stats = self.moe_stats, []
+            tok, emitted, finished, stats, self.moe_stats_host = \
+                self._fetch((*out, pending))
+            self.moe_stats_host.append(stats)  # the step's comes last
+            return tok, emitted, finished
 
     def verify(self, seed: int, proposals: np.ndarray,
                n_proposed: np.ndarray):
@@ -540,14 +552,17 @@ class SlotPoolRuntime:
             layouts.tree_bytes_per_device(
                 jax.tree_util.tree_leaves(seg["attn"][name])[0]
             ) // layers
-            for name in ("wq", "wk", "wv")
+            for name in (("wq", "w_dkv", "w_uk", "w_uv", "wo")
+                         if self.latent else ("wq", "wk", "wv"))
         )
         moved = sum(
             m.nbytes for m in large_moves(compiled.as_text(), one_matrix)
         )
         telemetry.set_gauge("serve/decode_weight_copy_bytes", moved)
         said.append(f"{moved / 2**30:.3f} GiB of weight-sized copies")
-        if e.serve.attention == "pallas":
+        if self.latent:
+            said += self._report_latent_pool()
+        elif e.serve.attention == "pallas":
             said += self._report_paged_walk()
         print(f"[trlx_tpu.serve] decode step: {', '.join(said)}",
               file=sys.stderr, flush=True)
@@ -585,6 +600,41 @@ class SlotPoolRuntime:
             )
         return said
 
+    def _report_latent_pool(self) -> list:
+        """What a latent pool costs and how it is read, at warm-up:
+        ``serve/latent_bytes_per_token`` (a layer: the latent out to whole
+        lane tiles, what a page really takes), the absorbed decode
+        kernel's walk under ``attention: pallas``
+        (``serve/latent_attn_pages_per_block``,
+        ``serve/latent_attn_blocks_per_table``) and the order each prefill
+        class compiled (``serve/latent_order{bucket=}``: 1 absorbed, 0
+        up-projected; chosen by the class's length alone)."""
+        from trlx_tpu.models.latent import ABSORB_MAX_T
+        from trlx_tpu.ops.latent_attention import block_plan
+
+        spec = self.engine.spec
+        pages = self.pool[0][0]
+        per_token = pages.shape[-1] * pages.dtype.itemsize
+        telemetry.set_gauge("serve/latent_bytes_per_token", per_token)
+        said = [f"latent pages of {per_token} bytes a token a layer "
+                f"({spec.latent_width} numbers read)"]
+        if self.engine.serve.attention == "pallas":
+            shape = pages.sharding.shard_shape(pages.shape)
+            P, blocks = block_plan(shape, pages.dtype, self.max_pages)
+            telemetry.set_gauge("serve/latent_attn_pages_per_block", P)
+            telemetry.set_gauge("serve/latent_attn_blocks_per_table", blocks)
+            said.append(f"walked {P} pages a block, {blocks} blocks a "
+                        f"table of {self.max_pages}")
+        orders = {}
+        for Bp, P, _ in self._prefill_fns:
+            absorbed = P <= ABSORB_MAX_T
+            telemetry.set_gauge("serve/latent_order", int(absorbed),
+                                {"bucket": f"b{Bp}p{P}"})
+            orders.setdefault("absorbed" if absorbed else "up-projected",
+                              []).append(f"b{Bp}p{P}")
+        said += [f"{k}: {' '.join(v)}" for k, v in sorted(orders.items())]
+        return said
+
     def warmup(self) -> Dict[str, float]:
         """Compile every admission bucket + the decode step up front.
         All rows aim at the sentinel slot, so the live pool is untouched;
@@ -594,7 +644,7 @@ class SlotPoolRuntime:
         pad = self.engine.pad_token_id
         latencies = {}
         # a model with window layers has the prefix-context variant alone
-        variants = (True,) if self.two_class else (False, True)
+        variants = (True,) if self.context_only else (False, True)
         for P, extents in self.engine.prompt_classes():
             for Bp in extents:
                 for suffix in variants:
@@ -653,7 +703,7 @@ class _LiveSlot:
     radix tree (the rollback handle for a failed prefill)."""
 
     __slots__ = ("request", "tokens", "pages", "committed", "wmap",
-                 "wquota", "pos0")
+                 "wquota", "pos0", "page_arr")
 
     def __init__(self, request: Request, pages=None, committed=None):
         self.request = request
@@ -668,6 +718,9 @@ class _LiveSlot:
         self.wmap: Dict[int, int] = {}
         self.wquota = 0
         self.pos0 = 0
+        # ``pages`` as an array (a latent pool's flight record counts the
+        # pages a step reads, every step)
+        self.page_arr = None
 
 
 class SlotScheduler:
@@ -1131,7 +1184,7 @@ class SlotScheduler:
     def _prefill_batch(self, batch: List[Request], P: int, extents) -> bool:
         """Prefill one admission batch; returns False when the page
         allocator ran dry and part of the batch went back to the queue."""
-        if self.runtime.two_class or self.engine.chunk_len(P):
+        if self.runtime.context_only or self.engine.chunk_len(P):
             return self._prefill_batch_classes(batch, P, extents)
         return self._prefill_batch_paged(batch, P, extents)
 
@@ -1264,8 +1317,8 @@ class SlotScheduler:
 
     def _prefill_batch_classes(self, batch: List[Request], P: int,
                                extents) -> bool:
-        """Paged admission of a model with two classes of page, and of any
-        prompt class served in chunks. As :meth:`_prefill_batch_paged`
+        """Paged admission of a model with two classes of page or latent
+        pages, and of any prompt class served in chunks. As :meth:`_prefill_batch_paged`
         (match, reserve, commit, prefill the unmatched suffix; exhaustion
         of EITHER class queues), and besides: the match is cut back to
         where the window-class pages are still kept; a slot maps only the
@@ -1311,6 +1364,8 @@ class SlotScheduler:
             live.tokens = list(r.committed)
             live.wmap, live.wquota = wmap, max(quota, len(wmap))
             live.pos0 = len(toks) - len(live.tokens)
+            if rt.latent:
+                live.page_arr = np.asarray(pages, np.int64)
             plans.append((r, toks, len(matched), live))
         if deferred:
             with self._cond:
@@ -1669,9 +1724,10 @@ class SlotScheduler:
                     tok, emitted, finished = self.runtime.step(
                         seed, self._wtable
                     )
-                    self._note_moe_stats()
                 else:
                     tok, emitted, finished = self.runtime.step(seed)
+                if self.runtime.routed:
+                    self._note_moe_stats()
                 # plain decode is the counts <= 1 degenerate case of the
                 # same harvest shape
                 cand = np.asarray(tok)[:, None]
@@ -2085,13 +2141,16 @@ class SlotScheduler:
             in_use = self._pages_in_use()
             rec.update(pages_full=in_use["full"],
                        pages_window=in_use["window"],
-                       pairs_here=self._fr_pairs,
+                       window_freed=self._fr_window_freed)
+            self._fr_window_freed = 0
+        if self.runtime.routed:
+            rec.update(pairs_here=self._fr_pairs,
                        pairs_step=self._fr_pairs_step,
                        experts_hit=self._fr_experts_hit,
-                       window_freed=self._fr_window_freed,
                        moe_load=round(self._moe_load, 3))
-            self._fr_pairs = self._fr_pairs_step = 0
-            self._fr_experts_hit = self._fr_window_freed = 0
+            self._fr_pairs = self._fr_pairs_step = self._fr_experts_hit = 0
+        if self.runtime.latent:
+            rec["pages_read"], rec["shared_pages_read"] = self._pages_read()
         if self.spec_k > 0:
             # a speculation regression (acceptance collapsing to 0) must
             # be visible in a stall dump, not only in the counters
@@ -2100,6 +2159,22 @@ class SlotScheduler:
         self.flight.record(**rec)
         self._fr_admitted = self._fr_evicted = 0
         self._fr_spec_proposed = self._fr_spec_accepted = 0
+
+    def _pages_read(self):
+        """(pages the live slots' contexts reach, those of them that
+        another live slot reaches too): what the last decode step read of
+        a latent pool, and how much of it the prefix cache let two slots
+        read from one copy."""
+        ps = self.runtime.page_size
+        reach = [
+            live.page_arr[:(live.pos0 + len(live.tokens) - 1) // ps + 1]
+            for live in self._live.values() if live.page_arr is not None
+        ]
+        if not reach:
+            return 0, 0
+        pages = np.concatenate(reach)
+        readers = np.bincount(pages, minlength=self.runtime.num_pages)
+        return int(pages.size), int((readers[pages] > 1).sum())
 
     def dump_flight_recorder(self) -> None:
         """Supervisor stall hook (``RunSupervisor.add_dump_fn``): print

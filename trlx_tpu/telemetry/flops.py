@@ -76,8 +76,13 @@ def kv_bytes_per_token(spec, kv_dtype: str = "bf16") -> int:
     layer. ``int8`` (serve.kv_dtype): ``head_dim`` 1-byte codes plus one
     f32 scale per (token, kv-head) — the quantize_kv layout. The single
     source of truth for pool sizing: slots.pool_stats and the
-    ``serve/kv_bytes_per_token`` gauge both read this.
+    ``serve/kv_bytes_per_token`` gauge both read this. A latent-attention
+    model keeps one latent a layer and no V: ``latent_page_width`` 2-byte
+    numbers, the latent out to whole lane tiles, which is what a page
+    takes (640 for the 576 that are read).
     """
+    if spec.kv_lora_rank:
+        return 2 * spec.n_layer * spec.latent_page_width
     per_head = (
         spec.head_dim + 4 if kv_dtype == "int8" else 2 * spec.head_dim
     )
