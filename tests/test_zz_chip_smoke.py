@@ -321,6 +321,69 @@ def test_decode_step_streams_its_qkv_weights_as_stored(
     assert all("attn" in m.op_name or not m.op_name for m in folded), folded
 
 
+def test_update_program_runs_its_frozen_trunk_outside_the_epochs_loop(
+        on_chip):
+    """The PPO update at gpt2-xl's widths (8 layers, two of them trained,
+    2 epochs), compiled for a v5e: the loop that carries the six stacked
+    frozen layers stands in the entry computation, and the epochs' loop
+    holds the top's forward and backward alone. XLA:TPU does not move a
+    loop out of the loop around it (on the chip three of gpt2-xl's four
+    46-layer forwards an update recomputed the same array), and XLA:CPU
+    does, so this is the one test that reads the compiler that has the
+    problem. The control is the whole pass scanned, as the update stood:
+    it must still show the trunk's loop inside, or the compiler has
+    learned to move it and this probe guards nothing."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from test_ppo_update_structure import batch, ppo_method, scanned_whole
+    from trlx_tpu.data.configs import ModelSpec
+    from trlx_tpu.models.policy import HydraPolicy
+    from trlx_tpu.trainers.ppo_trainer import ppo_update_fns
+    from trlx_tpu.utils.hlo_text import ENTRY, whiles_by_computation
+
+    L, k, epochs = 8, 2, 2
+    spec = ModelSpec(arch="gpt2", vocab_size=50257, n_layer=L, n_head=25,
+                     d_model=1600, d_ff=6400, n_positions=1024)
+    policy = HydraPolicy(spec=spec, num_layers_unfrozen=k,
+                         compute_dtype=jnp.bfloat16)
+    opt = optax.adamw(1e-6)
+    train_step, train_multi, _ = ppo_update_fns(
+        policy, ppo_method(epochs), opt
+    )
+
+    def arguments():
+        params = policy._init(jax.random.PRNGKey(0), jnp.float32,
+                              jnp.bfloat16)  # a bf16 trunk under a f32 top
+        return (params, opt.init(params["trainable"]),
+                batch(rows=16, gen=48))
+
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip),
+        jax.eval_shape(arguments),
+    )
+
+    def trunk_loops(fn):
+        """(in the entry computation, inside another loop's body)"""
+        with jax.default_matmul_precision("default"):  # as on the chip
+            text = jax.jit(fn, donate_argnums=(0, 1)).lower(
+                *args).compile().as_text()
+        whiles = whiles_by_computation(text)
+        is_trunk = lambda w: (
+            ("bf16", (L - k, 1600, 6400)) in w.carry
+            and w.body not in whiles  # the layers' own loop, not one around it
+        )
+        inside = [w for where, ws in whiles.items() if where != ENTRY
+                  for w in ws if is_trunk(w)]
+        return [w for w in whiles[ENTRY] if is_trunk(w)], inside
+
+    at_entry, inside = trunk_loops(train_multi)
+    assert len(at_entry) == 1 and inside == [], (at_entry, inside)
+    at_entry, inside = trunk_loops(scanned_whole(train_step, epochs))
+    assert at_entry == [] and len(inside) == 1, (at_entry, inside)
+
+
 def test_latent_decode_step_compiles_for_a_v5e_and_streams_its_projections(
         on_chip, monkeypatch):
     """The decode step of a latent-attention model at the published

@@ -16,6 +16,9 @@ and one forward computes trunk **once**, then branches twice — policy logits
 + values and reference logits in a single pass. Gradients are taken w.r.t.
 ``trainable`` only, which also subsumes the reference's separate
 bottom-layer freezing loop (reference: trlx/model/accelerate_base_model.py:38-41).
+The forward is two named halves, ``trunk`` and ``forward_from_trunk``, so a
+caller that runs the top several times over one batch (the PPO update's
+``ppo_epochs`` passes) runs the trunk once and starts each pass from its output.
 
 ``num_layers_unfrozen`` semantics (one definition, unlike the reference's
 inconsistent uses — see SURVEY §"quirks"): k = num_layers_unfrozen top
@@ -145,7 +148,12 @@ class HydraPolicy:
 
     # -- forward ------------------------------------------------------------
 
-    def _trunk(self, params: Params, tokens, attention_mask):
+    def trunk(self, params: Params, tokens, attention_mask):
+        """Embeddings + the frozen bottom blocks: ``(h, mask_bias,
+        positions)``, what ``forward_from_trunk`` /
+        ``forward_hidden_from_trunk`` start from. Reads
+        ``params["frozen_base"]`` alone, so its output does not change
+        while only ``trainable`` does."""
         positions = positions_from_mask(attention_mask)
         mask_bias = mask_arg_for(self._attn(), attention_mask)
         h = embed_tokens(
@@ -217,8 +225,17 @@ class HydraPolicy:
         logits/ref_logits: [B, T, V] float32; values: [B, T] float32.
         The trunk (embeddings + frozen bottom blocks) runs exactly once.
         """
-        h_top, h_ref, values = self.forward_hidden(
-            params, tokens, attention_mask, with_ref
+        return self.forward_from_trunk(
+            params, *self.trunk(params, tokens, attention_mask), with_ref
+        )
+
+    def forward_from_trunk(
+        self, params: Params, h, mask_bias, positions, with_ref: bool = True
+    ):
+        """`forward`'s second half: both top branches and their heads over
+        the trunk's output (``h, mask_bias, positions = trunk(...)``)."""
+        h_top, h_ref, values = self.forward_hidden_from_trunk(
+            params, h, mask_bias, positions, with_ref
         )
         embed = params["frozen_base"]["embed"]
         logits = self.branch_head_fn(params["trainable"], embed)(h_top)
@@ -243,7 +260,14 @@ class HydraPolicy:
         [B, T, V] logits tensors (the rollout program's memory peak) are
         never materialized; use `branch_head_fn` for the matching head
         callbacks."""
-        h, mask_bias, positions = self._trunk(params, tokens, attention_mask)
+        return self.forward_hidden_from_trunk(
+            params, *self.trunk(params, tokens, attention_mask), with_ref
+        )
+
+    def forward_hidden_from_trunk(
+        self, params: Params, h, mask_bias, positions, with_ref: bool = True
+    ):
+        """`forward_hidden`'s second half, over the trunk's output."""
         h_top = self._branch_hidden(
             params["trainable"], h, mask_bias, positions
         )

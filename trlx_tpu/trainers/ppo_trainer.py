@@ -21,6 +21,7 @@ Registered under both "JaxPPOTrainer" and the reference's name
 """
 
 
+import sys
 from typing import Callable, Dict, Optional
 
 import jax
@@ -49,6 +50,7 @@ from trlx_tpu.pipeline.ppo_pipeline import PPORolloutStorage
 from trlx_tpu.trainers import BaseRLTrainer, register_trainer
 from trlx_tpu.trainers.kl_controllers import make_kl_controller
 from trlx_tpu.utils import Clock, cosine_schedule
+from trlx_tpu.utils.jaxpr_scans import scan_sites
 from trlx_tpu.utils.tokenizer import load_tokenizer
 from trlx_tpu.utils.trackers import generations_table, make_tracker
 
@@ -95,6 +97,161 @@ def build_optimizer(train_config, sched=None) -> optax.GradientTransformation:
     return optax.chain(
         optax.clip_by_global_norm(train_config.grad_clip), opt
     )
+
+
+#: the named scope of the frozen trunk's forward inside an update program
+#: (with "update/top", "update/loss", "update/opt": op metadata only, what
+#: a device trace's ops are grouped by)
+TRUNK_SCOPE = "update/trunk"
+
+
+def trunk_passes(jaxpr) -> int:
+    """How many times one call of a traced update function runs the
+    frozen trunk: the layer loops under ``TRUNK_SCOPE`` (the outermost
+    there: a pipelined trunk nests the layers' loop in its ticks'), each
+    times the trip counts of the loops it stands in. 1 where the trunk is
+    evaluated beside the scan over ``ppo_epochs``, ``ppo_epochs`` where
+    it is evaluated inside; 0 where no layer is frozen."""
+    return sum(
+        site.runs for site in scan_sites(jaxpr)
+        if TRUNK_SCOPE in site.scope
+        and not any(TRUNK_SCOPE in s.scope for s in site.enclosing)
+    )
+
+
+def ppo_update_fns(policy: HydraPolicy, method, opt, guard_on: bool = False,
+                   max_step_kl: float = 0.0):
+    """The PPO update as pure functions ``(train_step, train_multi,
+    train_multi_indexed)`` of ``(params, opt_state, batch)``, for the
+    trainer to jit.
+
+    One dispatch is two parts. ``shared`` computes what every pass over a
+    batch reads and none changes: GAE + whitened advantages, the token
+    and attention arrays, and the frozen trunk's output ``h`` (the bottom
+    ``L - k`` blocks read ``params["frozen_base"]`` alone). ``one_pass``
+    is one optimization pass that starts from it: the trainable top's
+    forward and backward, the clipped losses, the optimizer step.
+    ``train_multi`` runs ``shared`` once, OUTSIDE its scan over
+    ``ppo_epochs``, and scans ``one_pass``; ``train_step`` is the same
+    two parts run once. The structure is the program's, not the
+    compiler's: XLA:CPU moves a loop-invariant inner loop out of a scan
+    by itself, XLA:TPU does not (measured on v5e at gpt2-xl: with the
+    trunk inside the scanned body, three of four 46-layer forwards
+    recomputed the same ``h``). ``trunk_passes`` reads the structure back
+    off the traced program; ``tests/test_ppo_update_structure.py`` holds
+    it."""
+    m = method
+
+    def shared(params, batch: PPORLBatch):
+        query = batch.query_tensors
+        response = batch.response_tensors
+        resp_mask = batch.response_masks
+        advantages, returns = gae_advantages(
+            batch.values, batch.rewards, m.gamma, m.lam, mask=resp_mask
+        )
+        advantages = jax.lax.stop_gradient(
+            whiten(advantages, mask=resp_mask)
+        )
+        tokens = jnp.concatenate([query, response], axis=1)
+        # attention matches what generation attended (the rollout's own
+        # prompt mask, response pads included — the reference's unmasked
+        # forward does the same, ppo_orchestrator.py:71); only the
+        # LOSSES exclude pads.
+        mask = jnp.concatenate(
+            [batch.query_masks, jnp.ones(response.shape, jnp.int32)],
+            axis=1,
+        )
+        with jax.named_scope(TRUNK_SCOPE):
+            trunk_out = policy.trunk(params, tokens, mask)
+        return advantages, returns, trunk_out
+
+    def one_pass(params, opt_state, batch: PPORLBatch, shared_out):
+        advantages, returns, trunk_out = shared_out
+        response = batch.response_tensors
+        P, G = batch.query_tensors.shape[1], response.shape[1]
+        old_values = batch.values
+        resp_mask = batch.response_masks
+
+        def loss_fn(trainable):
+            p = {**params, "trainable": trainable}
+            with jax.named_scope("update/top"):
+                logits, _, values = policy.forward_from_trunk(
+                    p, *trunk_out, with_ref=False
+                )
+            with jax.named_scope("update/loss"):
+                window = slice(P - 1, P + G - 1)
+                logprobs = logprobs_from_logits(logits[:, window], response)
+                vpred = values[:, window]
+                return ppo_losses(
+                    logprobs, vpred, batch.logprobs, old_values,
+                    advantages, returns,
+                    m.cliprange, m.cliprange_value, m.vf_coef,
+                    mask=resp_mask,
+                )
+
+        (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params["trainable"]
+        )
+        with jax.named_scope("update/opt"):
+            updates, new_opt_state = opt.update(
+                grads, opt_state, params["trainable"]
+            )
+            trainable = optax.apply_updates(params["trainable"], updates)
+            stats["grad_norm"] = optax.global_norm(grads)
+            if guard_on:
+                ok = jnp.isfinite(loss) & jnp.isfinite(stats["grad_norm"])
+                if max_step_kl > 0:
+                    ok &= stats["approx_kl"] <= max_step_kl
+                # commit-or-keep on device: a NaN update (grads poison the
+                # optimizer moments too) must not touch either tree
+                trainable = jax.tree_util.tree_map(
+                    lambda n, o: jnp.where(ok, n, o),
+                    trainable, params["trainable"],
+                )
+                new_opt_state = jax.tree_util.tree_map(
+                    lambda n, o: jnp.where(ok, n, o),
+                    new_opt_state, opt_state,
+                )
+                stats["bad_step"] = 1.0 - ok.astype(jnp.float32)
+        params = {**params, "trainable": trainable}
+        return params, new_opt_state, stats
+
+    def train_step(params, opt_state, batch: PPORLBatch):
+        return one_pass(params, opt_state, batch, shared(params, batch))
+
+    def train_multi(params, opt_state, batch: PPORLBatch):
+        """`ppo_epochs` optimization passes over one minibatch in a
+        single dispatch (the reference's inner loop,
+        accelerate_ppo_model.py:196-203, as a lax.scan). Returns the
+        LAST pass's stats, matching what the per-step loop logged."""
+        shared_out = shared(params, batch)  # once a batch, not once a pass
+
+        def one(carry, _):
+            params, opt_state, stats = one_pass(*carry, batch, shared_out)
+            return (params, opt_state), stats
+
+        (params, opt_state), stats_seq = jax.lax.scan(
+            one, (params, opt_state), None, length=m.ppo_epochs
+        )
+        last_stats = jax.tree_util.tree_map(lambda x: x[-1], stats_seq)
+        if guard_on:
+            # ANY bad inner pass marks the whole dispatch (each pass
+            # already self-skipped on device; the host guard counts
+            # the dispatch once)
+            last_stats["bad_step"] = stats_seq["bad_step"].max()
+        return params, opt_state, last_stats
+
+    def train_multi_indexed(params, opt_state, store_batch: PPORLBatch,
+                            idx):
+        """train_multi on store rows `idx`, gathered INSIDE the one
+        dispatch. The device-resident store otherwise pays one eager
+        gather dispatch per batch field (7 of them) before the train
+        program (same device-resident-indexing design as the ILQL
+        trainer's train_step_indexed)."""
+        batch = jax.tree_util.tree_map(lambda x: x[idx], store_batch)
+        return train_multi(params, opt_state, batch)
+
+    return train_step, train_multi, train_multi_indexed
 
 
 @register_trainer("JaxPPOTrainer")
@@ -323,102 +480,9 @@ class JaxPPOTrainer(BaseRLTrainer):
                 jnp.arange(kl_rewards.shape[0]), last
             ].add(scores)
 
-        def train_step(params, opt_state, batch: PPORLBatch):
-            query = batch.query_tensors
-            response = batch.response_tensors
-            P, G = query.shape[1], response.shape[1]
-
-            old_values = batch.values
-            resp_mask = batch.response_masks
-            advantages, returns = gae_advantages(
-                old_values, batch.rewards, m.gamma, m.lam, mask=resp_mask
-            )
-            advantages = jax.lax.stop_gradient(
-                whiten(advantages, mask=resp_mask)
-            )
-
-            tokens = jnp.concatenate([query, response], axis=1)
-            # attention matches what generation attended (the rollout's own
-            # prompt mask, response pads included — the reference's unmasked
-            # forward does the same, ppo_orchestrator.py:71); only the
-            # LOSSES exclude pads.
-            mask = jnp.concatenate(
-                [batch.query_masks, jnp.ones(response.shape, jnp.int32)],
-                axis=1,
-            )
-
-            def loss_fn(trainable):
-                p = {**params, "trainable": trainable}
-                logits, _, values = policy.forward(p, tokens, mask, with_ref=False)
-                window = slice(P - 1, P + G - 1)
-                logprobs = logprobs_from_logits(logits[:, window], response)
-                vpred = values[:, window]
-                return ppo_losses(
-                    logprobs, vpred, batch.logprobs, old_values,
-                    advantages, returns,
-                    m.cliprange, m.cliprange_value, m.vf_coef,
-                    mask=resp_mask,
-                )
-
-            (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params["trainable"]
-            )
-            updates, new_opt_state = opt.update(
-                grads, opt_state, params["trainable"]
-            )
-            trainable = optax.apply_updates(params["trainable"], updates)
-            stats["grad_norm"] = optax.global_norm(grads)
-            if guard_on:
-                ok = jnp.isfinite(loss) & jnp.isfinite(stats["grad_norm"])
-                if max_step_kl > 0:
-                    ok &= stats["approx_kl"] <= max_step_kl
-                # commit-or-keep on device: a NaN update (grads poison the
-                # optimizer moments too) must not touch either tree
-                trainable = jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(ok, n, o),
-                    trainable, params["trainable"],
-                )
-                new_opt_state = jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(ok, n, o),
-                    new_opt_state, opt_state,
-                )
-                stats["bad_step"] = 1.0 - ok.astype(jnp.float32)
-            params = {**params, "trainable": trainable}
-            return params, new_opt_state, stats
-
-        def train_multi(params, opt_state, batch: PPORLBatch):
-            """`ppo_epochs` optimization passes over one minibatch in a
-            single dispatch (the reference's inner loop,
-            accelerate_ppo_model.py:196-203, as a lax.scan). Returns the
-            LAST pass's stats, matching what the per-step loop logged."""
-
-            def one(carry, _):
-                params, opt_state = carry
-                params, opt_state, stats = train_step(
-                    params, opt_state, batch
-                )
-                return (params, opt_state), stats
-
-            (params, opt_state), stats_seq = jax.lax.scan(
-                one, (params, opt_state), None, length=m.ppo_epochs
-            )
-            last_stats = jax.tree_util.tree_map(lambda x: x[-1], stats_seq)
-            if guard_on:
-                # ANY bad inner pass marks the whole dispatch (each pass
-                # already self-skipped on device; the host guard counts
-                # the dispatch once)
-                last_stats["bad_step"] = stats_seq["bad_step"].max()
-            return params, opt_state, last_stats
-
-        def train_multi_indexed(params, opt_state, store_batch: PPORLBatch,
-                                idx):
-            """train_multi on store rows `idx`, gathered INSIDE the one
-            dispatch. The device-resident store otherwise pays one eager
-            gather dispatch per batch field (7 of them) before the train
-            program (same device-resident-indexing design as the ILQL
-            trainer's train_step_indexed)."""
-            batch = jax.tree_util.tree_map(lambda x: x[idx], store_batch)
-            return train_multi(params, opt_state, batch)
+        train_step, train_multi, train_multi_indexed = ppo_update_fns(
+            policy, m, opt, guard_on=guard_on, max_step_kl=max_step_kl
+        )
 
         # plain jit, or the AOT path with pinned output formats when the
         # relayout engaged, or plain jit with pinned output shardings
@@ -438,6 +502,44 @@ class JaxPPOTrainer(BaseRLTrainer):
             out_shardings=train_out,
         )
         self._finalize_rewards = jax.jit(finalize_rewards)
+        self._say_trunk_passes(train_multi)
+
+    def _say_trunk_passes(self, train_multi) -> None:
+        """When the update programs are built: how many times one dispatch
+        runs the frozen trunk, read off the jaxpr of the pure
+        ``train_multi`` (`trunk_passes`; traced over shapes, nothing
+        compiles, and the jitted attributes are not touched), as the gauge
+        ``ppo/update_trunk_passes`` and one log line."""
+        from trlx_tpu import telemetry
+
+        train = self.config.train
+        B, P, G = train.batch_size, max(train.input_size, 1), train.gen_size
+
+        def of(n, dtype):
+            return jax.ShapeDtypeStruct((B, n), dtype)
+
+        batch = PPORLBatch(
+            query_tensors=of(P, jnp.int32),
+            response_tensors=of(G, jnp.int32),
+            logprobs=of(G, jnp.float32),
+            values=of(G, jnp.float32),
+            rewards=of(G, jnp.float32),
+            response_masks=of(G, jnp.int32),
+            query_masks=of(P, jnp.int32),
+        )
+        state = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+            (self.params, self.opt_state),
+        )
+        passes = trunk_passes(jax.make_jaxpr(train_multi)(*state, batch))
+        telemetry.set_gauge("ppo/update_trunk_passes", passes)
+        print(
+            f"[trlx_tpu] ppo update: the frozen trunk "
+            f"({self.policy.spec.n_layer - self.policy.k} of "
+            f"{self.policy.spec.n_layer} layers) runs in {passes} of "
+            f"{self.config.method.ppo_epochs} epochs",
+            file=sys.stderr, flush=True,
+        )
 
     # -- BaseRLTrainer surface ------------------------------------------ #
 
