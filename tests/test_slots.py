@@ -316,6 +316,58 @@ def test_poisoned_step_replays_live_and_recovers(engine, fresh_registry,
         chaos.reset()
 
 
+@pytest.mark.parametrize("fault", ["dispatch", "fetch"])
+def test_a_poisoned_step_with_a_step_in_flight_replays_both(
+        engine, fresh_registry, fault):
+    """Every slot live, so the second step goes ahead of the first: a fault
+    at its dispatch, or in the first step's fetch under it, discards the
+    step in flight unread and replays every live request, bit-identical."""
+    s = SlotScheduler(engine)
+    s.warmup()
+    rows = [[1, 2], [3, 4, 5], [6], [7, 8, 9, 1]]
+    reqs = [s.submit(r, max_new_tokens=4) for r in rows]
+    in_flight = []  # the step in flight when the fault came
+    recover = s._recover_step
+
+    def recovering(error):
+        in_flight.append(s._inflight)
+        recover(error)
+
+    s._recover_step = recovering
+    if fault == "dispatch":
+        chaos.configure("serve_decode:exc@2")
+    else:
+        fetch = s.runtime.fetch_step
+
+        def poisoned(out):
+            if not in_flight:
+                raise RuntimeError("poisoned fetch")
+            return fetch(out)
+
+        s.runtime.fetch_step = poisoned
+    s.start()
+    try:
+        for r in reqs:
+            r.wait(timeout=30.0)
+        oracle = direct_generate(engine, rows, (4, 8, 8))
+        for i, r in enumerate(reqs):
+            assert r.result == engine.depad_row(oracle, i, 4)
+            assert r.replays == 1
+        # at the second dispatch the first step was in flight; in the
+        # first fetch, the second step, dispatched ahead of it
+        step, = in_flight
+        assert (step.index, step.ahead) == (
+            (1, False) if fault == "dispatch" else (2, True))
+        counters = fresh_registry.counters
+        assert counters["serve/replays"] == 4.0
+        assert counters.get("serve/request_errors", 0) == 0
+        assert counters["serve/steps_ahead"] >= 1.0
+        assert s.free_slots() == s.runtime.num_slots
+    finally:
+        chaos.reset()
+        s.stop()
+
+
 def test_replay_budget_exhaustion_is_typed_503(engine, fresh_registry,
                                                scheduler):
     """Every step poisoned (serve_decode:exc@*): the request burns its

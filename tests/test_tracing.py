@@ -236,6 +236,7 @@ def served():
         "flight": sched.flight.snapshot(),
         "counters": dict(tel.registry.counters),
         "itl_count": tel.registry.hists["serve/itl"].count,
+        "periods": list(tel.registry.hists["time/serve/slot_step"].window),
         "requests": requests,
         "runtime": sched.runtime,
     }
@@ -254,18 +255,28 @@ def test_scheduler_loop_is_spanned_phase_by_phase(served):
     by = {}
     for e in ev:
         by.setdefault(e["name"], []).append(e)
-    assert {"serve/admit", "serve/slot_step", "serve/step_fetch",
+    assert {"serve/admit", "serve/step_dispatch", "serve/step_fetch",
             "serve/harvest"} <= set(by)
     assert len({e["tid"] for e in ev}) == 1  # all on the worker's track
-    steps = by["serve/slot_step"]
-    assert len(by["serve/step_fetch"]) == len(steps) == len(
-        by["serve/harvest"])
-    for fetch, step, harvest in zip(by["serve/step_fetch"], steps,
-                                    by["serve/harvest"]):
-        assert fetch["args"]["parent"] == "serve/slot_step"
-        assert _inside(fetch, step)
+    steps = by["serve/step_dispatch"]
+    fetches = by["serve/step_fetch"]
+    assert len(fetches) == len(steps) == len(by["serve/harvest"])
+    # a step's period is observed, not spanned (warm-up's first step is)
+    assert "serve/slot_step" not in by
+    assert len(served["periods"]) == len(steps)
+    for fetch, step, harvest, period in zip(
+            fetches, steps, by["serve/harvest"], served["periods"]):
+        # a step's dispatch, then its fetch, then its harvest: three
+        # spans of their own on the worker's track; its period holds
+        # the wait for its result
+        assert "parent" not in fetch.get("args", {})
+        assert fetch["ts"] >= step["ts"] + step["dur"] - 0.01
         assert "parent" not in harvest.get("args", {})
-        assert harvest["ts"] >= step["ts"] + step["dur"] - 0.01
+        assert harvest["ts"] >= fetch["ts"] + fetch["dur"] - 0.01
+        assert period * 1e6 >= fetch["dur"] - 10.0
+    # a step in flight: the next step was dispatched before its fetch
+    assert any(nxt["ts"] + nxt["dur"] <= fetch["ts"] + 0.01
+               for fetch, nxt in zip(fetches, steps[1:]))
     prefills = [e for e in ev if e["name"].startswith("serve/prefill")]
     assert prefills
     for p in prefills:
@@ -281,11 +292,18 @@ def test_flight_records_carry_the_iteration_phases(served):
     flight = served["flight"]
     assert flight
     for rec in flight:
-        assert {"admit_ms", "fetch_ms", "harvest_ms", "step_ms"} <= set(rec)
+        assert {"admit_ms", "fetch_ms", "harvest_ms", "step_ms",
+                "ahead"} <= set(rec)
         assert rec["fetch_ms"] >= 0 and rec["harvest_ms"] >= 0
         # the wait for the result and the harvest both lie inside the step
         assert rec["fetch_ms"] + rec["harvest_ms"] <= rec["step_ms"] + 0.01
     assert any(rec["admit_ms"] > 0 for rec in flight if rec["admitted"])
+    # the steps dispatched while the one before was in flight, counted
+    assert {rec["ahead"] for rec in flight} == {0, 1}
+    assert served["counters"]["serve/steps_ahead"] == sum(
+        rec["ahead"] for rec in flight)
+    assert [rec["step"] for rec in flight] == list(
+        range(1, len(flight) + 1))
 
 
 def test_serve_programs_carry_their_names(served):

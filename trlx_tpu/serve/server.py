@@ -104,10 +104,13 @@ _SERVE_COUNTERS = (
     "serve/generated_tokens",
     # slot-scheduler family (trlx_tpu.serve.slots): admissions into pool
     # slots, harvested/freed slots, steps decoded while requests starved
-    # for a free slot
+    # for a free slot, steps dispatched while the one before was in
+    # flight, waits for arrivals that ended on the step in flight
     "serve/admissions",
     "serve/evictions",
     "serve/preempted_steps",
+    "serve/steps_ahead",
+    "serve/admit_waits_expired",
     # paged-KV family (trlx_tpu.serve.paged): prompt tokens whose prefill
     # was skipped via radix prefix hits, cached pages LRU-evicted under
     # allocation pressure

@@ -34,7 +34,10 @@ paged KV blocks, Kwon et al., SOSP '23):
   (and pages) immediately, and **admits** queued requests into free
   slots via bucketed prefill. Short requests no longer wait for long
   ones; filler rows become free slots; steady-state **slot occupancy**
-  (``serve/slot_occupancy``) is the utilization signal.
+  (``serve/slot_occupancy``) is the utilization signal. The loop keeps
+  one decode step in flight (step n+1 is dispatched before step n is
+  fetched and harvested), so the device does not idle while the host
+  harvests, admits and dispatches (``serve/steps_ahead``).
 
 Containment: the worker thread enters the serve supervisor; admission
 runs as the ``serve_admit`` phase (chaos seam ``serve_admit`` — a wedged admission is a stall the watchdog can
@@ -375,9 +378,14 @@ class SlotPoolRuntime:
         Bp, P = bucket
         return f"serve/prefill{'_sfx' if suffix else ''}_b{Bp}p{P}"
 
+    # a plain step's period: from its dispatch, or the result of the step
+    # before it if that came later, to its result (the scheduler observes
+    # it; warm-up's first step is a span)
     STEP_SPAN = "serve/slot_step"
+    DISPATCH_SPAN = "serve/step_dispatch"  # a plain step's dispatch
     VERIFY_SPAN = "serve/spec_verify"
-    FETCH_SPAN = "serve/step_fetch"  # child of either of the two above
+    # a plain step's fetch, after its dispatch; the verify step's child
+    FETCH_SPAN = "serve/step_fetch"
 
     # -- device calls ------------------------------------------------------ #
 
@@ -423,16 +431,19 @@ class SlotPoolRuntime:
                 self.pool, self.state = fn(*args)
 
     def step(self, seed: int, window_table=None):
-        """One decode step for every slot; returns host-side
-        (tokens [S], emitted [S], finished [S]) numpy arrays. A model
-        with window layers takes the slots' window-class ring tables
-        (``window_table`` [S, ring_pages], the scheduler's); a model with
-        routed experts leaves the routing counts of this step and of the
-        prefills since the last one in ``moe_stats_host`` — they ride the
-        token fetch."""
+        """Dispatch one decode step for every slot and start its outputs'
+        way to the host; returns them as device arrays, for
+        :meth:`fetch_step`. A model with window layers takes the slots'
+        window-class ring tables (``window_table`` [S, ring_pages], the
+        scheduler's; copied, since the scheduler advances its table while
+        the step is in flight); a model with routed experts hands the
+        routing counts of the prefills since the last step to this one's
+        outputs — they ride its fetch."""
+        import jax
+
         e = self.engine
         fn = self._decode_fn()
-        with telemetry.span(self.STEP_SPAN):
+        with telemetry.span(self.DISPATCH_SPAN):
             extra = ()
             if self.two_class:
                 if window_table is None:
@@ -440,18 +451,29 @@ class SlotPoolRuntime:
                         (self.num_slots, self.ring_pages),
                         self.num_window_pages, np.int32,
                     )
-                extra = (np.ascontiguousarray(window_table, np.int32),)
+                extra = (np.array(window_table, np.int32),)
             self.pool, self.state, *out = fn(
                 e.blocks, e.embed, e.ln_f, self.pool, self.state,
                 np.int32(seed), *extra,
             )
-            if not self.routed:
-                return self._fetch(tuple(out))
-            pending, self.moe_stats = self.moe_stats, []
-            tok, emitted, finished, stats, self.moe_stats_host = \
-                self._fetch((*out, pending))
-            self.moe_stats_host.append(stats)  # the step's comes last
-            return tok, emitted, finished
+            if self.routed:
+                out.append(self.moe_stats)
+                self.moe_stats = []
+            for x in jax.tree_util.tree_leaves(out):
+                x.copy_to_host_async()
+        return tuple(out)
+
+    def fetch_step(self, outputs):
+        """Wait for the outputs of a step :meth:`step` dispatched; returns
+        host-side (tokens [S], emitted [S], finished [S]) numpy arrays. A
+        model with routed experts leaves the routing counts of the step
+        and of the prefills that ran ahead of it in ``moe_stats_host``."""
+        if not self.routed:
+            return self._fetch(outputs)
+        tok, emitted, finished, stats, self.moe_stats_host = \
+            self._fetch(outputs)
+        self.moe_stats_host.append(stats)  # the step's comes last
+        return tok, emitted, finished
 
     def verify(self, seed: int, proposals: np.ndarray,
                n_proposed: np.ndarray):
@@ -473,8 +495,10 @@ class SlotPoolRuntime:
     def _fetch(self, outputs):
         """The step's blocking device->host fetch: what the host waited
         for the step's result (``fetch_s``, the flight record's
-        ``fetch_ms``). The programs dispatched before the step (this
-        iteration's prefills) run ahead of it and are waited out here."""
+        ``fetch_ms``). The programs dispatched before the step (the
+        prefills admitted ahead of it, and the step before it when that
+        one was still in flight) run ahead of it and are waited out
+        here."""
         import jax
 
         start = monotonic()
@@ -693,7 +717,8 @@ class SlotPoolRuntime:
                         start=np.zeros((Bp,), np.int32),
                         suffix=suffix,
                     )
-        self.step(0)
+        with telemetry.span(self.STEP_SPAN):
+            self.fetch_step(self.step(0))
         self._report_decode_memory()
         if self.spec_k > 0:
             # compile the verifier against the all-free pool: every row
@@ -734,11 +759,14 @@ class _LiveSlot:
     radix tree (the rollback handle for a failed prefill)."""
 
     __slots__ = ("request", "tokens", "pages", "committed", "wmap",
-                 "wquota", "pos0", "page_arr")
+                 "wquota", "pos0", "page_arr", "ahead")
 
     def __init__(self, request: Request, pages=None, committed=None):
         self.request = request
         self.tokens: List[int] = []
+        # the steps dispatched over this occupant whose tokens are not
+        # harvested yet (the step in flight)
+        self.ahead = 0
         self.pages: List[int] = pages or []
         self.committed: List[int] = committed or []
         # the window class (a model with window layers): the window-class
@@ -753,13 +781,63 @@ class _LiveSlot:
         # pages a step reads, every step)
         self.page_arr = None
 
+    def left(self) -> int:
+        """Tokens of the budget the steps dispatched so far leave over."""
+        return self.request.max_new_tokens - len(self.tokens) - self.ahead
+
+
+class _Step:
+    """One dispatched step: its outputs (``out``, device arrays on their
+    way to the host, or ``host``, a verify step's, fetched with its
+    dispatch), the occupant of every slot it decoded when it was
+    dispatched (``lanes``: a slot freed and admitted again before the
+    step's result came holds another request by then), its dispatch time
+    and index, whether it went out while the step before it was in
+    flight (``ahead``), and when its period began (``begun``: its
+    dispatch, or the result of the step before it if that came later)."""
+
+    __slots__ = ("out", "host", "lanes", "start", "index", "ahead", "begun")
+
+    def __init__(self, lanes: Dict[int, _LiveSlot], start: float,
+                 index: int, ahead: bool):
+        self.out = self.host = None
+        self.lanes = lanes
+        self.start = self.begun = start
+        self.index = index
+        self.ahead = ahead
+
+    def ready(self) -> bool:
+        """Whether the step's result is there (host arrays always are)."""
+        if self.host is not None:
+            return True
+        tok = self.out[0]
+        return isinstance(tok, np.ndarray) or tok.is_ready()
+
 
 class SlotScheduler:
     """The continuous-batching decode driver: one worker thread running
     the admit -> step -> harvest loop over the slot pool
     (``submit``/``start``/``stop``/``queue_depth``; a submitted
     :class:`Request` completes through its ``done`` event).
+
+    The loop keeps one decode step in flight: it dispatches step n+1
+    before it fetches and harvests step n, so the device has its next
+    program while the host harvests, admits and dispatches. The device
+    guards every lane (a finished or freed slot emits nothing and writes
+    nowhere) and runs the programs in the order they were dispatched, so
+    a step dispatched before the host saw a slot finish does nothing to
+    it. The step does not go ahead of an admission the synchronous order
+    (admit, step, harvest) would have made before it, except into a slot
+    that the step in flight ends on EOS, which the host cannot know
+    before the result: see :meth:`_may_go_ahead`.
+    Speculation, a hot swap, a drain's deadline, a poisoned step and
+    ``stop`` first complete the step in flight (a fault discards it).
     """
+
+    #: how often the wait for arrivals looks whether the step in flight
+    #: is done (a jax array tells no waiter), once the step's expected
+    #: period is three quarters gone
+    ARRIVAL_POLL_S = 2.5e-4
 
     def __init__(self, engine, max_queue: Optional[int] = None,
                  run_supervisor=None, slots: Optional[int] = None,
@@ -792,6 +870,9 @@ class SlotScheduler:
         self._free = list(range(self.runtime.num_slots))
         self._live: Dict[int, _LiveSlot] = {}
         self._step_counter = 0
+        #: the dispatched step whose result the host has not fetched
+        self._inflight: Optional[_Step] = None
+        self._fetched_at = 0.0  # when the last step's result came
         self._starved = False  # queue waited while no slot/page was free
         #: (event, slot, request) ring — "admit"/"free"; the e2e tests
         #: read it to prove a freed slot was reused mid-decode
@@ -1570,10 +1651,14 @@ class SlotScheduler:
         """Before a decode step: every live slot gets the window-class
         page its next token is written to (out of its reservation, when
         the token opens a page) and drops the pages that token's window
-        has passed; the rings handed to the step follow."""
+        has passed; the rings handed to the step follow. A step in flight
+        writes the position before: a slot whose budget it spends gets
+        nothing (this step writes nothing for it)."""
         rt = self.runtime
         for s, live in self._live.items():
-            pos = live.pos0 + len(live.tokens)  # written by this step
+            if live.left() <= 0:
+                continue
+            pos = live.pos0 + len(live.tokens) + live.ahead  # written by this step
             block = pos // rt.page_size
             if block not in live.wmap:
                 live.wmap[block], = self.cache.alloc_window(
@@ -1729,11 +1814,21 @@ class SlotScheduler:
             return None
 
     def _step(self) -> None:
+        """One step in the synchronous order: dispatch it, then complete
+        it."""
+        self._complete(self._dispatch())
+
+    def _dispatch(self) -> _Step:
+        """Dispatch the next step over the live slots. A plain step comes
+        back in flight; a speculative one (``verify``, whose proposals
+        are made from the tokens harvested so far) comes back fetched."""
+        step = _Step(dict(self._live), monotonic(), self._step_counter + 1,
+                     ahead=self._inflight is not None)
         plan = None
         with supervisor.phase("serve_decode"):
             chaos.maybe_inject("serve_decode")
             seed = self.engine.serve.seed + self._step_counter
-            self._step_counter += 1
+            self._step_counter = step.index
             if self.spec_k > 0 and self._live:
                 plan = self._gather_proposals()
             if plan is not None:
@@ -1746,26 +1841,19 @@ class SlotScheduler:
                     seed, props, nprops
                 )
                 counts = np.asarray(counts, np.int32)
+                step.host = (cand, counts, finished)
                 proposed = int(nprops.sum())
                 accepted = int(np.maximum(counts - 1, 0).sum())
-                span = self.runtime.VERIFY_SPAN
+            elif self.runtime.two_class:
+                self._advance_windows()
+                step.out = self.runtime.step(seed, self._wtable)
             else:
-                if self.runtime.two_class:
-                    self._advance_windows()
-                    tok, emitted, finished = self.runtime.step(
-                        seed, self._wtable
-                    )
-                else:
-                    tok, emitted, finished = self.runtime.step(seed)
-                if self.runtime.routed:
-                    self._note_moe_stats()
-                # plain decode is the counts <= 1 degenerate case of the
-                # same harvest shape
-                cand = np.asarray(tok)[:, None]
-                counts = np.asarray(emitted).astype(np.int32)
-                proposed = accepted = 0
-                span = self.runtime.STEP_SPAN
+                step.out = self.runtime.step(seed)
             supervisor.beat()
+        for live in step.lanes.values():
+            live.ahead += 1
+        if step.ahead:
+            telemetry.inc("serve/steps_ahead")
         if self._starved:
             telemetry.inc("serve/preempted_steps")
         if plan is not None:
@@ -1784,17 +1872,48 @@ class SlotScheduler:
             telemetry.set_gauge(
                 "serve/spec_acceptance_rate", self._spec_acceptance_rate()
             )
-        with telemetry.span("serve/harvest"):
-            self._harvest(cand, counts, finished, span)
+        return step
 
-    def _harvest(self, cand, counts, finished, span: str) -> None:
-        """Host half of a step: hand each live slot its tokens, complete
-        and free the finished ones. ``_harvest_s`` is the flight record's
-        ``harvest_ms``."""
+    def _complete(self, step: _Step) -> None:
+        """Fetch a dispatched step's result and harvest it. A plain step's
+        period goes into ``time/serve/slot_step``."""
+        rt = self.runtime
+        if step.host is None:
+            with supervisor.phase("serve_decode"):
+                tok, emitted, finished = rt.fetch_step(step.out)
+                supervisor.beat()
+            span = rt.STEP_SPAN
+            if rt.routed:
+                self._note_moe_stats()
+            # plain decode is the counts <= 1 degenerate case of the
+            # same harvest shape
+            cand = np.asarray(tok)[:, None]
+            counts = np.asarray(emitted).astype(np.int32)
+        else:
+            cand, counts, finished = step.host
+            span = rt.VERIFY_SPAN
+        fetched = monotonic()
+        step.begun = max(step.start, self._fetched_at)
+        self._fetched_at = fetched
+        if span == rt.STEP_SPAN:
+            telemetry.observe("time/serve/slot_step", fetched - step.begun)
+        for live in step.lanes.values():
+            live.ahead -= 1
+        with telemetry.span("serve/harvest"):
+            self._harvest(cand, counts, finished, step.lanes, span)
+
+    def _harvest(self, cand, counts, finished, lanes, span: str) -> None:
+        """Host half of a step: hand each slot the step decoded its
+        tokens, complete and free the finished ones. ``lanes`` holds each
+        slot's occupant when the step was dispatched: a lane whose
+        occupant is gone (harvested while the step was in flight, the
+        slot perhaps admitted again since) is not this step's to hand
+        out. ``_harvest_s`` is the flight record's ``harvest_ms``."""
         done_at = monotonic()
         emitted_total = 0
-        for slot in list(self._live):
-            live = self._live[slot]
+        for slot, live in lanes.items():
+            if self._live.get(slot) is not live:
+                continue
             c = int(counts[slot])
             if c:
                 toks = [int(t) for t in cand[slot, :c]]
@@ -1927,7 +2046,10 @@ class SlotScheduler:
         in-flight request with its committed tokens journaled. The
         ``serve_replay`` chaos seam fires before any mutation; a fault
         there (or during the reset itself) is a double fault and falls
-        back to :meth:`_fail_live`."""
+        back to :meth:`_fail_live`. A step still in flight is discarded
+        unread (a fault in the step before it poisons it too): the replay
+        covers every token neither step's harvest handed out."""
+        self._inflight = None
         if self.flight is not None:
             self.flight.dump(f"poisoned step: {error!r}")
         try:
@@ -1996,7 +2118,9 @@ class SlotScheduler:
     def _drain_expire(self) -> None:
         """Drain deadline passed: complete everything still in flight
         with DrainTimeout (worker thread, or inline when the worker was
-        never started)."""
+        never started). The step in flight completes first: what it
+        finished is answered."""
+        self._settle()
         with self._cond:
             pending = list(self._queue)
             self._queue.clear()
@@ -2074,6 +2198,7 @@ class SlotScheduler:
             box = self._pending_swap
         if box is None:
             return
+        self._settle()  # no step of the old weights may still run
         e = self.engine
         old_version = e.model_version
         old_views = (e.blocks, e.embed, e.ln_f)
@@ -2144,28 +2269,45 @@ class SlotScheduler:
                 "candidate checkpoint; rolling back"
             )
 
-    def _record_step(self, start: float, end: float) -> None:
-        """One compact flight-recorder record per engine step; the
-        admitted/evicted deltas accumulated since the last record reset
-        here so each record owns exactly its step's churn."""
+    def _record_step(self, start: float, end: float,
+                     step: Optional[_Step] = None) -> None:
+        """One compact flight-recorder record per engine step, written
+        when the step (``step``, the last dispatched one if not given)
+        completes; the admitted/evicted deltas accumulated since the last
+        record reset here so each record owns exactly its iteration's
+        churn. ``active`` counts the slots the step decoded that are still
+        live after its harvest, as the synchronous order counts them. With
+        a step in flight the admissions a record counts were made after
+        the step before it completed: they run ahead of the step in
+        flight, not of this one (``lib/gaps.py``'s join of admissions to
+        records reads them so)."""
         if self.flight is None:
             self._fr_admitted = self._fr_evicted = 0
+            self._admit_s = 0.0
             self._fr_spec_proposed = self._fr_spec_accepted = 0
             return
+        kept = list(self._live.values()) if step is None else [
+            live for s, live in step.lanes.items()
+            if self._live.get(s) is live
+        ]
         rec = {
-            "step": self._step_counter,
+            "step": step.index if step else self._step_counter,
             "t": round(end, 4),
-            "active": len(self._live),
+            "active": len(kept),
             "finished": self._fr_evicted,
             "admitted": self._fr_admitted,
-            "occupancy": round(self._occupancy(), 4),
+            "occupancy": round(
+                len(kept) / max(self.runtime.num_slots, 1), 4
+            ),
             "step_ms": round((end - start) * 1000.0, 3),
-            # the iteration's host phases: admission before the step
-            # (prefill dispatches included), the wait for the step's
-            # result, and the harvest after it
+            # the iteration's host phases: its admission (prefill
+            # dispatches included), the wait for the step's result, and
+            # the harvest after it
             "admit_ms": round(self._admit_s * 1000.0, 3),
             "fetch_ms": round(self.runtime.fetch_s * 1000.0, 3),
             "harvest_ms": round(self._harvest_s * 1000.0, 3),
+            # 1: dispatched while the step before it was in flight
+            "ahead": int(bool(step and step.ahead)),
             "pages_free": self.cache.free_pages(),
         }
         if self.runtime.two_class:
@@ -2181,7 +2323,8 @@ class SlotScheduler:
                        moe_load=round(self._moe_load, 3))
             self._fr_pairs = self._fr_pairs_step = self._fr_experts_hit = 0
         if self.runtime.latent:
-            rec["pages_read"], rec["shared_pages_read"] = self._pages_read()
+            rec["pages_read"], rec["shared_pages_read"] = \
+                self._pages_read(kept)
         if self.spec_k > 0:
             # a speculation regression (acceptance collapsing to 0) must
             # be visible in a stall dump, not only in the counters
@@ -2189,17 +2332,19 @@ class SlotScheduler:
             rec["spec_accepted"] = self._fr_spec_accepted
         self.flight.record(**rec)
         self._fr_admitted = self._fr_evicted = 0
+        self._admit_s = 0.0
         self._fr_spec_proposed = self._fr_spec_accepted = 0
 
-    def _pages_read(self):
-        """(pages the live slots' contexts reach, those of them that
-        another live slot reaches too): what the last decode step read of
-        a latent pool, and how much of it the prefix cache let two slots
-        read from one copy."""
+    def _pages_read(self, lives=None):
+        """(pages the contexts of ``lives`` — the live slots' if not given
+        — reach, those of them that another of them reaches too): what
+        the last decode step read of a latent pool, and how much of it
+        the prefix cache let two slots read from one copy."""
         ps = self.runtime.page_size
         reach = [
             live.page_arr[:(live.pos0 + len(live.tokens) - 1) // ps + 1]
-            for live in self._live.values() if live.page_arr is not None
+            for live in (self._live.values() if lives is None else lives)
+            if live.page_arr is not None
         ]
         if not reach:
             return 0, 0
@@ -2269,46 +2414,129 @@ class SlotScheduler:
         if sup_cm is None:
             sup_cm = supervisor.NULL_CM
         with sup_cm:
-            while not self._stop.is_set():
-                # one coherent snapshot of the cross-thread poll state
-                # per iteration (the HTTP thread publishes swaps and
-                # drains under the cond); _live/_free are worker-owned
-                with self._cond:
-                    swap_pending = self._pending_swap is not None
-                    draining = self._draining
-                    queue_empty = not self._queue
-                self._update_brownout(monotonic())
-                admit_start = monotonic()
-                if swap_pending:
-                    # admission pauses so _live can empty; queued +
-                    # in-flight requests finish on the ADMITTED version
-                    if not self._live:
-                        self._apply_pending_swap()
-                        continue
-                else:
-                    # an empty queue leaves _admit nothing to place: it
-                    # gets no span of its own
-                    with (supervisor.NULL_CM if queue_empty
-                          else telemetry.span("serve/admit")):
-                        self._admit()
-                self._admit_s = monotonic() - admit_start
-                if draining:
-                    if not self._live and queue_empty:
-                        self._drained.set()
-                    elif monotonic() >= self._drain_deadline:
-                        self._drain_expire()
-                if not self._live:
-                    with self._cond:
-                        if not self._queue and not self._stop.is_set() \
-                                and self._pending_swap is None:
-                            self._cond.wait(timeout=0.1)
-                    continue
-                step_start = monotonic()
-                try:
-                    self._step()
-                except Exception as e:
-                    self._recover_step(e)
-                else:
-                    end = monotonic()
-                    self._last_step_ms = (end - step_start) * 1000.0
-                    self._record_step(step_start, end)
+            try:
+                while not self._stop.is_set():
+                    self._iterate()
+            finally:
+                self._settle()
+
+    def _iterate(self) -> None:
+        """One pass of the worker loop: complete the step in flight first
+        where the next one may not go ahead of it, admit, dispatch the
+        next step, then complete the one before it."""
+        # one coherent snapshot of the cross-thread poll state per
+        # iteration (the HTTP thread publishes swaps and drains under
+        # the cond); _live/_free are worker-owned
+        with self._cond:
+            swap_pending = self._pending_swap is not None
+            draining = self._draining
+        self._update_brownout(monotonic())
+        if self._inflight is not None and (
+            swap_pending or self.spec_k > 0 or not self._may_go_ahead()
+        ):
+            self._settle()  # the synchronous order: it completes first
+        with self._cond:
+            queue_empty = not self._queue
+        admit_start = monotonic()
+        if swap_pending:
+            # admission pauses so _live can empty; queued + in-flight
+            # requests finish on the ADMITTED version
+            if not self._live:
+                self._apply_pending_swap()
+                return
+        else:
+            # an empty queue leaves _admit nothing to place: it gets no
+            # span of its own
+            with (supervisor.NULL_CM if queue_empty
+                  else telemetry.span("serve/admit")):
+                self._admit()
+        self._admit_s = monotonic() - admit_start
+        if draining:
+            if not self._live and queue_empty:
+                self._drained.set()
+            elif monotonic() >= self._drain_deadline:
+                self._drain_expire()
+        if not self._live:
+            with self._cond:
+                if not self._queue and not self._stop.is_set() \
+                        and self._pending_swap is None:
+                    self._cond.wait(timeout=0.1)
+            return
+        before = self._inflight
+        try:
+            self._inflight = step = self._dispatch()
+            if before is not None:
+                self._finish(before)
+            if step.host is not None:  # a verify step: already fetched
+                self._inflight = None
+                self._finish(step)
+        except Exception as e:
+            self._recover_step(e)
+
+    def _may_go_ahead(self) -> bool:
+        """The admission rule of a loop with a step in flight: whether
+        the next step may be dispatched before it completes.
+
+        Not when the step in flight spends some live slot's
+        ``max_new_tokens`` budget (or no slot is live): the synchronous
+        order harvests that slot and admits into it before the next step,
+        whether the request is queued already or arrives before the
+        harvest, so the loop completes the step first. At once when no
+        slot is free, or nothing could be admitted (pages starved, a
+        drain). Otherwise the worker waits on ``_cond`` until the queue
+        can fill every free slot — the arrivals are then admitted ahead of
+        the next step, where the synchronous order would have admitted
+        them — or until the step in flight is done: then False, the loop
+        completes it first, and ``serve/admit_waits_expired`` counts a
+        gap the device still pays.
+
+        A slot the step in flight ends on EOS is the one the host cannot
+        foresee: the next step goes ahead over its finished lane, and a
+        request admitted into it rides the step after that, one step
+        later than in the synchronous order."""
+        if not self._live or any(
+                live.left() <= 0 for live in self._live.values()):
+            return False
+        free = len(self._free)
+        if not free or self._starved:
+            return True
+        step = self._inflight
+        # asleep until three quarters of the median period of the steps
+        # since warm-up are gone (an arrival wakes it), then poll for the
+        # step's result
+        tel = telemetry.current()
+        hist = tel and tel.registry.hists.get(f"time/{self.runtime.STEP_SPAN}")
+        period = hist.quantile(0.5) if hist and hist.window else 0.0
+        due = max(step.start, self._fetched_at) + 0.75 * period
+        with self._cond:
+            while len(self._queue) < free:
+                if (self._draining or self._stop.is_set()
+                        or self._pending_swap is not None):
+                    return True
+                if step.ready():
+                    telemetry.inc("serve/admit_waits_expired")
+                    return False
+                self._cond.wait(
+                    timeout=max(due - monotonic(), self.ARRIVAL_POLL_S)
+                )
+        return True
+
+    def _finish(self, step: _Step) -> None:
+        """Complete a dispatched step and write its flight record. Its
+        ``step_ms`` runs from its period's start (``_Step.begun``) to the
+        end of its harvest."""
+        self._complete(step)
+        end = monotonic()
+        self._last_step_ms = (end - step.begun) * 1000.0
+        self._record_step(step.begun, end, step)
+
+    def _settle(self) -> None:
+        """Complete the step in flight, if there is one. A fault there is
+        a poisoned step."""
+        step, self._inflight = self._inflight, None
+        if step is None:
+            return
+        try:
+            self._finish(step)
+        except Exception as e:
+            self._recover_step(e)
