@@ -24,7 +24,7 @@ from tests.test_ppo_e2e import make_config
 from trlx_tpu.data.configs import ModelSpec
 from trlx_tpu.data.ppo_types import PPORLBatch
 from trlx_tpu.models.policy import HydraPolicy
-from trlx_tpu.trainers.ppo_trainer import ppo_update_fns, trunk_passes
+from trlx_tpu.trainers.ppo_trainer import TRUNK_SCOPE, ppo_update_fns
 from trlx_tpu.utils.jaxpr_scans import scan_sites
 from trlx_tpu.utils.loading import get_model
 
@@ -47,6 +47,20 @@ def batch(masked=False, rows=B, gen=G):
         rewards=jnp.asarray(rng.normal(0.0, 0.2, (rows, gen)), jnp.float32),
         response_masks=jnp.ones((rows, gen), jnp.int32),
         query_masks=jnp.asarray(query_masks),
+    )
+
+
+def trunk_passes(jaxpr) -> int:
+    """How many times one call of a traced update function runs the
+    frozen trunk: the layer loops under ``TRUNK_SCOPE`` (the outermost
+    there: a pipelined trunk nests the layers' loop in its ticks'), each
+    times the trip counts of the loops it stands in. 1 where the trunk is
+    evaluated beside the scan over ``ppo_epochs``, ``ppo_epochs`` where
+    it is evaluated inside; 0 where no layer is frozen."""
+    return sum(
+        site.runs for site in scan_sites(jaxpr)
+        if TRUNK_SCOPE in site.scope
+        and not any(TRUNK_SCOPE in s.scope for s in site.enclosing)
     )
 
 
@@ -102,19 +116,11 @@ def test_the_epochs_scan_holds_no_trunk_and_one_stands_beside_it():
     assert trunk_passes(whole) == EPOCHS
 
 
-def test_the_trainers_own_update_programs_run_the_trunk_once(capsys):
-    """Through the registry, both programs the learn loop dispatches, and
-    what the operator sees when they are built: the log line and the
-    gauge."""
-    from trlx_tpu import telemetry
-
+def test_the_trainers_own_update_programs_run_the_trunk_once():
+    """Through the registry, both programs the learn loop dispatches."""
     config = make_config(total_steps=4, batch_size=B, ppo_epochs=2)
     config.model.model_spec["n_layer"] = 4
     trainer = get_model(config.model.model_type)(config)
-    said = capsys.readouterr().err
-    assert said.count("the frozen trunk (3 of 4 layers) runs in 1 of 2 epochs") == 1
-    registry = telemetry.current().registry
-    assert registry.gauges["ppo/update_trunk_passes"] == 1
     args = (trainer.params, trainer.opt_state, batch())
     assert trunk_passes(trainer._train_multi.trace(*args).jaxpr) == 1
     stored = jax.tree_util.tree_map(lambda x: jnp.tile(x, (2,) + (1,) * (x.ndim - 1)), batch())

@@ -327,27 +327,31 @@ def generate(
     # never one concatenated [L, ...] stack: re-assembling segment slices
     # costs a full cache copy in HLO temps per program (~2 GB at gpt2-xl
     # b128), for buffers only this function ever reads
-    cache_segs = [
-        init_kv_cache(spec, size, B, S, cache_dtype) for size in seg_sizes
-    ]
-    positions = positions_from_mask(prompt_mask)
-    h = embed_tokens(embed, spec, prompt_tokens, positions, compute_dtype)
-    # [B, 1, P, S] bias: causal over prompt slots, pad keys excluded, future
-    # (generation) slots excluded.
-    prefill_bias = jnp.concatenate(
-        [
-            causal_mask_bias(prompt_mask),
-            jnp.full((B, 1, P, G), NEG_INF, jnp.float32),
-        ],
-        axis=-1,
-    )
-    for i, seg in enumerate(segments):
-        h, cache_segs[i] = apply_blocks_with_cache(
-            seg, cache_segs[i], spec, h, prefill_bias, positions,
-            cache_offset=jnp.int32(0), attention_fn=attention_fn,
+    # (the named scopes prefill, decode and, inside a step, head and
+    # sample are the program's parts in a device trace)
+    with jax.named_scope("prefill"):
+        cache_segs = [
+            init_kv_cache(spec, size, B, S, cache_dtype)
+            for size in seg_sizes
+        ]
+        positions = positions_from_mask(prompt_mask)
+        h = embed_tokens(embed, spec, prompt_tokens, positions, compute_dtype)
+        # [B, 1, P, S] bias: causal over prompt slots, pad keys excluded,
+        # future (generation) slots excluded.
+        prefill_bias = jnp.concatenate(
+            [
+                causal_mask_bias(prompt_mask),
+                jnp.full((B, 1, P, G), NEG_INF, jnp.float32),
+            ],
+            axis=-1,
         )
-    h_last = layer_norm(ln_f, h[:, -1:], spec.layer_norm_epsilon)
-    logits0 = project_logits(embed, spec, h_last)[:, 0]  # [B, V]
+        for i, seg in enumerate(segments):
+            h, cache_segs[i] = apply_blocks_with_cache(
+                seg, cache_segs[i], spec, h, prefill_bias, positions,
+                cache_offset=jnp.int32(0), attention_fn=attention_fn,
+            )
+        h_last = layer_norm(ln_f, h[:, -1:], spec.layer_norm_epsilon)
+        logits0 = project_logits(embed, spec, h_last)[:, 0]  # [B, V]
 
     buffer_mask = jnp.concatenate(
         [prompt_mask, jnp.ones((B, G), jnp.int32)], axis=-1
@@ -442,33 +446,35 @@ def generate(
 
     def live_step(carry, step):
         cache, logits, h_prev_normed, prev_tok, finished, rng = carry
-        rng, key = jax.random.split(rng)
-        step_logits = logits
-        if extras_fn is not None:
-            step_logits = extras_fn(h_prev_normed, step_logits, prev_tok)
-        if logit_mask is not None:
-            step_logits = jnp.where(logit_mask, step_logits, NEG_INF)
-        if config.eos_token_id >= 0 and config.min_new_tokens > 0:
-            suppress = step < config.min_new_tokens
-            eos_col = step_logits[:, config.eos_token_id]
-            step_logits = step_logits.at[:, config.eos_token_id].set(
-                jnp.where(suppress, NEG_INF, eos_col)
-            )
-        # the normalized [B, V] distribution is never materialized: every
-        # warper and categorical() itself is invariant to the per-row
-        # logsumexp shift, so the draw runs on the raw logits and the
-        # recorded (unwarped) logprob is gather(logits, tok) - logsumexp —
-        # a fused reduction instead of a full-vocab log_softmax write+read
-        logz = jax.nn.logsumexp(step_logits, axis=-1)
-        tok = sample_token(key, step_logits, config.sampling)
-        logprob = jnp.take_along_axis(
-            step_logits, tok[:, None], axis=-1
-        )[:, 0] - logz
-        tok = jnp.where(finished, jnp.int32(config.pad_token_id), tok)
-        logprob = jnp.where(finished, 0.0, logprob)
-        emitted_mask = ~finished
-        if config.eos_token_id >= 0:
-            finished = finished | (tok == config.eos_token_id)
+        with jax.named_scope("sample"):
+            rng, key = jax.random.split(rng)
+            step_logits = logits
+            if extras_fn is not None:
+                step_logits = extras_fn(h_prev_normed, step_logits, prev_tok)
+            if logit_mask is not None:
+                step_logits = jnp.where(logit_mask, step_logits, NEG_INF)
+            if config.eos_token_id >= 0 and config.min_new_tokens > 0:
+                suppress = step < config.min_new_tokens
+                eos_col = step_logits[:, config.eos_token_id]
+                step_logits = step_logits.at[:, config.eos_token_id].set(
+                    jnp.where(suppress, NEG_INF, eos_col)
+                )
+            # the normalized [B, V] distribution is never materialized:
+            # every warper and categorical() itself is invariant to the
+            # per-row logsumexp shift, so the draw runs on the raw logits
+            # and the recorded (unwarped) logprob is gather(logits, tok) -
+            # logsumexp — a fused reduction instead of a full-vocab
+            # log_softmax write+read
+            logz = jax.nn.logsumexp(step_logits, axis=-1)
+            tok = sample_token(key, step_logits, config.sampling)
+            logprob = jnp.take_along_axis(
+                step_logits, tok[:, None], axis=-1
+            )[:, 0] - logz
+            tok = jnp.where(finished, jnp.int32(config.pad_token_id), tok)
+            logprob = jnp.where(finished, 0.0, logprob)
+            emitted_mask = ~finished
+            if config.eos_token_id >= 0:
+                finished = finished | (tok == config.eos_token_id)
 
         # one-token forward against the cache
         offset = P + step
@@ -479,8 +485,9 @@ def generate(
             jnp.float32
         )
         cache, h = run_layers(cache, h, bias, pos, offset)
-        h_normed = layer_norm(ln_f, h, spec.layer_norm_epsilon)
-        next_logits = project_logits(embed, spec, h_normed)[:, 0]
+        with jax.named_scope("head"):
+            h_normed = layer_norm(ln_f, h, spec.layer_norm_epsilon)
+            next_logits = project_logits(embed, spec, h_normed)[:, 0]
         carry = (cache, next_logits, h_normed[:, 0], tok, finished, rng)
         return carry, (tok, logprob, emitted_mask)
 
@@ -529,9 +536,10 @@ def generate(
         prompt_tokens, jnp.maximum(real_len - 1, 0)[:, None], axis=1
     )[:, 0]
     carry0 = (decode_cache, logits0, h0_normed, last_prompt_tok, finished0, rng)
-    _, (gen_tokens, gen_logprobs, gen_mask) = jax.lax.scan(
-        decode_body, carry0, jnp.arange(G)
-    )
+    with jax.named_scope("decode"):
+        _, (gen_tokens, gen_logprobs, gen_mask) = jax.lax.scan(
+            decode_body, carry0, jnp.arange(G)
+        )
     gen_tokens = gen_tokens.T  # [B, G]
     gen_logprobs = gen_logprobs.T
     gen_mask = gen_mask.T.astype(jnp.int32)

@@ -600,9 +600,8 @@ class SlotPoolRuntime:
         program and pool: ``serve/passes`` (layer applications over layers:
         the step's K/V scatters under ``layer<n>/kv_write``, each times the
         trip counts of the loops around it, over its layers' two, in the
-        jaxpr of the pure step, as ``ppo/update_trunk_passes`` reads the
-        update's), ``serve/pool_leaves`` (the (pass, layer) caches the pool
-        holds: every layer's leaf keeps each pass's pages) and
+        jaxpr of the pure step), ``serve/pool_leaves`` (the (pass, layer)
+        caches the pool holds: every layer's leaf keeps each pass's pages) and
         ``serve/kv_bytes_per_token`` (all of them)."""
         import jax
 

@@ -138,8 +138,9 @@ class BaseRLTrainer:
 
     def _step_jit(self):
         """``(jit_, pin)`` for the trainers' step programs. Default: plain
-        ``jax.jit`` (C++ fastpath dispatch) and no pin. When the decode
-        relayout engaged (6B-class frozen stacks, ``_layout_faithful``),
+        ``jax.jit`` (C++ fastpath dispatch) behind ``telemetry.programs.jit``,
+        which registers the executable at its first call, and no pin. When
+        the decode relayout engaged (6B-class frozen stacks, ``_layout_faithful``),
         the params carry custom at-rest layouts that only the AOT compile
         path preserves (trlx_tpu.utils.aotjit), and ``pin(tree)`` gives
         the per-leaf formats the train steps must pass as
@@ -155,15 +156,16 @@ class BaseRLTrainer:
         jit-cache check caught it)."""
         import jax
 
+        from trlx_tpu.telemetry import programs
         from trlx_tpu.utils.aotjit import aot_jit, formats_of
 
         if self._layout_faithful:
             return aot_jit, formats_of
         if self.mesh is not None:
-            return jax.jit, lambda tree: jax.tree_util.tree_map(
+            return programs.jit, lambda tree: jax.tree_util.tree_map(
                 lambda x: x.sharding, tree
             )
-        return jax.jit, None
+        return programs.jit, None
 
     def _put(self, tree):
         """Host batch -> device: sharded over (dp, fsdp) when a mesh is

@@ -21,7 +21,6 @@ Registered under both "JaxPPOTrainer" and the reference's name
 """
 
 
-import sys
 from typing import Callable, Dict, Optional
 
 import jax
@@ -50,7 +49,6 @@ from trlx_tpu.pipeline.ppo_pipeline import PPORolloutStorage
 from trlx_tpu.trainers import BaseRLTrainer, register_trainer
 from trlx_tpu.trainers.kl_controllers import make_kl_controller
 from trlx_tpu.utils import Clock, cosine_schedule
-from trlx_tpu.utils.jaxpr_scans import scan_sites
 from trlx_tpu.utils.tokenizer import load_tokenizer
 from trlx_tpu.utils.trackers import generations_table, make_tracker
 
@@ -105,20 +103,6 @@ def build_optimizer(train_config, sched=None) -> optax.GradientTransformation:
 TRUNK_SCOPE = "update/trunk"
 
 
-def trunk_passes(jaxpr) -> int:
-    """How many times one call of a traced update function runs the
-    frozen trunk: the layer loops under ``TRUNK_SCOPE`` (the outermost
-    there: a pipelined trunk nests the layers' loop in its ticks'), each
-    times the trip counts of the loops it stands in. 1 where the trunk is
-    evaluated beside the scan over ``ppo_epochs``, ``ppo_epochs`` where
-    it is evaluated inside; 0 where no layer is frozen."""
-    return sum(
-        site.runs for site in scan_sites(jaxpr)
-        if TRUNK_SCOPE in site.scope
-        and not any(TRUNK_SCOPE in s.scope for s in site.enclosing)
-    )
-
-
 def ppo_update_fns(policy: HydraPolicy, method, opt, guard_on: bool = False,
                    max_step_kl: float = 0.0):
     """The PPO update as pure functions ``(train_step, train_multi,
@@ -137,9 +121,8 @@ def ppo_update_fns(policy: HydraPolicy, method, opt, guard_on: bool = False,
     compiler's: XLA:CPU moves a loop-invariant inner loop out of a scan
     by itself, XLA:TPU does not (measured on v5e at gpt2-xl: with the
     trunk inside the scanned body, three of four 46-layer forwards
-    recomputed the same ``h``). ``trunk_passes`` reads the structure back
-    off the traced program; ``tests/test_ppo_update_structure.py`` holds
-    it."""
+    recomputed the same ``h``). ``tests/test_ppo_update_structure.py``
+    reads the structure back off the traced program."""
     m = method
 
     def shared(params, batch: PPORLBatch):
@@ -427,28 +410,35 @@ class JaxPPOTrainer(BaseRLTrainer):
             logits tensors of the policy AND reference branch — 2.7 GB at
             gpt2-124M [128, 52], the fused rollout program's memory peak —
             are never materialized. Replaces the reference's two forward
-            passes + host KL math (ppo_orchestrator.py:70-98)."""
-            h_top, h_ref, values = policy.forward_hidden(
-                params, sequences, attention_mask, with_ref=True
-            )
-            P = input_size  # static
-            response = sequences[:, P:]
-            window = slice(P - 1, sequences.shape[1] - 1)
-            embed = params["frozen_base"]["embed"]
-            logprobs = chunked_label_logprobs(
-                policy.branch_head_fn(params["trainable"], embed),
-                h_top[:, window], response,
-            )
-            ref_logprobs = chunked_label_logprobs(
-                policy.branch_head_fn(params["ref"], embed),
-                h_ref[:, window], response,
-            )
-            vals = values[:, window]
-            rewards, seq_kl = kl_penalty_rewards(
-                logprobs, ref_logprobs,
-                jnp.zeros(sequences.shape[0], jnp.float32),
-                kl_coef, mask=response_mask,
-            )
+            passes + host KL math (ppo_orchestrator.py:70-98).
+
+            Its parts are the named scopes ``trunk``, ``top`` (the two
+            halves of ``forward_hidden``) and ``logprobs``."""
+            with jax.named_scope("trunk"):
+                trunk_out = policy.trunk(params, sequences, attention_mask)
+            with jax.named_scope("top"):
+                h_top, h_ref, values = policy.forward_hidden_from_trunk(
+                    params, *trunk_out, with_ref=True
+                )
+            with jax.named_scope("logprobs"):
+                P = input_size  # static
+                response = sequences[:, P:]
+                window = slice(P - 1, sequences.shape[1] - 1)
+                embed = params["frozen_base"]["embed"]
+                logprobs = chunked_label_logprobs(
+                    policy.branch_head_fn(params["trainable"], embed),
+                    h_top[:, window], response,
+                )
+                ref_logprobs = chunked_label_logprobs(
+                    policy.branch_head_fn(params["ref"], embed),
+                    h_ref[:, window], response,
+                )
+                vals = values[:, window]
+                rewards, seq_kl = kl_penalty_rewards(
+                    logprobs, ref_logprobs,
+                    jnp.zeros(sequences.shape[0], jnp.float32),
+                    kl_coef, mask=response_mask,
+                )
             return logprobs, vals, rewards, seq_kl
 
         def rollout_fn(params, bank_tokens, bank_mask, idx, rng, kl_coef):
@@ -459,14 +449,17 @@ class JaxPPOTrainer(BaseRLTrainer):
             Every host<->device sync stalls the host until the device
             queue drains, so the rollout keeps everything on device and
             the orchestrator fetches only (sequences, seq_kl) — the two
-            things the host reward callback actually needs."""
+            things the host reward callback actually needs. Its parts are
+            the named scopes ``rollout/generate`` and ``rollout/score``."""
             query = bank_tokens[idx]
             query_mask = bank_mask[idx]
-            out = generate_fn(params, query, query_mask, rng)
-            logprobs, vals, kl_rewards, seq_kl = score_fn(
-                params, out.sequences, out.attention_mask, out.gen_mask,
-                kl_coef, query.shape[1],
-            )
+            with jax.named_scope("rollout/generate"):
+                out = generate_fn(params, query, query_mask, rng)
+            with jax.named_scope("rollout/score"):
+                logprobs, vals, kl_rewards, seq_kl = score_fn(
+                    params, out.sequences, out.attention_mask, out.gen_mask,
+                    kl_coef, query.shape[1],
+                )
             return out, query, query_mask, logprobs, vals, kl_rewards, seq_kl
 
         def finalize_rewards(kl_rewards, gen_mask, scores):
@@ -502,44 +495,6 @@ class JaxPPOTrainer(BaseRLTrainer):
             out_shardings=train_out,
         )
         self._finalize_rewards = jax.jit(finalize_rewards)
-        self._say_trunk_passes(train_multi)
-
-    def _say_trunk_passes(self, train_multi) -> None:
-        """When the update programs are built: how many times one dispatch
-        runs the frozen trunk, read off the jaxpr of the pure
-        ``train_multi`` (`trunk_passes`; traced over shapes, nothing
-        compiles, and the jitted attributes are not touched), as the gauge
-        ``ppo/update_trunk_passes`` and one log line."""
-        from trlx_tpu import telemetry
-
-        train = self.config.train
-        B, P, G = train.batch_size, max(train.input_size, 1), train.gen_size
-
-        def of(n, dtype):
-            return jax.ShapeDtypeStruct((B, n), dtype)
-
-        batch = PPORLBatch(
-            query_tensors=of(P, jnp.int32),
-            response_tensors=of(G, jnp.int32),
-            logprobs=of(G, jnp.float32),
-            values=of(G, jnp.float32),
-            rewards=of(G, jnp.float32),
-            response_masks=of(G, jnp.int32),
-            query_masks=of(P, jnp.int32),
-        )
-        state = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-            (self.params, self.opt_state),
-        )
-        passes = trunk_passes(jax.make_jaxpr(train_multi)(*state, batch))
-        telemetry.set_gauge("ppo/update_trunk_passes", passes)
-        print(
-            f"[trlx_tpu] ppo update: the frozen trunk "
-            f"({self.policy.spec.n_layer - self.policy.k} of "
-            f"{self.policy.spec.n_layer} layers) runs in {passes} of "
-            f"{self.config.method.ppo_epochs} epochs",
-            file=sys.stderr, flush=True,
-        )
 
     # -- BaseRLTrainer surface ------------------------------------------ #
 

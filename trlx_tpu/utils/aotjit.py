@@ -13,10 +13,13 @@ fitting on one 16 GB chip.
 AOT path, caching executables by the full argument signature (tree
 structure + per-leaf shape/dtype/layout). The hashing cost is a few
 microseconds per call for typical param trees — noise next to the
-device programs it dispatches.
+device programs it dispatches. Each executable it compiles is registered
+with ``telemetry.programs`` under its module name.
 """
 
 import jax
+
+from trlx_tpu.telemetry import programs
 
 __all__ = ["aot_jit", "formats_of"]
 
@@ -57,6 +60,7 @@ def _leaf_sig(x):
 class _AotJit:
     def __init__(self, fun, **jit_kwargs):
         self._jitted = jax.jit(fun, **jit_kwargs)
+        self._module = programs.module_name(fun)
         self._cache = {}
 
     def lower(self, *args, **kwargs):  # passthrough for introspection
@@ -83,6 +87,7 @@ class _AotJit:
                 telemetry.inc("compile/recompiles")
             compiled = self._jitted.lower(*args).compile()
             self._cache[key] = compiled
+            programs.register(self._module, compiled.as_text)
         return compiled
 
     def __call__(self, *args):

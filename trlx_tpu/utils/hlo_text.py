@@ -1,5 +1,6 @@
 """What a compiled executable's text (``compiled.as_text()``) says the
-compiler did with the program's large operands.
+compiler did with the program's large operands, and which named scope
+each of its instructions belongs to.
 
 A dot streams a weight from HBM once; an op that first writes the weight
 out again in another layout is a pass of its own over it, every time the
@@ -12,12 +13,15 @@ which loop the compiler left inside which: XLA:TPU does not move a loop
 whose inputs never change out of the loop around it (measured on v5e:
 the PPO update's frozen trunk inside its scan over epochs), so a
 compile-only test holds the trunk's loop in the entry computation.
+:func:`op_names` maps each instruction to its metadata's ``op_name``, the
+scopes a profile's operations are read by (``telemetry.programs``).
 """
 
 import re
 from typing import Dict, List, NamedTuple, Tuple
 
-__all__ = ["ENTRY", "Move", "While", "large_moves", "whiles_by_computation"]
+__all__ = ["ENTRY", "Move", "While", "large_moves", "op_names",
+           "whiles_by_computation"]
 
 #: `` %name = <result type> opcode(`` of one instruction line
 _INSTR = re.compile(r"^\s+(?:ROOT )?%(\S+) = (.*?) ([\w\-]+)\(")
@@ -84,6 +88,17 @@ def large_moves(text: str, min_bytes: int) -> List[Move]:
             moves.append(Move(kind, nbytes, name,
                               op_name.group(1) if op_name else ""))
     return moves
+
+
+_NAMED = re.compile(r'^\s*(?:ROOT )?%?([\w.\-]+) = .*?op_name="([^"]*)"', re.M)
+
+
+def op_names(text: str) -> Dict[str, str]:
+    """``{instruction name: op_name}`` of every instruction of a compiled
+    program's text that carries one (``fusion.12`` ->
+    ``jit(rollout_fn)/rollout/generate/decode/while/body/mlp/dot_general``);
+    a profile names an operation by its instruction."""
+    return dict(_NAMED.findall(text))
 
 
 class While(NamedTuple):
