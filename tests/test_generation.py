@@ -436,3 +436,131 @@ def test_decide_unroll_eager_and_env_override(monkeypatch):
     assert decide_unroll(spec, params, batch_size=4, seq_len=16) is True
     monkeypatch.setenv("TRLX_TPU_DECODE_UNROLL_MAX", "0")
     assert decide_unroll(spec, params, batch_size=4, seq_len=16) is False
+
+
+# -- the decode step's bounded read of the contiguous cache ---------------- #
+
+
+def _bounded_and_whole(arch, prompt, mask, cfg, block, monkeypatch,
+                       extras_fn=None):
+    """generate() twice on the same inputs: with the decode step reading
+    only the written slots in extents of ``block`` slots (the last one
+    the whole buffer), and with the plan empty (the whole buffer read and
+    masked every step)."""
+    import trlx_tpu.models.generation as gen_mod
+
+    spec, policy, params, blocks, embed, ln_f = setup(arch)
+    S = prompt.shape[1] + cfg.gen_size
+    plans = []
+
+    def run(plan):
+        def fake_plan(spec_, batch, buffer_len, cache_dtype):
+            assert buffer_len == S
+            plans.append(plan)
+            return plan
+
+        monkeypatch.setattr(gen_mod, "decode_read_plan", fake_plan)
+        fn = jax.jit(
+            lambda b, e, lf, p, m, rng: generate(
+                spec, b, e, lf, p, m, rng, cfg, compute_dtype=jnp.float32,
+                cache_dtype=jnp.float32, extras_fn=extras_fn,
+            )
+        )
+        return fn(blocks, embed, ln_f, prompt, mask, jax.random.PRNGKey(4))
+
+    plan = tuple(range(block, S, block)) + (S,)
+    assert len(plan) > 1
+    bounded, whole = run(plan), run(())
+    assert plans == [plan, ()]
+    return bounded, whole
+
+
+@pytest.mark.parametrize(
+    "arch, P, G, block, layers",
+    [
+        ("gpt2", 5, 6, 4, "unrolled"),  # S = 11, not a multiple of 4
+        ("gpt2", 4, 8, 4, "unrolled"),  # S = 12, a multiple of 4
+        ("gpt2", 5, 6, 1, "unrolled"),  # a slot an extent
+        ("llama", 5, 6, 4, "unrolled"),  # GQA: 4 query heads over 2
+        ("gpt2", 5, 6, 4, "fori"),
+        ("llama", 4, 8, 3, "fori"),
+    ],
+)
+def test_bounded_cache_read_matches_the_whole_buffer(
+        arch, P, G, block, layers, monkeypatch):
+    """The decode step's attention over the written slots alone (a switch
+    over the plan's extents) gives the greedy tokens, the log-probabilities
+    and the emitted mask of the whole buffer read under its mask: the
+    slots it leaves out carry probability nought there, so only rounding
+    may differ (float32 here; the chip's bfloat16 within its own
+    rounding). Both layer paths, MHA and GQA, a buffer a multiple of the
+    extent and one not."""
+    import trlx_tpu.models.generation as gen_mod
+
+    if layers == "fori":
+        monkeypatch.setattr(gen_mod, "_UNROLL_MAX_LAYERS", 0)
+    prompt = jax.random.randint(jax.random.PRNGKey(5), (2, P), 1, 97)
+    mask = jnp.ones((2, P), jnp.int32)
+    cfg = GenerationConfig(gen_size=G, sampling=SamplingParams(do_sample=False))
+    bounded, whole = _bounded_and_whole(arch, prompt, mask, cfg, block,
+                                        monkeypatch)
+    np.testing.assert_array_equal(np.asarray(bounded.gen_tokens),
+                                  np.asarray(whole.gen_tokens))
+    np.testing.assert_array_equal(np.asarray(bounded.gen_mask),
+                                  np.asarray(whole.gen_mask))
+    np.testing.assert_allclose(np.asarray(bounded.gen_logprobs),
+                               np.asarray(whole.gen_logprobs),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_bounded_cache_read_under_eos_exit_and_left_padding(monkeypatch):
+    """The same over left-padded prompts (pad slots inside the first
+    extent, masked as before) and the EOS early-exit path: the rows end
+    at step 2 and the guard skips the steps after it, in both reads."""
+    eos = 7
+    prompt = jax.random.randint(jax.random.PRNGKey(9), (3, 5), 1, 97)
+    mask = jnp.array([[0, 0, 1, 1, 1], [1, 1, 1, 1, 1], [0, 1, 1, 1, 1]],
+                     jnp.int32)
+    prompt = prompt * mask
+    cfg = GenerationConfig(
+        gen_size=8, sampling=SamplingParams(do_sample=False),
+        eos_token_id=eos, pad_token_id=0, min_new_tokens=2,
+    )
+    bounded, whole = _bounded_and_whole(
+        "gpt2", prompt, mask, cfg, 4, monkeypatch,
+        extras_fn=_eos_hungry_extras(eos),
+    )
+    assert np.asarray(bounded.gen_mask).sum(axis=1).tolist() == [3, 3, 3]
+    for a, b in zip(bounded, whole):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_decode_read_plan_from_the_cache_shape(monkeypatch):
+    """The plan is read off the buffer length, the rows and the head
+    shape. gpt2-xl's PPO rollout (128 rows, 4 + 48 slots, 25 heads of 64,
+    bfloat16 on one chip): extents of 16 slots, 6.5 MB of K a layer each,
+    the last the whole 52, so the loop reads 0.673 of what the whole
+    buffer every step would. Whole (no plan): a buffer within one extent,
+    rows that fill no whole lane tile on some device, heads as wide as a
+    lane tile (their cache is laid out head-last already). Split over
+    devices, an extent counts one device's rows."""
+    from trlx_tpu.models.generation import decode_read_plan, decode_read_share
+
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    xl = ModelSpec(arch="gpt2", vocab_size=50257, n_layer=48, n_head=25,
+                   d_model=1600, n_positions=1024)
+    plan = decode_read_plan(xl, 128, 52, jnp.bfloat16)
+    assert plan == (16, 32, 48, 52)
+    assert decode_read_share(plan, 4, 48) == pytest.approx(1680 / 2496)
+    assert decode_read_share((), 4, 48) == 1.0
+    assert decode_read_plan(xl, 128, 16, jnp.bfloat16) == ()  # one extent
+    assert decode_read_plan(xl, 64, 52, jnp.bfloat16) == ()
+    assert decode_read_plan(xl, 128, 52, jnp.float32) == (8, 16, 24, 32,
+                                                          40, 48, 52)
+    gptj = ModelSpec(arch="gptj", vocab_size=50400, n_layer=28, n_head=16,
+                     d_model=4096, rotary_dim=64, n_positions=2048)
+    assert decode_read_plan(gptj, 128, 52, jnp.bfloat16) == ()
+    monkeypatch.setattr(jax, "device_count", lambda: 4)
+    assert decode_read_plan(xl, 128, 52, jnp.bfloat16) == ()  # 32 a chip
+    assert decode_read_plan(xl, 512, 52, jnp.bfloat16) == (16, 32, 48, 52)

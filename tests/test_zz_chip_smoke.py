@@ -10,6 +10,7 @@ and that the cache lands where the contract says.
 """
 
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -540,3 +541,80 @@ def test_looped_step_writes_every_pass_in_place_and_streams_its_weights(
     assert text.count(" while(") == 1  # the passes: one loop, its body the layers
     if program == "decode_step":
         assert "tpu_custom_call" in text
+
+
+# -- the rollout's decode step reads only the written slots --------------- #
+
+
+def test_rollout_decode_keeps_the_cache_rows_in_the_lanes(on_chip,
+                                                          monkeypatch):
+    """generate()'s decode loop at gpt2-xl's widths and rollout shape (2
+    layers, 128 rows, 4 + 48 slots, 25 heads of 64), compiled for a v5e:
+    each layer's attention is one conditional over the read plan's
+    extents, every cache buffer keeps the rows in the lanes (XLA:TPU's
+    own layout for the whole-buffer read, ``{0,3,2,1}``: nothing padded)
+    and is written in place. The control is the same switch over plain
+    slices of the cache as it is shaped: the conditional's operand is
+    then laid out anew with ``(25, 64)`` padded to whole tiles (2.56
+    times the bytes a step reads), which this probe must still see."""
+    import jax
+    import jax.numpy as jnp
+
+    from trlx_tpu.data.configs import ModelSpec
+    from trlx_tpu.models import generation as G
+    from trlx_tpu.models.policy import HydraPolicy
+    from trlx_tpu.models.transformer import attention_scores
+
+    monkeypatch.setattr(jax, "device_count", lambda: 1)  # one described chip
+    rows, P, gen = 128, 4, 48
+    spec = ModelSpec(arch="gpt2", vocab_size=50257, n_layer=2, n_head=25,
+                     d_model=1600, d_ff=6400, n_positions=1024)
+    assert G.decode_read_plan(spec, rows, P + gen) == (16, 32, 48, 52)
+    policy = HydraPolicy(spec=spec, num_layers_unfrozen=1,
+                         compute_dtype=jnp.bfloat16)
+    config = G.GenerationConfig(
+        gen_size=gen, sampling=G.SamplingParams(do_sample=True),
+        eos_token_id=50256, pad_token_id=50256, min_new_tokens=gen,
+    )
+
+    def arguments():
+        params = policy._init(jax.random.PRNGKey(0), jnp.float32,
+                              jnp.bfloat16)
+        return (params, jnp.zeros((rows, P), jnp.int32),
+                jnp.ones((rows, P), jnp.int32), jax.random.PRNGKey(1))
+
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip),
+        jax.eval_shape(arguments),
+    )
+
+    def run(params, query, mask, rng):
+        blocks = policy.all_blocks(params)
+        embed, ln_f = policy.head_params_for_decode(params)
+        return G.generate(spec, blocks, embed, ln_f, query, mask, rng,
+                          config, unroll_layers=True)
+
+    def cache_layouts():
+        fresh = lambda *a: run(*a)  # a trace of its own, not jit's cached one
+        with jax.default_matmul_precision("default"):  # as on the chip
+            text = jax.jit(fresh).lower(*args).compile().as_text()
+        layouts = set(re.findall(r"bf16\[128,52,25,64\]\{([\d,]+):", text))
+        return text, layouts
+
+    text, layouts = cache_layouts()
+    assert text.count(" conditional(") == spec.n_layer
+    # rows in the lanes; {3,1,2,0} is the prefill's own buffer
+    assert layouts <= {"0,3,2,1", "3,1,2,0"}, layouts
+    assert text.count("dynamic-update-slice_fusion") >= 2 * spec.n_layer
+
+    def plain_slices(extents, reach, q, k, v, mask_bias):
+        return jax.lax.switch(
+            jnp.sum(jnp.asarray(extents) < reach),
+            [lambda q, k, v, b, e=e: attention_scores(
+                q, k[:, :e], v[:, :e], b[..., :e]) for e in extents],
+            q, k, v, mask_bias,
+        )
+
+    monkeypatch.setattr(G, "_attend_written", plain_slices)
+    _, layouts = cache_layouts()
+    assert "3,2,1,0" in layouts, layouts  # row-major: (25, 64) padded

@@ -519,7 +519,8 @@ def _paged_read(new_cache, page_table, page_size, dtype):
 def _slot_cache(kv_cache, k, v, cache_offset):
     """Cache writer, contiguous (``generate()``'s own buffer): the same
     buffer slot ``cache_offset`` for every row. The reader is the whole
-    buffer."""
+    buffer, or the slots written so far where the caller brings a
+    ``cache_read_fn`` (:func:`block_apply`)."""
     k_cache, v_cache = kv_cache
     with jax.named_scope("kv_write"):
         k_full = jax.lax.dynamic_update_slice_in_dim(
@@ -638,6 +639,7 @@ def block_apply(
     paged_attend_fn=None,
     token_mask: Optional[jnp.ndarray] = None,
     moe_stats: Optional[list] = None,
+    cache_read_fn=None,
 ) -> Tuple[jnp.ndarray, Optional[Tuple[jnp.ndarray, jnp.ndarray]]]:
     """One transformer block on hidden states `h` [B, T, D].
 
@@ -689,7 +691,11 @@ def block_apply(
     with the caller's own reader of the post-scatter pool (the blocked
     prefill attention of models/generation.py). `token_mask` [B, T]
     marks real tokens for the expert FFN; `moe_stats`, a list, receives
-    that layer's routing counts.
+    that layer's routing counts. `cache_read_fn(q, k_full, v_full,
+    mask_bias) -> [B, T, H, hd]` replaces the contiguous cache's
+    whole-buffer read + attention_fn with the caller's own reader of the
+    written buffer (generate()'s decode step, which reads only the slots
+    written so far); it names its own parts.
     """
     if flags.latent:
         if paged_attend_fn is not None or page_base is not None or ring \
@@ -774,14 +780,17 @@ def block_apply(
             )
         k_full, v_full = _slot_cache(kv_cache, k, v, cache_offset)
         new_cache = (k_full, v_full)
-        with jax.named_scope("kv_read"):
-            k_ctx = expand_kv(k_full.astype(q.dtype))
-            v_ctx = expand_kv(v_full.astype(q.dtype))
+        if cache_read_fn is not None:
+            a = cache_read_fn(q, k_full, v_full, mask_bias)
+        else:
+            with jax.named_scope("kv_read"):
+                k_ctx = expand_kv(k_full.astype(q.dtype))
+                v_ctx = expand_kv(v_full.astype(q.dtype))
     else:
         k_ctx, v_ctx = expand_kv(k), expand_kv(v)
 
     with jax.named_scope("attn"):
-        if a is None:  # not a fused paged reader's
+        if a is None:  # not a fused or a bounded reader's
             a = attention_fn(q, k_ctx, v_ctx, mask_bias)
         a = _project(a.reshape(B, T, H * hd), attn["wo"], attn.get("bo"))
         if flags.post_norms:
